@@ -16,6 +16,8 @@ Layer map (mirrors reference SURVEY.md §1, redesigned TPU-first):
 
 __version__ = "0.1.0"
 
+import os as _os
+
 import jax as _jax
 
 # Coordinate math (affine resampling, distance matrices, model fits) needs
@@ -23,3 +25,17 @@ import jax as _jax
 # error is pixels at volume scale. This is imaging, not ML training — always
 # run matmuls/einsums at highest precision (f32 on MXU via 3-pass bf16).
 _jax.config.update("jax_default_matmul_precision", "highest")
+
+# Every `bst <tool>` is a fresh process, so without a persistent compile
+# cache a staged run pays every XLA build in every stage, every time.
+# JAX_COMPILATION_CACHE_DIR places the cache from outside (jax reads it
+# itself; nothing is set here then); otherwise it lives at ONE fixed path
+# inside the checkout — the path is part of the cache key, so it must never
+# move between processes or runs.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update("jax_compilation_cache_dir", _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache"))
+# the many sub-second shape-bucket kernels are the bulk of a stage's
+# builds: keep them too (jax's default skips compiles under 1 s)
+_jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)

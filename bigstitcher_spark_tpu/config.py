@@ -384,25 +384,11 @@ _knob("BST_BENCH_DIR", "str", "/tmp/bst_bench",
 _knob("BST_BENCH_TILE", "int", None,
       "Override the primary bench config's tile edge (e.g. 384 runs "
       "(384,384,192) tiles).", consumer="bench")
-_knob("BST_BENCH_CHILD_TIMEOUT", "int", 1500,
-      "Per-child-process timeout (s) for bench.py subprocess runs.",
-      consumer="bench")
-_knob("BST_BENCH_DEVICE_TIMEOUT", "int", 300,
-      "Accelerator-probe timeout (s) for bench.py.", consumer="bench")
 _knob("BST_BENCH_RUNS", "int", 5,
       "Fusion benchmark repetitions per config.", consumer="bench")
 _knob("BST_BENCH_FRESH_BASELINE", "bool", True,
       "Re-measure numpy/tensorstore baselines inside every bench run; 0 "
       "reuses BASELINE_MEASURED.json.", consumer="bench")
-_knob("BST_BENCH_PARTIAL", "str", None,
-      "Path where a bench child process streams partial results "
-      "(set by the bench parent).", consumer="bench")
-_knob("BST_BENCH_CHILD", "bool", False,
-      "Marks a bench subprocess (set by the bench parent).",
-      consumer="bench")
-_knob("BST_BENCH_TPU_ONLY", "bool", False,
-      "Fail the bench run instead of falling back to CPU when the "
-      "accelerator is unreachable.", consumer="bench")
 
 # -- test suite ------------------------------------------------------------
 _knob("BST_TEST_TPU", "bool", False,

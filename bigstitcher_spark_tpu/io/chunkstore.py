@@ -629,8 +629,6 @@ class Dataset:
                  for d in range(ndim)]
         import itertools
 
-        fused = native_blockio.has_region_read()
-
         def read_one(pos):
             path = os.path.join(root, *[str(p) for p in pos])
             lo = [pos[d] * block[d] for d in range(ndim)]
@@ -640,27 +638,10 @@ class Dataset:
                     - max(off[d], lo[d]) for d in range(ndim)]
             if any(c <= 0 for c in copy):
                 return
-            if fused:
-                # decode straight into the output box: the big-endian swap
-                # fuses with the strided write (absent chunk = fill zeros)
-                native_blockio.read_block_region(
-                    path, out, dst_off, src_lo, copy, compression=ctype)
-                return
-            # stale libblockio.so without the region symbol: decode the
-            # whole chunk and assemble in numpy (keeps lz4 readable)
-            blk = native_blockio.read_block(path, self.dtype, block,
-                                            compression=ctype)
-            if blk is None:
-                return
-            src = tuple(
-                slice(src_lo[d], min(src_lo[d] + copy[d], blk.shape[d]))
-                for d in range(ndim))
-            if any(s.stop <= s.start for s in src):
-                return
-            dst = tuple(
-                slice(dst_off[d], dst_off[d] + (src[d].stop - src[d].start))
-                for d in range(ndim))
-            out[dst] = blk[src]
+            # decode straight into the output box: the big-endian swap
+            # fuses with the strided write (absent chunk = fill zeros)
+            native_blockio.read_block_region(
+                path, out, dst_off, src_lo, copy, compression=ctype)
 
         positions = list(itertools.product(*grids))
         if len(positions) > 1:
@@ -794,8 +775,9 @@ class Dataset:
 
     def _native_n5_eligible(self) -> str | None:
         """Shared native-codec eligibility gate for N5 reads AND writes:
-        local N5 store, zstd/raw codec, native library present. Returns the
-        compression type, or None when the tensorstore path must be used."""
+        local N5 store, zstd/raw (or, with liblz4 present, lz4) codec,
+        ``BST_NATIVE_IO`` on. Returns the compression type, or None when
+        the tensorstore path must be used."""
         if (self.reversed_axes or self.store is None
                 or getattr(self.store, "format", None) != StorageFormat.N5
                 or not getattr(self.store, "is_local", False)
@@ -809,8 +791,6 @@ class Dataset:
         if ctype == "lz4":
             return "lz4" if native_blockio.has_lz4() else None
         if ctype not in ("zstd", "raw"):
-            return None
-        if not native_blockio.available():
             return None
         return ctype
 
@@ -900,8 +880,6 @@ class Dataset:
             return False
         from . import native_blockio
 
-        if not native_blockio.has_zarr():
-            return False
         meta = self._zarr_meta()
         if (meta is None or meta.get("order") != "C"
                 or meta.get("dimension_separator", ".") != "."

@@ -1,11 +1,16 @@
 """ctypes binding for the native N5 block codec (native/blockio.cpp).
 
-Optional fast path: ctypes foreign calls release the GIL, so a Python thread
-pool over ``write_block``/``read_block`` encodes (zstd) and writes chunks
-truly in parallel — the role the reference fills with prebuilt codec JNI libs
-(N5Util.java:82-105, SURVEY.md §2.3). Falls back cleanly when the shared
-library has not been built (``make -C native``); callers must check
-``available()``.
+ctypes foreign calls release the GIL, so a Python thread pool over
+``write_block``/``read_block`` encodes (zstd) and writes chunks truly in
+parallel — the role the reference fills with prebuilt codec JNI libs
+(N5Util.java:82-105, SURVEY.md §2.3). ``libblockio.so`` is a build product
+(gitignored): the first use builds it from ``native/blockio.cpp`` with the
+machine's ``make``/``g++``/``libzstd`` and rebuilds it whenever the source
+is newer, so the loaded library always exports everything this module
+binds. A build or load failure raises — the codec is on by default
+(``BST_NATIVE_IO``), and silently reading and writing through the slower
+path is not what the user asked for; ``BST_NATIVE_IO=0`` is how to run
+without it.
 """
 
 from __future__ import annotations
@@ -17,32 +22,41 @@ import subprocess
 import numpy as np
 
 _LIB = None
-_TRIED = False
 
-_SO_PATH = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
-                        "native", "libblockio.so")
-_SRC_DIR = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
-                        "native")
+_SRC_DIR = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir,
+                                        os.pardir, "native"))
 
 COMPRESSION = {"raw": 0, "zstd": 1, "lz4": 2}
 
 
-def _load():
-    global _LIB, _TRIED
-    if _TRIED:
-        return _LIB
-    _TRIED = True
-    so = os.path.abspath(_SO_PATH)
-    if not os.path.exists(so):
-        try:  # build on first use; the toolchain is baked into the image
-            subprocess.run(["make", "-C", os.path.abspath(_SRC_DIR)],
-                           check=True, capture_output=True, timeout=120)
-        except Exception:
-            return None
+def _stale(so: str) -> bool:
+    """Whether the library is missing or older than its source."""
     try:
-        lib = ctypes.CDLL(so)
+        return os.path.getmtime(so) < os.path.getmtime(
+            os.path.join(_SRC_DIR, "blockio.cpp"))
     except OSError:
-        return None
+        return True
+
+
+def _load():
+    """The bound library, built first if missing or stale. Unlocked on
+    purpose: threads racing the first use each run ``make`` (which compiles
+    to a temp name and renames, so nobody maps a half-written file) and
+    bind the same library — idempotent, and no lock is held across the
+    child process."""
+    global _LIB
+    if _LIB is not None:
+        return _LIB
+    so = os.path.join(_SRC_DIR, "libblockio.so")
+    if _stale(so):
+        proc = subprocess.run(["make", "-C", _SRC_DIR], capture_output=True,
+                              text=True, timeout=300)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"building {so} failed (make rc={proc.returncode}; "
+                f"set BST_NATIVE_IO=0 to run without the native codec):\n"
+                f"{proc.stdout}{proc.stderr}")
+    lib = ctypes.CDLL(so)
     lib.n5_encode_bound.restype = ctypes.c_int64
     lib.n5_encode_bound.argtypes = [ctypes.c_int64, ctypes.c_int32]
     lib.n5_write_block_file.restype = ctypes.c_int64
@@ -57,49 +71,38 @@ def _load():
         ctypes.c_int64, ctypes.POINTER(ctypes.c_uint32),
         ctypes.POINTER(ctypes.c_int32),
     ]
-    if hasattr(lib, "n5_read_block_region"):
-        lib.n5_read_block_region.restype = ctypes.c_int64
-        lib.n5_read_block_region.argtypes = [
-            ctypes.c_char_p, ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint32),
-            ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_int32),
-        ]
-    if hasattr(lib, "lz4_available"):
-        lib.lz4_available.restype = ctypes.c_int32
-        lib.lz4_available.argtypes = []
-    if hasattr(lib, "zarr_write_chunk_file"):
-        lib.zarr_write_chunk_file.restype = ctypes.c_int64
-        lib.zarr_write_chunk_file.argtypes = [
-            ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_uint32),
-            ctypes.POINTER(ctypes.c_uint32), ctypes.c_int32, ctypes.c_void_p,
-            ctypes.c_int32, ctypes.c_int32,
-        ]
+    lib.n5_read_block_region.restype = ctypes.c_int64
+    lib.n5_read_block_region.argtypes = [
+        ctypes.c_char_p, ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_int32),
+    ]
+    lib.lz4_available.restype = ctypes.c_int32
+    lib.lz4_available.argtypes = []
+    lib.zarr_write_chunk_file.restype = ctypes.c_int64
+    lib.zarr_write_chunk_file.argtypes = [
+        ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_uint32),
+        ctypes.POINTER(ctypes.c_uint32), ctypes.c_int32, ctypes.c_void_p,
+        ctypes.c_int32, ctypes.c_int32,
+    ]
     _LIB = lib
     return _LIB
 
 
-def has_zarr() -> bool:
-    lib = _load()
-    return lib is not None and hasattr(lib, "zarr_write_chunk_file")
-
-
-def has_region_read() -> bool:
-    """True when the library exports the fused strided region reader
-    (n5_read_block_region) — older builds lack it and must use the
-    tensorstore path."""
-    lib = _load()
-    return lib is not None and hasattr(lib, "n5_read_block_region")
+def loaded() -> bool:
+    """Whether this process has loaded the codec (i.e. some read or write
+    went through it) — what run manifests report; never triggers a
+    build."""
+    return _LIB is not None
 
 
 def has_lz4() -> bool:
-    """True when the native codec can serve N5 lz4 (LZ4Block) chunks —
-    the library was built with the lz4 path AND liblz4 loads at runtime.
-    Reference codec surface parity: util/N5Util.java:87-88."""
-    lib = _load()
-    return (lib is not None and hasattr(lib, "lz4_available")
-            and bool(lib.lz4_available()))
+    """True when liblz4 loads at runtime, i.e. the native codec can serve
+    N5 lz4 (LZ4Block) chunks. Reference codec surface parity:
+    util/N5Util.java:87-88."""
+    return bool(_load().lz4_available())
 
 
 def write_zarr_chunk(
@@ -117,8 +120,6 @@ def write_zarr_chunk(
     shorter than ``chunk_shape`` (array edge) are padded with
     ``fill_value``."""
     lib = _load()
-    if lib is None or not hasattr(lib, "zarr_write_chunk_file"):
-        raise RuntimeError("native zarr chunk writer not available")
     ndim = data.ndim
     strides = (ctypes.c_int64 * ndim)(*data.strides)
     src_dims = (ctypes.c_uint32 * ndim)(*data.shape)
@@ -133,10 +134,6 @@ def write_zarr_chunk(
         raise IOError(f"zarr_write_chunk_file({chunk_path}) failed: {got}")
 
 
-def available() -> bool:
-    return _load() is not None
-
-
 def write_block(
     block_path: str,
     data: np.ndarray,
@@ -149,8 +146,6 @@ def write_block(
     so the buffer handed to C must be Fortran-contiguous w.r.t. that order.
     """
     lib = _load()
-    if lib is None:
-        raise RuntimeError("native blockio not available")
     arr = np.asfortranarray(data)
     dims = (ctypes.c_uint32 * arr.ndim)(*arr.shape)
     got = lib.n5_write_block_file(
@@ -176,8 +171,6 @@ def read_block_region(
     intermediate chunk array, no numpy assembly copy). Returns elements
     copied, or None if the file is absent."""
     lib = _load()
-    if lib is None or not hasattr(lib, "n5_read_block_region"):
-        raise RuntimeError("native blockio region read not available")
     ndim = dst.ndim
     es = dst.dtype.itemsize
     base = dst.ctypes.data + sum(
@@ -205,8 +198,6 @@ def read_block(
 ) -> np.ndarray | None:
     """Decode one N5 block file -> xyz-first array, or None if absent."""
     lib = _load()
-    if lib is None:
-        raise RuntimeError("native blockio not available")
     dtype = np.dtype(dtype)
     cap = int(np.prod(max_shape)) * dtype.itemsize
     out = np.empty(int(np.prod(max_shape)), dtype=dtype)

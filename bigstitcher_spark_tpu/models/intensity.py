@@ -458,20 +458,16 @@ def _register_device_coefficients(dev, out, views, base, ncell, dims, s):
     making the host->device round trip. The float64 math is the same IEEE
     sequence as the host branch above, so the registered table is
     bit-identical to one rebuilt from ``out``."""
-    try:
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
+    import jax
+    import jax.numpy as jnp
 
-        from .affine_fusion import register_coefficient_table
+    from .affine_fusion import register_coefficient_table
 
-        with enable_x64():
-            d = jnp.reshape(dev[: 2 * ncell * len(views)], (-1, 2))
-            per = {}
-            for v in views:
-                c = d[base[v]: base[v] + ncell]
-                c = jnp.concatenate([c[:, :1], c[:, 1:] / s], axis=1)
-                per[v] = jnp.reshape(c, (*dims, 2)).astype(jnp.float32)
-        register_coefficient_table(out, per)
-    except Exception as e:  # pragma: no cover - residency is best-effort
-        observe.log(f"device coefficient registration skipped: {e!r}",
-                    stage="solve-intensities")
+    with jax.enable_x64(True):
+        d = jnp.reshape(dev[: 2 * ncell * len(views)], (-1, 2))
+        per = {}
+        for v in views:
+            c = d[base[v]: base[v] + ncell]
+            c = jnp.concatenate([c[:, :1], c[:, 1:] / s], axis=1)
+            per[v] = jnp.reshape(c, (*dims, 2)).astype(jnp.float32)
+    register_coefficient_table(out, per)

@@ -24,7 +24,7 @@ Solver.java:351-369).
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 import numpy as np
 
@@ -328,10 +328,15 @@ def _fit_from_moments(kind: str, sw, swp, swq, spp, spq, eps=1e-9):
 def _resolve_backend(params: SolverParams) -> str:
     """``device`` (jit lax.while_loop relaxation, the default) or
     ``numpy`` (the host reference path): explicit params.backend wins,
-    else the BST_SOLVE_DEVICE knob (policy owned by ops.solve)."""
+    else the BST_SOLVE_DEVICE knob and what the platform can compile
+    (policy owned by ops.solve)."""
     from ..ops import solve as _dsolve
 
-    return _dsolve.resolve_backend(params.backend)
+    regularized = params.regularization != M.NONE and params.lam > 0
+    return _dsolve.resolve_backend(
+        params.backend,
+        needs_f64_lu=(params.model == M.AFFINE
+                      or (regularized and params.regularization == M.AFFINE)))
 
 
 def relax(
@@ -709,6 +714,11 @@ def solve(
                 stage="solver", echo=verbose,
                 tiles=len(tiles), links=len(links))
 
+    # placed once for the whole solve and filed in the run manifest's
+    # stage table: where the relaxation ran is part of the result
+    backend = _resolve_backend(params)
+    params = replace(params, backend=backend)
+
     fixed = pick_fixed(tiles, params)
     iterative = params.method.endswith("ITERATIVE")
     two_round = params.method.startswith("TWO_ROUND")
@@ -751,6 +761,10 @@ def solve(
         observe.log(f"solver: WARNING did not reach --maxError "
                     f"{params.max_error} px (best {total_err:.3f} px)",
                     stage="solver", echo=verbose)
+    observe.progress.record_stage(
+        "solver", backend=backend, model=params.model, tiles=len(tiles),
+        links=len(links), iterations=total_it, removed_links=len(removed),
+        max_error_px=round(float(total_err), 4))
     return SolveResult(corrections, total_err, total_it, removed, link_errors)
 
 

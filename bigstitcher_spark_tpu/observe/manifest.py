@@ -26,19 +26,38 @@ def manifest_name(process_index: int, process_count: int) -> str:
 
 
 def device_info() -> dict:
-    """Best-effort device inventory; empty when no backend ever came up."""
-    try:
-        import jax
+    """Device inventory, plus what the run chose on its way to the device:
+    peak device memory, the compile cache and its entry count, whether
+    chunk IO went through the native codec. (The in-flight budgets the
+    run's dispatch windows were given, and their sources, are in the
+    manifest's ``metrics``: ``bst_inflight_windows_total{source}``.)
+    ``chip_smoke.py`` reads these to tell an accelerator run from a
+    degraded one. A backend that cannot initialize is recorded as
+    ``{"error": ...}`` — never as an empty inventory."""
+    import jax
 
+    from ..io import native_blockio
+
+    try:
         devs = jax.devices()
-        return {
-            "platform": devs[0].platform,
-            "device_kind": getattr(devs[0], "device_kind", None),
-            "local_device_count": jax.local_device_count(),
-            "device_count": len(devs),
-        }
-    except Exception:
-        return {}
+    except RuntimeError as e:   # a failing run still gets its manifest
+        return {"error": repr(e)}
+    local = jax.local_devices()
+    cache_dir = jax.config.jax_compilation_cache_dir
+    return {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "local_device_count": len(local),
+        "device_count": len(devs),
+        "peak_bytes_in_use": [
+            (d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in local],
+        "compile_cache": {
+            "dir": cache_dir,
+            "entries": (len(os.listdir(cache_dir))
+                        if os.path.isdir(cache_dir) else 0)},
+        "native_codec_loaded": native_blockio.loaded(),
+    }
 
 
 def _json_default(o):

@@ -70,6 +70,10 @@ METRICS: dict[str, str] = {
         "H2D bytes avoided by native-dtype transport (vs f32 upload)",
     "bst_xfer_d2h_bytes_saved_total":
         "D2H bytes avoided by on-device output conversion",
+    "bst_mesh_drain_rows_total":
+        "work items whose outputs a per-device drain worker fetched and "
+        "consumed, labeled by device — the evidence that every device of "
+        "a sharded stage did work",
     # fused multiscale epilogue (models/affine_fusion.py): pyramid-level
     # bytes that rode the fusion drain instead of a container re-read pass
     "bst_epilogue_d2h_bytes_total":
@@ -84,6 +88,10 @@ METRICS: dict[str, str] = {
     # in-flight dispatch window (utils/devicemem.py)
     "bst_inflight_bytes": "bytes currently dispatched but not drained",
     "bst_inflight_bytes_highwater": "high-water mark of in-flight bytes",
+    "bst_inflight_windows_total":
+        "dispatch windows opened, per source of their byte budget",
+    "bst_inflight_budget_bytes":
+        "byte budget of the newest dispatch window, per source",
     # retry layer (parallel/retry.py)
     "bst_retry_rounds_total": "block retry rounds executed",
     "bst_blocks_failed_total": "blocks that failed (per exception class)",

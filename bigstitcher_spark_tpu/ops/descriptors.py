@@ -289,7 +289,7 @@ def match_ratio_test(desc_a, owner_a, desc_b, owner_b, ratio,
     # carry, and its output tables; segment k+1 dispatches before segment
     # k drains — one pipelined device_get per segment, up to two segments
     # resident — so the device never idles between segments
-    from ..utils.devicemem import InflightWindow, dispatch_budget_bytes
+    from ..utils.devicemem import InflightWindow, derived_budget
 
     dim = int(desc_a.shape[1])
     chunk_cost = (rb * dim * 4          # row slice copy
@@ -304,13 +304,13 @@ def match_ratio_test(desc_a, owner_a, desc_b, owner_b, ratio,
     own_dev = getattr(jax.config, "jax_default_device", None)
     if own_dev is not None:
         from ..parallel.pairsched import concurrent_pair_workers
-        from ..utils.devicemem import pair_budget_bytes
+        from ..utils.devicemem import pair_budget
 
-        budget = pair_budget_bytes(own_dev, concurrent_pair_workers())
+        budget, source = pair_budget(own_dev, concurrent_pair_workers())
     else:
-        budget = dispatch_budget_bytes()
+        budget, source = derived_budget()
     per_seg = max(1, int(budget // (2 * chunk_cost)))
-    window = InflightWindow()
+    window = InflightWindow(budget, source)
     starts = list(range(0, da, rb))
     ratio32 = jnp.float32(ratio)
     owners: list[np.ndarray] = []

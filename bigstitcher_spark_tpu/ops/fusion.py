@@ -342,7 +342,7 @@ fuse_block_shift = jax.jit(
 # ---------------------------------------------------------------------------
 # Device-resident volume fusion: one dispatch per (channel, timepoint) volume.
 #
-# Host<->device transfers are the scarce resource (PCIe, or worse a tunnel);
+# Host<->device transfers are the scarce resource (PCIe);
 # the per-block path moves every patch across it. Here the source tiles are
 # uploaded ONCE as a uint16 stack living in HBM, a lax.scan walks the output
 # block grid — per block: gather the K relevant tiles, dynamic-slice the

@@ -27,7 +27,7 @@ ports the iterative global optimization onto the device:
   applied via gather/segment-sum per CG step, psum-reduced across shards,
   so the memory footprint is O(matches + cells) instead of O(cells²).
 
-All solver math runs in float64 under a scoped ``enable_x64`` so the
+All solver math runs in float64 under a scoped ``jax.enable_x64`` so the
 device path tracks the numpy reference to its convergence thresholds
 (documented tolerance ≤ 1e-6; in practice ~1e-12 relative): the graph is
 tiny next to the voxel stages, and the iteration-count/convergence parity
@@ -52,8 +52,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import config
@@ -73,14 +71,34 @@ def bucket(n: int, minimum: int = 8) -> int:
     return 1 << int(np.ceil(np.log2(n)))
 
 
-def resolve_backend(explicit: str | None = None) -> str:
+def resolve_backend(explicit: str | None = None,
+                    needs_f64_lu: bool = False) -> str:
     """``device`` (the compiled solvers here, the default) or ``numpy``
     (the host reference paths): an explicit request wins, else the
     ``BST_SOLVE_DEVICE`` knob. The single owner of that policy — the
-    affine solver and the intensity solve must never drift apart on it."""
+    affine solver and the intensity solve must never drift apart on it.
+
+    ``needs_f64_lu``: the caller's program contains a float64
+    ``jnp.linalg.solve`` (the AFFINE model fit). XLA:TPU implements
+    LuDecomposition for F32 and C64 only — measured on a v5e with JAX
+    0.9.0 (scripts/chip_probe.py): the AFFINE relaxation fails to compile
+    there (UNIMPLEMENTED), while TRANSLATION and RIGID compile and agree
+    with numpy to 3e-12. So on a TPU such a solve is PLACED on the host
+    numpy solver (the test reference) by this rule, and says so — not by
+    catching the compiler's error. An explicit ``device`` request is
+    honoured and fails loudly."""
     if explicit:
         return explicit.lower()
-    return "device" if config.get_bool("BST_SOLVE_DEVICE") else "numpy"
+    if not config.get_bool("BST_SOLVE_DEVICE"):
+        return "numpy"
+    if needs_f64_lu and jax.default_backend() == "tpu":
+        from .. import observe
+
+        observe.log("solver: the AFFINE fit needs a float64 LU solve, which "
+                    "XLA:TPU does not implement — running the numpy solver "
+                    "on the host", stage="solver")
+        return "numpy"
+    return "device"
 
 
 def global_enabled() -> bool:
@@ -405,8 +423,12 @@ def _build_relax_fn(model: str, reg: str, T_pad: int, N_pad: int,
     """Compile (or fetch) the relax kernel for one shape bucket. Callers
     count warm/cold via ``record_compile_bucket`` at the call site."""
     if n_shards <= 1:
+        # the sharded program's psum is a fusion boundary between the
+        # moment sums and the fits; the same boundary here keeps XLA from
+        # fusing (and re-rounding) across it, so both layouts compile the
+        # same pieces and stay bit-identical
         kernel = _relax_core(model, reg, T_pad, L_pad, hist_cap, pw,
-                             lambda t: t)
+                             jax.lax.optimization_barrier)
         return jax.jit(kernel)
 
     mesh = _solve_mesh(n_shards, global_mesh)
@@ -425,14 +447,14 @@ def _build_relax_fn(model: str, reg: str, T_pad: int, N_pad: int,
 
     sharded = P(SOLVE_AXIS)
     rep = P()
-    return jax.jit(shard_map(
-        shard_kernel, mesh,
+    return jax.jit(jax.shard_map(
+        shard_kernel, mesh=mesh,
         in_specs=(sharded,) * 7 + (rep,) * 7,
         out_specs=rep,
         # outputs are replicated by construction (all post-psum math is
-        # identical on every device); the while_loop has no rep rule, so
-        # tell shard_map not to try proving it
-        check_rep=False,
+        # identical on every device); tell shard_map not to try proving
+        # it through the while_loop
+        check_vma=False,
     ))
 
 
@@ -488,7 +510,7 @@ def relax_on_device(
     fm[: problem.n_tiles] = np.asarray(fixed_mask, bool)
     wt = np.zeros((T_pad, 3))
     wt[: problem.n_tiles] = np.asarray(warm_t, np.float64)
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _build_relax_fn(model, reg, T_pad, problem.local.shape[-2],
                              L_pad, hist_cap, plateau_width,
                              problem.n_shards, problem.global_mesh)
@@ -597,10 +619,10 @@ def _build_cg_fn(n_unknowns: int, M_pad: int, S_pad: int, max_iter: int,
 
     sharded = P(SOLVE_AXIS)
     rep = P()
-    return jax.jit(shard_map(
-        shard_fn, mesh,
+    return jax.jit(jax.shard_map(
+        shard_fn, mesh=mesh,
         in_specs=(sharded,) * 8 + (rep,) * 8,
-        out_specs=rep, check_rep=False))
+        out_specs=rep, check_vma=False))
 
 
 def _cg_shapes(n_cells: int, n_rows: int, n_smooth: int,
@@ -698,7 +720,7 @@ def solve_intensity_device(
         max_iter_run = limit_iterations
     else:
         max_iter_run = max_iter
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _build_cg_fn(n_unknowns, M_pad, S_pad, max_iter, n_shards,
                           global_mesh)
         args = (ca, cb, *stats, spad[:, 0], spad[:, 1], wpad, dpad,

@@ -99,11 +99,8 @@ def _enable_cpu_collectives(jax_mod) -> None:
     BEFORE it initializes — without it a multi-process CPU world raises
     "Multiprocess computations aren't implemented on the CPU backend" at
     the first psum. Harmless on accelerator platforms (the flag only
-    affects XLA:CPU) and on jax builds without the option."""
-    try:
-        jax_mod.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
+    affects XLA:CPU)."""
+    jax_mod.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def _relay_bringup() -> None:

@@ -34,26 +34,25 @@ _HIGHWATER = _metrics.gauge("bst_inflight_bytes_highwater")
 _LOCK = threading.Lock()
 
 
-def _derived_budget(device=None) -> tuple[int, str]:
+def derived_budget(device=None) -> tuple[int, str]:
     """(budget bytes, source) with source ``"env"`` (the process-wide
     ``BST_INFLIGHT_BYTES``), ``"stats"`` (the device's own
     ``memory_stats``, genuinely per device) or ``"fallback"`` (the
-    backend reported nothing)."""
+    backend reports no memory stats — XLA:CPU). ``InflightWindow``
+    records the source of every window it opens, so a TPU run that sized
+    its windows from the CPU constant is visible in its manifest."""
     env = config.get_bytes("BST_INFLIGHT_BYTES")
     if env is not None:
         return env, "env"
-    try:
-        import jax
+    import jax
 
-        if device is None:
-            device = jax.local_devices()[0]
-        stats = device.memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            free = limit - int(stats.get("bytes_in_use", 0))
-            return max(256 << 20, int(_FREE_FRACTION * free)), "stats"
-    except Exception:
-        pass
+    if device is None:
+        device = jax.local_devices()[0]
+    stats = device.memory_stats() or {}
+    limit = int(stats.get("bytes_limit", 0))
+    if limit > 0:
+        free = limit - int(stats.get("bytes_in_use", 0))
+        return max(256 << 20, int(_FREE_FRACTION * free)), "stats"
     return DEFAULT_BUDGET, "fallback"
 
 
@@ -65,24 +64,25 @@ def dispatch_budget_bytes(device=None) -> int:
     scaled by a safety fraction; otherwise ``DEFAULT_BUDGET``. Per-device
     callers (the pair scheduler's one-window-per-device workers) pass
     their own device so each window sizes to its own HBM."""
-    return _derived_budget(device)[0]
+    return derived_budget(device)[0]
 
 
-def pair_budget_bytes(device=None, n_local: int = 1) -> int:
-    """Per-device in-flight budget for one of ``n_local`` concurrent pair
-    scheduler workers: ``BST_PAIR_INFLIGHT_BYTES`` wins verbatim (it is
-    defined per device); a ``memory_stats``-derived budget is genuinely
+def pair_budget(device=None, n_local: int = 1) -> tuple[int, str]:
+    """(budget bytes, source) of the per-device in-flight budget for one
+    of ``n_local`` concurrent pair scheduler workers:
+    ``BST_PAIR_INFLIGHT_BYTES`` wins verbatim (it is defined per device,
+    source ``"pair_env"``); a ``memory_stats``-derived budget is genuinely
     per device and used as is; the process-wide knobs (the
     ``BST_INFLIGHT_BYTES`` env, the no-stats fallback) are SPLIT across
     the workers — N workers must not each claim the whole process
     budget."""
     env = config.get_bytes("BST_PAIR_INFLIGHT_BYTES")
     if env is not None:
-        return env
-    budget, source = _derived_budget(device)
+        return env, "pair_env"
+    budget, source = derived_budget(device)
     if source != "stats":
         budget = max(1, budget // max(n_local, 1))
-    return budget
+    return budget, source
 
 
 class InflightWindow:
@@ -90,11 +90,19 @@ class InflightWindow:
 
     ``charge``/``release`` keep a per-window total and feed the
     process-wide current/high-water gauges, so artifacts record how close
-    the window ran to its budget."""
+    the window ran to its budget. Opening a window records the budget it
+    was sized with and where that came from (``derived_budget``'s sources,
+    ``"pair_env"``, or ``"caller"`` for a budget handed in) — the run
+    manifest's ``metrics`` carry these series, so what a run's windows
+    were really given is read there, not re-derived afterwards."""
 
-    def __init__(self, budget: int | None = None):
-        self.budget = dispatch_budget_bytes() if budget is None else budget
+    def __init__(self, budget: int | None = None, source: str = "caller"):
+        if budget is None:
+            budget, source = derived_budget()
+        self.budget = budget
         self.inflight = 0
+        _metrics.counter("bst_inflight_windows_total", source=source).inc()
+        _metrics.gauge("bst_inflight_budget_bytes", source=source).set(budget)
 
     def fits(self, nbytes: int) -> bool:
         """Whether one more dispatch of ``nbytes`` stays inside the budget.

@@ -9,8 +9,11 @@
 # disjoint, so no cross-host traffic happens outside the stage barriers.
 #
 # Usage:
-#   # all N processes on THIS machine (single node, N runtimes):
-#   scripts/pod_launch.sh -n 4 -- affine-fusion -o /data/fused.zarr
+#   # all N processes on THIS machine — the CPU test world only
+#   # (JAX_PLATFORMS=cpu): a TPU chip belongs to one process, so on a TPU
+#   # host every one of N local processes would claim every chip. ONE
+#   # process drives all the chips of a TPU host; just run `bst <tool>`.
+#   JAX_PLATFORMS=cpu scripts/pod_launch.sh -n 4 -- affine-fusion -o /data/fused.zarr
 #
 #   # one process per host on a cluster (run on every host, ids 0..N-1):
 #   scripts/pod_launch.sh -n 4 -c head-node:8476 -i $HOST_ID -- \

@@ -293,14 +293,49 @@ class TestPairScheduler:
                                                             monkeypatch):
         # BST_INFLIGHT_BYTES is process-wide: N workers split it;
         # BST_PAIR_INFLIGHT_BYTES is per device: taken verbatim
-        from bigstitcher_spark_tpu.utils.devicemem import pair_budget_bytes
+        from bigstitcher_spark_tpu.utils.devicemem import pair_budget
 
         monkeypatch.delenv("BST_PAIR_INFLIGHT_BYTES", raising=False)
         monkeypatch.setenv("BST_INFLIGHT_BYTES", "8000")
-        assert pair_budget_bytes(None, 8) == 1000
-        assert pair_budget_bytes(None, 1) == 8000
+        assert pair_budget(None, 8) == (1000, "env")
+        assert pair_budget(None, 1) == (8000, "env")
         monkeypatch.setenv("BST_PAIR_INFLIGHT_BYTES", "500")
-        assert pair_budget_bytes(None, 8) == 500
+        assert pair_budget(None, 8) == (500, "pair_env")
+
+    def test_windows_record_the_budget_they_were_given(self, monkeypatch):
+        # the manifest reads what each window was sized with, and from
+        # where, off these series — not a value re-derived afterwards
+        from bigstitcher_spark_tpu.observe import metrics
+        from bigstitcher_spark_tpu.parallel.pairsched import (
+            PairTask, run_pair_tasks,
+        )
+        from bigstitcher_spark_tpu.utils.devicemem import InflightWindow
+
+        def run(source, budget_bytes=None):
+            base = metrics.get_registry().snapshot()
+            run_pair_tasks([PairTask(index=i) for i in range(8)],
+                           lambda t: t.index, lambda ts, hs: list(hs),
+                           stage="sched-record-test", n_devices=2,
+                           budget_bytes=budget_bytes)
+            d = metrics.get_registry().snapshot_delta(base)
+            opened = {k.split('"')[1]: v for k, v in d.items() if v
+                      and k.startswith("bst_inflight_windows_total")}
+            assert opened == {source: 2}    # one window per worker
+            return d[f'bst_inflight_budget_bytes{{source="{source}"}}']
+
+        monkeypatch.delenv("BST_INFLIGHT_BYTES", raising=False)
+        monkeypatch.setenv("BST_PAIR_INFLIGHT_BYTES", "500")
+        assert run("pair_env") == 500
+        monkeypatch.delenv("BST_PAIR_INFLIGHT_BYTES")
+        monkeypatch.setenv("BST_INFLIGHT_BYTES", "8000")
+        assert run("env") == 4000   # the process-wide knob, split in two
+        assert run("caller", budget_bytes=77) == 77
+        monkeypatch.delenv("BST_INFLIGHT_BYTES")
+        base = metrics.get_registry().snapshot()
+        w = InflightWindow()    # XLA:CPU reports no memory stats
+        d = metrics.get_registry().snapshot_delta(base)
+        assert d['bst_inflight_windows_total{source="fallback"}'] == 1
+        assert d['bst_inflight_budget_bytes{source="fallback"}'] == w.budget
 
     def test_batched_drain_failure_isolates_to_offender(self):
         # a host-side error in a batched segment drain must fall back to

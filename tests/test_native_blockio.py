@@ -9,10 +9,6 @@ import pytest
 
 from bigstitcher_spark_tpu.io import native_blockio
 
-pytestmark = pytest.mark.skipif(
-    not native_blockio.available(), reason="native blockio not built"
-)
-
 
 def test_roundtrip_dtypes(tmp_path):
     rng = np.random.default_rng(0)
@@ -85,10 +81,6 @@ class TestNativeZarrChunks:
         from bigstitcher_spark_tpu.io import native_blockio
         from bigstitcher_spark_tpu.io.chunkstore import ChunkStore, StorageFormat
 
-        if not native_blockio.has_zarr():
-            import pytest
-
-            pytest.skip("native lib not built")
         st = ChunkStore.create(str(tmp_path / "z.zarr"), StorageFormat.ZARR)
         ds = st.create_dataset("0", (130, 96, 40, 2, 2), (64, 64, 32, 1, 1),
                                "uint16")
@@ -118,10 +110,6 @@ class TestNativeZarrChunks:
         from bigstitcher_spark_tpu.io import native_blockio
         from bigstitcher_spark_tpu.io.chunkstore import ChunkStore, StorageFormat
 
-        if not native_blockio.has_zarr():
-            import pytest
-
-            pytest.skip("native lib not built")
         rng = np.random.default_rng(3)
         v = rng.integers(0, 4000, (32, 24, 16), dtype=np.uint16)
         outs = {}
@@ -214,3 +202,44 @@ class TestLz4Codec:
         np.testing.assert_array_equal(ds2.read_full(), data)
         np.testing.assert_array_equal(ds2.read((16, 8, 4), (20, 20, 20)),
                                       data[16:36, 8:28, 4:24])
+
+
+class TestBuildOnDemand:
+    """libblockio.so is a gitignored build product: a clean checkout must
+    build it from native/blockio.cpp, rebuild it when the source is newer
+    (never load a stale library), and fail loudly — not fall back — when
+    the build breaks."""
+
+    SRC = native_blockio._SRC_DIR
+
+    def _fresh(self, monkeypatch, tmp_path, source: str):
+        d = tmp_path / "native"
+        d.mkdir()
+        (d / "Makefile").write_text(
+            open(os.path.join(self.SRC, "Makefile")).read())
+        (d / "blockio.cpp").write_text(source)
+        monkeypatch.setattr(native_blockio, "_LIB", None)
+        monkeypatch.setattr(native_blockio, "_SRC_DIR", str(d))
+        return d
+
+    def test_builds_when_missing_and_rebuilds_when_stale(self, monkeypatch,
+                                                         tmp_path):
+        d = self._fresh(monkeypatch, tmp_path, open(
+            os.path.join(self.SRC, "blockio.cpp")).read())
+        assert not native_blockio.loaded()
+        native_blockio._load()
+        so = d / "libblockio.so"
+        assert so.exists() and native_blockio.loaded()
+        built = so.stat().st_mtime_ns
+        # a source newer than the library forces a rebuild on next load
+        os.utime(d / "blockio.cpp", ns=(built + 10**9, built + 10**9))
+        monkeypatch.setattr(native_blockio, "_LIB", None)
+        native_blockio._load()
+        assert so.stat().st_mtime_ns > built
+        assert not list(d.glob("*.tmp"))
+
+    def test_broken_build_raises(self, monkeypatch, tmp_path):
+        self._fresh(monkeypatch, tmp_path, "this is not C++\n")
+        with pytest.raises(RuntimeError, match="BST_NATIVE_IO=0"):
+            native_blockio._load()
+        assert not native_blockio.loaded()

@@ -122,6 +122,29 @@ class TestRelaxParity:
         with config.overrides({"BST_SOLVE_DEVICE": True}):
             assert S._resolve_backend(params) == "device"
 
+    def test_tpu_places_the_f64_lu_solve_on_the_host(self, monkeypatch,
+                                                     capsys):
+        """XLA:TPU has no float64 LuDecomposition (measured, PR 21): the
+        AFFINE fit is placed on the numpy solver by rule and says so; the
+        models that compile stay on the device; an explicit request is
+        honoured."""
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert S._resolve_backend(S.SolverParams(model=M.AFFINE)) == "numpy"
+        assert "float64 LU" in capsys.readouterr().out
+        assert S._resolve_backend(S.SolverParams(
+            model=M.RIGID, regularization=M.AFFINE)) == "numpy"
+        capsys.readouterr()
+        for model in (M.TRANSLATION, M.RIGID):
+            assert S._resolve_backend(
+                S.SolverParams(model=model)) == "device"
+        assert S._resolve_backend(S.SolverParams(
+            model=M.RIGID, regularization=M.AFFINE, lam=0.0)) == "device"
+        assert S._resolve_backend(S.SolverParams(
+            model=M.AFFINE, backend="device")) == "device"
+        assert capsys.readouterr().out == ""
+
     def test_empty_links_identity(self):
         tiles, _ = _graph(n=(2, 1))
         res = S.relax([], tiles, {tiles[0]},

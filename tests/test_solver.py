@@ -2,6 +2,8 @@
 grids with known ground truth (exceeds the reference's manual smoke tests,
 per SURVEY.md §4 implication)."""
 
+import json
+
 import numpy as np
 import pytest
 from click.testing import CliRunner
@@ -303,14 +305,21 @@ def stitched_project(tmp_path_factory):
     return proj
 
 
-def test_solver_cli_end_to_end(stitched_project):
+def test_solver_cli_end_to_end(stitched_project, tmp_path):
     proj = stitched_project
     runner = CliRunner()
     res = runner.invoke(cli, [
         "solver", "-x", proj.xml_path, "-s", "STITCHING",
         "-tm", "TRANSLATION", "--method", "ONE_ROUND_ITERATIVE",
+        "--telemetry-dir", str(tmp_path / "tel"),
     ], catch_exceptions=False)
     assert res.exit_code == 0, res.output
+    # the run manifest's stage table says where the relaxation ran: the
+    # placement ops.solve.resolve_backend chose, or the one asked for
+    with open(tmp_path / "tel" / "manifest-00000-of-00001.json") as f:
+        rec, = [r for r in json.load(f)["stages"] if r["stage"] == "solver"]
+    assert rec["backend"] == "device" and rec["model"] == "TRANSLATION"
+    assert rec["tiles"] == 4 and rec["links"] > 0
     sd = SpimData.load(proj.xml_path)
     # after solving, every tile's world position should match truth up to
     # the global offset of the fixed tile
