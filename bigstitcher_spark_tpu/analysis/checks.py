@@ -54,7 +54,7 @@ Checks
 
 ``span-name``
     The trace/span twin of ``metric-name``: every name passed to
-    ``profiling.span`` / ``trace.span`` / ``trace.instant`` must be a
+    ``profiling.span`` / ``trace.instant`` / ``trace.record`` must be a
     literal declared once in ``observe/metric_names.py``'s ``SPANS``
     table. Dynamic span-name construction is banned outright — a
     constructed name fractures both the span aggregates and the
@@ -732,7 +732,7 @@ def check_metric_names(files: list[FileCtx]) -> list[Finding]:
 # call sites that name a span/trace series: <module>.<fn> where the fn is
 # a recorder entry point — matched by the LAST TWO dotted components so
 # both `profiling.span(...)` and an aliased `_trace.instant(...)` resolve
-_SPAN_FNS = {"span": ("profiling", "trace", "_trace"),
+_SPAN_FNS = {"span": ("profiling",),
              "instant": ("trace", "_trace"),
              "record": ("trace", "_trace")}
 # the declaring/implementing modules are exempt (they manipulate names)

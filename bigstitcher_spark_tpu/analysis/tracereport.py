@@ -14,6 +14,12 @@ the ``telemetry-merge`` fold of a pod run) into exactly those numbers:
   frontier question (ROADMAP "Known gap");
 - per-track (device / writer thread) busy/idle and the largest idle
   gaps — the scheduler-shaped holes items 2–3 must fill;
+- the **device's own busy and idle time** where the trace carries
+  ``device N (XLA)`` tracks (``--trace-device``), each long gap named
+  after the span open on every host thread meanwhile (innermost by the
+  events' ``parent``); without those tracks every "compute" number is
+  host time inside dispatch and sync calls and is labelled
+  **host-inferred**;
 - the **critical path**: per-item causal chains reassembled from the
   events' work-item identity, the chain that finishes last, and its
   top-k blocking segments by duration.
@@ -39,8 +45,10 @@ def _category(name: str) -> str:
         return "compute"
     if name.endswith(".prefetch") or name.endswith(".extract"):
         return "read"
-    if name.endswith(".h2d_tiles"):
+    if name.endswith(".h2d_tiles") or name.endswith(".h2d"):
         return "h2d"
+    if name.endswith((".pack", ".plan", ".store")):
+        return "host"
     return "other"
 
 
@@ -82,13 +90,17 @@ def load_events(path: str) -> tuple[list[dict], dict]:
     events: list[dict] = []
     meta: dict = {"files": [os.path.basename(p) for p in paths],
                   "recorded": 0, "dropped": 0,
-                  "unaligned_processes": []}
+                  "unaligned_processes": [], "clock": {}}
     for p in paths:
         with open(p, encoding="utf-8") as f:
             doc = json.load(f)
         b = doc.get("bst", {})
         meta["recorded"] += int(b.get("recorded") or 0)
         meta["dropped"] += int(b.get("dropped") or 0)
+        # the device-trace clock join of a --trace-device run
+        meta["clock"].update({k: v for k, v in b.items()
+                              if k.startswith("clock_")
+                              and not isinstance(v, dict)})
         meta["unaligned_processes"] += b.get("unaligned_processes") or []
         events.extend(doc.get("traceEvents", ()))
     # concatenating several PER-PROCESS traces puts unaligned host clocks
@@ -122,7 +134,8 @@ def build_intervals(events: list[dict]) -> tuple[list[dict], dict]:
             out.append({"name": ev.get("name"), "start": ts,
                         "end": ts + float(ev.get("dur", 0.0)) / 1e6,
                         "pid": key[0], "tid": key[1],
-                        "args": ev.get("args") or {}})
+                        "args": ev.get("args") or {},
+                        "xla": _is_xla(ev)})
         elif ph == "B":
             stacks.setdefault(key, []).append((ts, ev.get("args") or {}))
         else:
@@ -188,21 +201,144 @@ def analyze(path: str, top: int = 5) -> dict:
     return rep
 
 
+def _is_xla(ev: dict) -> bool:
+    """An event of a ``device N (XLA)`` track (observe/devicetrace.py)."""
+    return str(ev.get("cat", "")).startswith("xla.")
+
+
+def _open_on_each_thread(intervals: list[dict], track_names: dict,
+                         at: float) -> dict:
+    """{host thread: innermost span open at ``at``}. Innermost by the
+    events' ``parent``: of the spans of one thread that hold the instant,
+    the one that is no other's parent (traces older than the ids: the one
+    that began last)."""
+    holding: dict[tuple, list[dict]] = {}
+    for iv in intervals:
+        # a span attributed to a device is drawn on that device's ring
+        # track, not on the thread that ran it: no host thread's answer
+        if iv["start"] <= at <= iv["end"] and "device" not in iv["args"]:
+            holding.setdefault((iv["pid"], iv["tid"]), []).append(iv)
+    out = {}
+    for key, ivs in holding.items():
+        parents = {iv["args"].get("parent") for iv in ivs}
+        leaves = [iv for iv in ivs if iv["args"].get("id") not in parents
+                  or iv["args"].get("id") is None]
+        out[track_names.get(key) or f"tid {key[1]}"] = max(
+            leaves or ivs, key=lambda iv: iv["start"])["name"]
+    return out
+
+
+def _device_report(xla: list[dict], host: list[dict], track_names: dict,
+                   t0: float, t1: float, top: int) -> list[dict]:
+    """Per device: busy (the union of its XLA ops) and idle over the
+    trace's wall clock, its modules, and its longest gaps with what every
+    host thread was in meanwhile."""
+    by_dev: dict[str, dict] = {}
+    for iv in xla:
+        d = by_dev.setdefault(str(iv["args"].get("device")),
+                              {"busy": [], "modules": {}})
+        if iv["name"] == "xla.busy":
+            d["busy"].append(iv)
+        else:
+            m = d["modules"].setdefault(iv["name"], [0, 0.0])
+            m[0] += 1
+            m[1] += iv["end"] - iv["start"]
+    out = []
+    for dev, d in sorted(by_dev.items()):
+        busy = [(max(a, t0), min(b, t1)) for a, b in _union(d["busy"])
+                if min(b, t1) > max(a, t0)]
+        edges = [t0] + [x for ab in busy for x in ab] + [t1]
+        gaps = sorted(((b - a, a) for a, b in zip(edges[0::2], edges[1::2])
+                       if b > a), reverse=True)[:max(1, top)]
+        out.append({
+            "device": dev,
+            "busy_s": round(_total(busy), 6),
+            "busy_pct": round(100.0 * _total(busy) / (t1 - t0), 2)
+            if t1 > t0 else 0.0,
+            "modules": {k: [n, round(s, 6)]
+                        for k, (n, s) in sorted(d["modules"].items())},
+            "largest_gaps": [
+                {"seconds": round(g, 6), "at_s": round(a - t0, 6),
+                 "open": _open_on_each_thread(host, track_names, a + g / 2)}
+                for g, a in gaps]})
+    return out
+
+
+def span_tree(intervals: list[dict]) -> list[dict]:
+    """The spans as a tree, by the events' ``id``/``parent``: one row per
+    path of names from a root, with count, total seconds and SELF seconds
+    (a span's duration less the union of its direct children's intervals,
+    whatever thread they ran on). Rows come parents first, siblings by
+    total time. Empty for a trace recorded before the ids."""
+    by_id = {iv["args"]["id"]: iv for iv in intervals
+             if iv["args"].get("id") is not None}
+    kids: dict = {}
+    for iv in by_id.values():
+        kids.setdefault(iv["args"].get("parent"), []).append(iv)
+    rows: dict[tuple, list] = {}
+
+    def walk(iv, path):
+        path = path + (iv["name"],)
+        mine = kids.get(iv["args"]["id"], [])
+        covered = _total(_union(
+            [{"start": max(c["start"], iv["start"]),
+              "end": min(c["end"], iv["end"])} for c in mine
+             if min(c["end"], iv["end"]) > max(c["start"], iv["start"])]))
+        row = rows.setdefault(path, [0, 0.0, 0.0])
+        row[0] += 1
+        row[1] += iv["end"] - iv["start"]
+        row[2] += iv["end"] - iv["start"] - covered
+        for c in mine:
+            walk(c, path)
+
+    for iv in by_id.values():
+        if iv["args"].get("parent") not in by_id:
+            walk(iv, ())
+    out = []
+
+    def emit(prefix):
+        below = [p for p in rows if p[:-1] == prefix]
+        for p in sorted(below, key=lambda p: -rows[p][1]):
+            n, total, self_s = rows[p]
+            out.append({"path": list(p), "count": n,
+                        "total_s": round(total, 6),
+                        "self_s": round(self_s, 6),
+                        "leaf": not any(q[:-1] == p for q in rows)})
+            emit(p)
+
+    emit(())
+    return out
+
+
 def build_report(events: list[dict], meta: dict | None = None,
                  top: int = 5) -> dict:
     intervals, track_names = build_intervals(events)
+    # the device's own timeline is reported apart: it is no host span
+    xla = [iv for iv in intervals if iv.get("xla")]
+    intervals = [iv for iv in intervals if not iv.get("xla")]
     rep: dict = {"events": len([e for e in events
                                 if e.get("ph") in ("B", "E", "X", "i")]),
                  "intervals": len(intervals),
                  "recorded": (meta or {}).get("recorded", 0),
                  "dropped": (meta or {}).get("dropped", 0),
                  "stages": {}, "tracks": [],
-                 "critical_path": None, "top_blocking": []}
+                 "critical_path": None, "top_blocking": [],
+                 "device_source": "xla" if xla else "host-inferred",
+                 "clock": (meta or {}).get("clock") or {}}
     if not intervals:
         return rep
     t0 = min(iv["start"] for iv in intervals)
     t1 = max(iv["end"] for iv in intervals)
     rep["wall_s"] = round(t1 - t0, 6)
+    # what the host alone would say of the device: the union of the spans
+    # around dispatch and sync calls, over the wall clock
+    rep["host_inferred_compute_pct"] = _pct(_total(_union(
+        [iv for iv in intervals if _category(iv["name"]) == "compute"])),
+        t1 - t0)
+    if xla:
+        rep["devices"] = _device_report(xla, intervals, track_names,
+                                        t0, t1, top)
+    rep["span_tree"] = span_tree(intervals)
 
     # -- per-stage category decomposition + pairwise overlap ---------------
     by_group: dict[str, list[dict]] = {}
@@ -213,7 +349,8 @@ def build_report(events: list[dict], meta: dict | None = None,
         g1 = max(iv["end"] for iv in ivs)
         wall = g1 - g0
         unions = {}
-        for cat in ("compute", "d2h", "write", "read", "h2d", "other"):
+        for cat in ("compute", "d2h", "write", "read", "h2d", "host",
+                    "other"):
             unions[cat] = _union([iv for iv in ivs
                                   if _category(iv["name"]) == cat])
         busy = _union(ivs)
@@ -223,7 +360,7 @@ def build_report(events: list[dict], meta: dict | None = None,
             "idle_pct": _pct(max(0.0, wall - _total(busy)), wall),
             "overlap": {},
         }
-        for cat in ("compute", "d2h", "write", "read", "h2d"):
+        for cat in ("compute", "d2h", "write", "read", "h2d", "host"):
             tot = _total(unions[cat])
             if tot:
                 entry[f"{cat}_s"] = round(tot, 6)
@@ -306,11 +443,43 @@ def render_report(rep: dict) -> str:
         f"{rep['intervals']} interval(s) from {rep['events']} event(s)"
         + (f", {rep['dropped']} DROPPED by ring overflow"
            if rep.get("dropped") else ""))
+    # the device's own numbers, where the trace has them; the host's
+    # guess is printed beside them, and alone it is called what it is
+    inferred = rep.get("device_source") != "xla"
+    for d in rep.get("devices", ()):
+        lines.append(
+            f"device {d['device']} (XLA): busy {d['busy_s']:.3f}s "
+            f"({d['busy_pct']:.2f}% of the wall clock), idle "
+            f"{100.0 - d['busy_pct']:.2f}% | host-inferred compute "
+            f"{rep.get('host_inferred_compute_pct', 0.0):.1f}%")
+        for name, (n, s) in d["modules"].items():
+            lines.append(f"  module {name}: {n} call(s), {s:.6f}s")
+        for g in d["largest_gaps"]:
+            held = ", ".join(f"{t}={n}" for t, n in sorted(g["open"].items()))
+            lines.append(f"  gap {g['seconds']:.3f}s @{g['at_s']:.3f}s: "
+                         + (held or "(no span open)"))
+    clock = rep.get("clock") or {}
+    if clock.get("clock_anchors"):
+        lines.append(
+            f"clock join: {clock['clock_anchors']} span anchors, residual "
+            f"p95 {clock.get('clock_residual_us', 0.0):.1f}us (max "
+            f"{clock.get('clock_residual_max_us', 0.0):.1f}us), drift "
+            f"{clock.get('clock_drift_us', 0.0):.1f}us first tenth to last")
+    if inferred and rep["stages"]:
+        lines.append(
+            "device numbers below are host-inferred: this trace has no "
+            "device (XLA) tracks, so 'compute' is host time inside "
+            "dispatch and sync calls "
+            f"({rep.get('host_inferred_compute_pct', 0.0):.1f}% of the "
+            "wall clock) — record with --trace-device for the device's own")
     for group, e in rep["stages"].items():
         parts = []
-        for cat, label in (("compute", "compute"), ("d2h", "d2h"),
+        # a stage's "compute" is the host's clock around dispatch and
+        # sync calls whether or not the device's own tracks are there
+        for cat, label in (("compute", "compute (host-inferred)"),
+                           ("d2h", "d2h"),
                            ("write", "write"), ("read", "read"),
-                           ("h2d", "h2d")):
+                           ("h2d", "h2d"), ("host", "host")):
             if f"{cat}_s" in e:
                 parts.append(f"{label} {e[f'{cat}_s']:.3f}s "
                              f"({e[f'{cat}_pct']:.0f}%)")
@@ -331,6 +500,18 @@ def render_report(rep: dict) -> str:
             lines.append(f"  p{t['pid']} {t['name']}: busy {t['busy_s']:.3f}s"
                          f" ({t['util_pct']:.0f}% of its {t['span_s']:.3f}s"
                          f" span), largest gaps: {gaps}")
+    if rep.get("span_tree"):
+        lines.append("span tree (count, total, self = total less the "
+                     "union of the children):")
+        for row in rep["span_tree"]:
+            depth = len(row["path"]) - 1
+            label = "  " * depth + row["path"][-1]
+            lines.append(f"  {label:<40} {row['count']:>6} "
+                         f"{row['total_s']:>10.3f}s "
+                         f"self {row['self_s']:>9.3f}s"
+                         + (f" ({_pct(row['self_s'], row['total_s']):.1f}%"
+                            " of the root has no named child)"
+                            if depth == 0 and not row["leaf"] else ""))
     cp = rep.get("critical_path")
     if cp:
         lines.append(f"critical path [{cp['stage']} item {cp['item']}]: "

@@ -76,10 +76,15 @@ def _set_profile(ctx, param, value):
 
 
 def _set_trace(ctx, param, value):
+    # --trace-device implies --trace; whichever click resolves first
+    # configures the recorder, and the device session is started at most
+    # once
     if value:
         from ..observe import trace
 
-        trace.configure()
+        device = param.name == "trace_device"
+        if not trace.enabled() or (device and not trace.device_session()):
+            trace.configure(device=device)
         _register_telemetry_close(ctx)
     return value
 
@@ -112,6 +117,16 @@ def infrastructure_options(f):
                           "exit (next to --telemetry-dir files when set, "
                           "else BST_TRACE_PATH / ./bst-trace.json); "
                           "analyze with 'bst trace-report'")(f)
+    f = click.option("--trace-device", "trace_device", is_flag=True,
+                     default=False, expose_value=False,
+                     callback=_set_trace,
+                     help="--trace, plus a JAX profiler session (python "
+                          "tracer off) for the command's length: the "
+                          "trace file gains 'device N (XLA)' tracks (XLA "
+                          "module events, the union of XLA ops) on the "
+                          "host spans' clock, and 'bst trace-report' then "
+                          "reads device busy and idle from the device "
+                          "itself")(f)
     return f
 
 

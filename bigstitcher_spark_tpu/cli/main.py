@@ -26,6 +26,12 @@ from . import (
 )
 
 
+# tools whose first act is device work: for these the backend's start is
+# stamped (observe/process.py) where it would happen anyway. Host-only
+# tools are left alone: bringing a TPU up costs them ten seconds
+_DEVICE_TOOLS = {"affine-fusion", "nonrigid-fusion", "stitching",
+                 "detect-interestpoints", "match-interestpoints"}
+
 # tools that must NOT auto-bind the BST_METRICS_PORT exporter: daemon
 # management and thin clients run on the same host as the daemon that
 # owns the port (the `bst serve --detach` parent or a `bst submit` would
@@ -48,10 +54,14 @@ def cli(ctx):
     # workload tools only — a short `bst submit`/`bst jobs` has nothing
     # live to push, and a `bst serve` daemon hosts the collector itself
     # inside Daemon.start()
+    from ..observe import process
     from ..parallel.distributed import init_distributed
 
+    process.imports_done()
     init_distributed(
         start_relay=ctx.invoked_subcommand not in _NO_LIVE_EXPORTER)
+    if ctx.invoked_subcommand in _DEVICE_TOOLS:
+        process.backend_ready()
     # live HTTP exporter for long one-shot runs: no-op unless
     # BST_METRICS_PORT is set (the serve daemon wires richer providers in)
     if ctx.invoked_subcommand not in _NO_LIVE_EXPORTER:

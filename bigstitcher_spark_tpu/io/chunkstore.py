@@ -19,6 +19,7 @@ import enum
 import json
 import os
 import shutil
+import time
 from dataclasses import dataclass
 from typing import Any, Sequence
 
@@ -59,14 +60,19 @@ def bump_remote_pin() -> int:
     _REMOTE_PIN[0] += 1
     return _REMOTE_PIN[0]
 
-# one (bytes, chunk-ops) counter pair per (op, path-taken) — cached so the
-# hot path pays one dict lookup + two lock'd adds per box read/write, which
-# also records WHICH implementation served it (native codec vs tensorstore
-# vs h5py), the tuning signal for the native-IO fast paths
+# one (bytes, chunk-ops, seconds) counter triple per (op, path-taken) —
+# cached so the hot path pays one dict lookup + a few lock'd adds per box
+# read/write, which also records WHICH implementation served it (native
+# codec vs tensorstore vs h5py), the tuning signal for the native-IO fast
+# paths
 _IO_COUNTERS: dict[tuple[str, str], tuple] = {}
 
 
-def _record_io(op: str, via: str, nbytes: int, dataset: str) -> None:
+def _record_io(op: str, via: str, nbytes: int, dataset: str,
+               seconds: float | None = None) -> None:
+    """``seconds``: what the fetch + decode of a read that missed the
+    decoded LRU, or the encode + store of a write, took on this thread
+    (a cache hit has none)."""
     pair = _IO_COUNTERS.get((op, via))
     if pair is None:
         # literal series names per op branch so every metric string is
@@ -75,13 +81,18 @@ def _record_io(op: str, via: str, nbytes: int, dataset: str) -> None:
         # silent zero-valued series)
         if op == "read":
             pair = (_metrics.counter("bst_io_read_bytes_total", path=via),
-                    _metrics.counter("bst_io_read_ops_total", path=via))
+                    _metrics.counter("bst_io_read_ops_total", path=via),
+                    _metrics.counter("bst_io_read_seconds_total", path=via))
         else:
             pair = (_metrics.counter("bst_io_write_bytes_total", path=via),
-                    _metrics.counter("bst_io_write_ops_total", path=via))
+                    _metrics.counter("bst_io_write_ops_total", path=via),
+                    _metrics.counter("bst_io_write_seconds_total",
+                                     path=via))
         _IO_COUNTERS[(op, via)] = pair
     pair[0].inc(int(nbytes))
     pair[1].inc()
+    if seconds is not None:
+        pair[2].inc(seconds)
     if _trace.enabled():
         # timeline marks with byte payload (literal names per branch —
         # the span-name lint check bans constructed names)
@@ -369,6 +380,7 @@ class Dataset:
                        (off[d] + shp[d] - 1) // block[d] + 1)
                  for d in range(ndim)]
         copied = {"cache": 0}
+        miss_s = None
 
         def fill(pos, chunk) -> int:
             lo = [pos[d] * block[d] for d in range(ndim)]
@@ -391,6 +403,7 @@ class Dataset:
             else:
                 copied["cache"] += fill(pos, chunk)
         if misses:
+            t0 = time.perf_counter()
             got = self._read_chunks(misses)
             if got is None:
                 return None  # no decode route: fall back (and re-read hits)
@@ -400,10 +413,12 @@ class Dataset:
                 cc.put((dkey, sig, pos), chunk)
                 nb += fill(pos, chunk)
             copied[via] = copied.get(via, 0) + nb
+            miss_s = time.perf_counter() - t0
         hooks = _DAG_HOOKS[0]
         for via, nb in copied.items():
             if nb:
-                _record_io("read", via, nb, self.path)
+                _record_io("read", via, nb, self.path,
+                           None if via == "cache" else miss_s)
                 if hooks is not None:
                     hooks.account_read(self, via, nb)
         return out
@@ -566,9 +581,11 @@ class Dataset:
             cached = self._cached_read(offset, shape)
             if cached is not None:
                 return cached
+        t0 = time.perf_counter()
         native = self._native_read(offset, shape)
         if native is not None:
-            _record_io("read", "native", native.nbytes, self.path)
+            _record_io("read", "native", native.nbytes, self.path,
+                       time.perf_counter() - t0)
             if hooks is not None:
                 hooks.account_read(self, "native", native.nbytes)
             return native
@@ -586,7 +603,8 @@ class Dataset:
             data = self._ts[sel]
             via = "h5py"
         data = np.asarray(data)
-        _record_io("read", via, data.nbytes, self.path)
+        _record_io("read", via, data.nbytes, self.path,
+                   time.perf_counter() - t0)
         if hooks is not None:
             hooks.account_read(self, via, data.nbytes)
         return data.transpose(tuple(range(data.ndim))[::-1]) if self.reversed_axes else data
@@ -685,9 +703,11 @@ class Dataset:
         return bool(fn(self, dev, offset))
 
     def _write_impl(self, data: np.ndarray, offset: Sequence[int]) -> None:
+        t0 = time.perf_counter()
         if (self._native_write(data, offset)
                 or self._native_write_zarr(data, offset)):
-            _record_io("write", "native", data.nbytes, self.path)
+            _record_io("write", "native", data.nbytes, self.path,
+                       time.perf_counter() - t0)
             return
         if self._ts is None:
             raise ValueError(
@@ -706,7 +726,8 @@ class Dataset:
         else:
             self._ts[sel] = data
             via = "h5py"
-        _record_io("write", via, data.nbytes, self.path)
+        _record_io("write", via, data.nbytes, self.path,
+                   time.perf_counter() - t0)
 
     def _multipart_write(self, data: np.ndarray,
                          offset: Sequence[int]) -> bool:
@@ -766,10 +787,12 @@ class Dataset:
 
         from ..parallel.retry import run_with_retry
 
+        t0 = time.perf_counter()
         run_with_retry(parts, put_one, max_retries=4, delay_s=0.25,
                        label="upload", verbose=False,
                        threads=min(int(threads), len(parts)))
-        _record_io("write", "tensorstore", data.nbytes, self.path)
+        _record_io("write", "tensorstore", data.nbytes, self.path,
+                   time.perf_counter() - t0)
         _REMOTE_WRITE_BYTES.inc(int(data.nbytes))
         return True
 

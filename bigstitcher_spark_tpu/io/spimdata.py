@@ -26,6 +26,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from .. import profiling
 from ..utils.geometry import (
     Interval,
     affine_from_flat,
@@ -185,6 +186,7 @@ class SpimData:
     # ------------------------------------------------------------------- load
 
     @staticmethod
+    @profiling.span("spimdata.load")
     def load(path: str | os.PathLike) -> "SpimData":
         """Load a project XML from a local path or cloud URI (the reference
         loads XMLs from file/S3/GCS via URITools, AbstractBasic.java:49-70)."""
@@ -335,6 +337,7 @@ class SpimData:
 
     # ------------------------------------------------------------------- save
 
+    @profiling.span("spimdata.save")
     def save(self, path: str | os.PathLike | None = None) -> None:
         if path is None:
             path = self.xml_path

@@ -37,6 +37,7 @@ _H2D_BYTES = _metrics.counter("bst_xfer_h2d_bytes_total")
 _D2H_BYTES = _metrics.counter("bst_xfer_d2h_bytes_total")
 _H2D_SAVED = _metrics.counter("bst_xfer_h2d_bytes_saved_total")
 _D2H_SAVED = _metrics.counter("bst_xfer_d2h_bytes_saved_total")
+_VOXELS_DONE = _metrics.counter("bst_fusion_voxels_total")
 _TILE_HITS = _metrics.counter("bst_tile_cache_hits_total")
 _TILE_MISSES = _metrics.counter("bst_tile_cache_misses_total")
 _TILE_HIT_BYTES = _metrics.counter("bst_tile_cache_hit_bytes_total")
@@ -197,10 +198,31 @@ def fuse_grid_block(
     ``coefficients``: optional per-view (cx,cy,cz,2) intensity-correction
     grids (BlkAffineFusion.initWithIntensityCoefficients role); forces the
     general gather kernel."""
+    dev_out = _fuse_grid_block_device(
+        sd, loader, views, block, bbox, fusion_type, blend, anisotropy,
+        patch_quantum, compute_block_shape, stats, inside_offset,
+        coefficients)
+    if dev_out is None:
+        return None
+    with profiling.span("fusion.d2h", item=tuple(map(int, block.offset))):
+        return _fetch_block(dev_out, block)
+
+
+def _fuse_grid_block_device(sd, loader, views, block, bbox,
+                            fusion_type="AVG_BLEND", blend=None,
+                            anisotropy=None, patch_quantum=32,
+                            compute_block_shape=None, stats=None,
+                            inside_offset=(0.0, 0.0, 0.0),
+                            coefficients=None):
+    """:func:`fuse_grid_block` up to the kernel's end: the DEVICE (fused,
+    wsum) of the static compute shape, or None when no view overlaps."""
+    bkey = tuple(map(int, block.offset))
     blend = blend or BlendParams()
     bshape = tuple(compute_block_shape or block.size)
-    block_global = Interval.from_shape(bshape, block.offset).translate(bbox.min)
-    plans = plan_block(sd, loader, views, block_global, anisotropy)
+    with profiling.span("fusion.plan", item=bkey):
+        block_global = Interval.from_shape(
+            bshape, block.offset).translate(bbox.min)
+        plans = plan_block(sd, loader, views, block_global, anisotropy)
     if not plans:
         return None
 
@@ -227,14 +249,43 @@ def fuse_grid_block(
     if stats is not None:
         stats.compile_keys.add((bshape, pshape, vb, fusion_type,
                                 coefficients is not None))
-    with profiling.span("fusion.kernel", item=tuple(map(int, block.offset))):
-        fused, wsum = F.fuse_block(
-            patches, affines, offsets, img_dims, borders, ranges, valid,
-            block_shape=bshape, fusion_type=fusion_type, inside_offs=ioffs,
-            coeffs=coeffs, coeff_affines=coeff_affs,
-        )
-        fused, wsum = jax.device_get((fused, wsum))
-    # crop the static compute shape back to the (possibly clipped) block
+    return _run_block_kernel(
+        F.fuse_block, bkey,
+        (patches, affines, offsets, img_dims, borders, ranges, valid),
+        dict(inside_offs=ioffs, coeffs=coeffs, coeff_affines=coeff_affs),
+        block_shape=bshape, fusion_type=fusion_type)
+
+
+def _run_block_kernel(kernel, bkey, args: tuple, kwargs: dict, **static):
+    """One block through its kernel with each leg bracketed: the explicit
+    upload of the staged inputs until it is done, then the call until its
+    outputs are ready. The per-block chain is serial anyway (a fetch
+    follows every call), so neither wait is new. Returns the DEVICE
+    (fused, wsum), their fetch already queued."""
+    staged = sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(
+        (args, kwargs)) if isinstance(x, np.ndarray))
+    with profiling.span("fusion.h2d", item=bkey, nbytes=staged):
+        # may_alias: the transfer reads the staged host buffers in place,
+        # as a jitted call's implicit upload does; device_put's default
+        # copies them first (a host memcpy of the whole patch stack)
+        args, kwargs = jax.block_until_ready(
+            jax.device_put((args, kwargs), may_alias=True))
+    _H2D_BYTES.inc(staged)
+    with profiling.span("fusion.kernel", item=bkey):
+        out = kernel(*args, **kwargs, **static)
+        # queue the fetch behind the kernel, as the device_get that used
+        # to follow the call did: its host buffers are then allocated and
+        # faulted in while the device works, not after it
+        for leaf in out:
+            leaf.copy_to_host_async()
+        return jax.block_until_ready(out)
+
+
+def _fetch_block(dev_out, block):
+    """Fetch one block's device (fused, wsum) and crop the static compute
+    shape back to the (possibly clipped) block."""
+    fused, wsum = jax.device_get(dev_out)
+    _D2H_BYTES.inc(int(fused.nbytes) + int(wsum.nbytes))
     sl = tuple(slice(0, s) for s in block.size)
     return fused[sl], wsum[sl]
 
@@ -475,14 +526,10 @@ def _fuse_sep_path(sd, loader, plans, block, bshape, fusion_type, blend,
      ) = _sep_inputs(sd, loader, plans, pshape, vb, blend, inside_offset)
     if stats is not None:
         stats.compile_keys.add((bshape, pshape, "sep", vb, fusion_type))
-    with profiling.span("fusion.kernel", item=tuple(map(int, block.offset))):
-        fused, wsum = F.fuse_block_sep(
-            patches, diags, ts, offsets, img_dims, borders, ranges, valid,
-            block_shape=bshape, fusion_type=fusion_type, inside_offs=ioffs,
-        )
-        fused, wsum = jax.device_get((fused, wsum))
-    sl = tuple(slice(0, s) for s in block.size)
-    return fused[sl], wsum[sl]
+    return _run_block_kernel(
+        F.fuse_block_sep, tuple(map(int, block.offset)),
+        (patches, diags, ts, offsets, img_dims, borders, ranges, valid),
+        dict(inside_offs=ioffs), block_shape=bshape, fusion_type=fusion_type)
 
 
 def _fuse_shift_path(loader, plans, block, block_global, bshape, fusion_type,
@@ -495,14 +542,10 @@ def _fuse_shift_path(loader, plans, block, block_global, bshape, fusion_type,
                        inside_offset)
     if stats is not None:
         stats.compile_keys.add((bshape, "shift", vb, fusion_type))
-    with profiling.span("fusion.kernel", item=tuple(map(int, block.offset))):
-        fused, wsum = F.fuse_block_shift(
-            patches, fracs, lpos0, img_dims, borders, ranges, valid,
-            block_shape=bshape, fusion_type=fusion_type, inside_offs=ioffs,
-        )
-        fused, wsum = jax.device_get((fused, wsum))
-    sl = tuple(slice(0, s) for s in block.size)
-    return fused[sl], wsum[sl]
+    return _run_block_kernel(
+        F.fuse_block_shift, tuple(map(int, block.offset)),
+        (patches, fracs, lpos0, img_dims, borders, ranges, valid),
+        dict(inside_offs=ioffs), block_shape=bshape, fusion_type=fusion_type)
 
 
 def device_tile_budget_bytes() -> int:
@@ -905,6 +948,8 @@ def _drain_device_volume(out, out_ds, zarr_ct, pyramid=(),
                 ds.write(data, (x0, 0, 0))
             if epi:
                 _EPI_WRITE_BYTES.inc(data.nbytes)
+            else:
+                _VOXELS_DONE.inc(int(data.size))
 
     with CtxThreadPool(max_workers=max(1, io_threads)) as pool:
         list(pool.map(drain, jobs))
@@ -920,6 +965,7 @@ def _write_block(out_ds, data, block, zarr_ct):
             out_ds.write(data[..., None, None], (*block.offset, c, t))
         else:
             out_ds.write(data, block.offset)
+    _VOXELS_DONE.inc(int(data.size))
 
 
 def _write_epilogue_block(ds, data, offset, zarr_ct):
@@ -1207,6 +1253,7 @@ def _record_fusion_stage(stage: str, stats: "FusionStats",
     )
 
 
+@profiling.span("fusion.stage")
 def fuse_volume(
     sd: SpimData,
     loader: ViewLoader,
@@ -1305,33 +1352,38 @@ def fuse_volume(
         return stats
 
     def process(block: GridBlock) -> None:
-        res = fuse_grid_block(
+        dev_out = _fuse_grid_block_device(
             sd, loader, views, block, bbox, fusion_type, blend, aniso,
             compute_block_shape=compute_block, stats=stats,
             inside_offset=mask_offset if masks else (0.0, 0.0, 0.0),
             coefficients=coefficients,
         )
         stats.blocks += 1
-        if res is None:
+        if dev_out is None:
             stats.skipped_empty += 1
             return
-        fused, wsum = res
         bkey = tuple(map(int, block.offset))
-        if masks:
-            out = (wsum > 0).astype(np.float32)
-            if out_dtype != "float32":
-                out *= float(np.iinfo(np.dtype(out_dtype)).max)
-            data = out.astype(out_dtype)
-        else:
-            out_nbytes = int(np.prod(block.size)
-                             * np.dtype(out_dtype).itemsize)
-            with profiling.span("fusion.d2h", item=bkey, nbytes=out_nbytes):
+        nvox = int(np.prod(block.size))
+        out_nbytes = nvox * np.dtype(out_dtype).itemsize
+        # every fetch of the block is one span: the float32 block and its
+        # weights come to the host, and the block goes up again to be
+        # converted to the output type and comes back a second time
+        with profiling.span("fusion.d2h", item=bkey, nbytes=out_nbytes):
+            fused, wsum = _fetch_block(dev_out, block)
+            if not masks:
                 data = jax.device_get(
                     F.convert_intensity(
                         fused, np.float32(min_intensity),
                         np.float32(max_intensity), out_dtype=out_dtype,
                     )
                 )
+                _H2D_BYTES.inc(int(fused.nbytes))
+                _D2H_BYTES.inc(int(data.nbytes))
+        if masks:
+            out = (wsum > 0).astype(np.float32)
+            if out_dtype != "float32":
+                out *= float(np.iinfo(np.dtype(out_dtype)).max)
+            data = out.astype(out_dtype)
         with profiling.span("fusion.write", item=bkey,
                             nbytes=int(data.nbytes)):
             if zarr_ct is not None:
@@ -1340,7 +1392,8 @@ def fuse_volume(
                 out_ds.write(out5, (*block.offset, c, t))
             else:
                 out_ds.write(data, block.offset)
-        stats.voxels += int(np.prod(block.size))
+        stats.voxels += nvox
+        _VOXELS_DONE.inc(nvox)
         if progress:
             observe.log(f"  block {block.offset} done ({len(grid)} total)",
                         stage="affine-fusion")

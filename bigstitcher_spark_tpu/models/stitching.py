@@ -43,6 +43,7 @@ from ..observe import metrics as _metrics
 
 _H2D_BYTES = _metrics.counter("bst_xfer_h2d_bytes_total")
 _H2D_SAVED = _metrics.counter("bst_xfer_h2d_bytes_saved_total")
+_PAIRS_DONE = _metrics.counter("bst_stitching_pairs_total")
 
 
 @dataclass
@@ -330,6 +331,7 @@ def _fft_shape(shape: Sequence[int]) -> tuple[int, ...]:
     return tuple(1 << max(0, int(np.ceil(np.log2(max(int(s), 1))))) for s in shape)
 
 
+@profiling.span("stitching.stage")
 def stitch_all_pairs(
     sd: SpimData,
     loader: ViewLoader,
@@ -343,8 +345,9 @@ def stitch_all_pairs(
     Returns unfiltered results; apply ``filter_results`` + store into
     ``sd.stitching_results`` (the driver-side collect of the reference)."""
     params = params or StitchingParams()
-    groups = build_groups(sd, views)
-    pairs = plan_pairs(sd, groups)
+    with profiling.span("stitching.plan"):
+        groups = build_groups(sd, views)
+        pairs = plan_pairs(sd, groups)
     observe.log(f"stitching: {len(groups)} groups, {len(pairs)} overlapping "
                 "pairs", stage="stitching", echo=progress,
                 groups=len(groups), pairs=len(pairs))
@@ -479,19 +482,20 @@ def _as_uint16_lossless(stack: np.ndarray) -> np.ndarray | None:
 
 
 def _dispatch_bucket(jobs: list[_PairJob], shp, params):
-    a = np.stack([pad_to(j.crop_a, shp) for j in jobs])
-    b = np.stack([pad_to(j.crop_b, shp) for j in jobs])
-    # lossless h2d downcast, decided ONCE for both stacks so the jitted
-    # kernel sees only two dtype signatures (u16/u16 or f32/f32) per
-    # shape bucket: halves the bytes on the PCIe link, and the
-    # device cast back to float32 is bit-identical
-    ua = _as_uint16_lossless(a)
-    ub = _as_uint16_lossless(b) if ua is not None else None
-    if ua is not None and ub is not None:
-        a, b = ua, ub
-        _H2D_SAVED.inc(a.size * 4 - a.nbytes + b.size * 4 - b.nbytes)
-    ext_a = np.stack([np.array(j.crop_a.shape, np.int32) for j in jobs])
-    ext_b = np.stack([np.array(j.crop_b.shape, np.int32) for j in jobs])
+    with profiling.span("stitching.pack"):
+        a = np.stack([pad_to(j.crop_a, shp) for j in jobs])
+        b = np.stack([pad_to(j.crop_b, shp) for j in jobs])
+        # lossless h2d downcast, decided ONCE for both stacks so the jitted
+        # kernel sees only two dtype signatures (u16/u16 or f32/f32) per
+        # shape bucket: halves the bytes on the PCIe link, and the
+        # device cast back to float32 is bit-identical
+        ua = _as_uint16_lossless(a)
+        ub = _as_uint16_lossless(b) if ua is not None else None
+        if ua is not None and ub is not None:
+            a, b = ua, ub
+            _H2D_SAVED.inc(a.size * 4 - a.nbytes + b.size * 4 - b.nbytes)
+        ext_a = np.stack([np.array(j.crop_a.shape, np.int32) for j in jobs])
+        ext_b = np.stack([np.array(j.crop_b.shape, np.int32) for j in jobs])
     _H2D_BYTES.inc(a.nbytes + b.nbytes + ext_a.nbytes + ext_b.nbytes)
     return pcm_peaks_batch(a, b, ext_a, ext_b, params.peaks_to_check, 0.25)
 
@@ -511,9 +515,13 @@ def _refine_bucket(sd, jobs: list[_PairJob], shp, peaks,
             params.min_overlap_frac
             * min(int(np.prod(j.crop_a.shape)),
                   int(np.prod(j.crop_b.shape))))
-        shifts[k], rs[k] = refine_peaks(
-            j.crop_a, j.crop_b, peaks[k], shp,
-            min_overlap=min_ov, subpixel=params.subpixel)
+        with profiling.span("stitching.refine.pair",
+                            item=(j.group_a.views[0].setup,
+                                  j.group_b.views[0].setup)):
+            shifts[k], rs[k] = refine_peaks(
+                j.crop_a, j.crop_b, peaks[k], shp,
+                min_overlap=min_ov, subpixel=params.subpixel)
+        _PAIRS_DONE.inc()
 
     with profiling.span("stitching.refine"):
         # bound concurrent scorers by their SAT footprint: each refine
@@ -580,6 +588,7 @@ def filter_results(
     return out
 
 
+@profiling.span("stitching.store")
 def store_results(
     sd: SpimData,
     results: list[PairwiseStitchingResult],

@@ -26,7 +26,7 @@ import os
 import sys
 import time
 
-from . import events, manifest, metrics, progress, trace  # noqa: F401
+from . import compiles, events, manifest, metrics, progress, trace  # noqa: F401
 
 _STATE: dict = {
     "dir": None,
@@ -48,6 +48,7 @@ def configure(telemetry_dir: str, profile: bool = True) -> None:
     d = os.path.abspath(telemetry_dir)
     os.makedirs(d, exist_ok=True)
     events.configure(d)
+    compiles.listen()
     progress.reset_records()
     _STATE["dir"] = d
     _STATE["started_at"] = time.time()
@@ -95,6 +96,7 @@ def finalize(tool: str | None = None, params: dict | None = None,
     with open(prom_path, "w", encoding="utf-8") as f:
         f.write(reg.render_prometheus())
     spans = {k: {"count": s.count, "total_s": round(s.total_s, 3),
+                 "self_s": round(s.self_s, 3),
                  "max_s": round(s.max_s, 3), "min_s": round(s.min_s, 3)}
              for k, s in profiling.get().stats().items()}
     seconds = time.time() - _STATE["started_at"]
@@ -182,7 +184,7 @@ class JobRun:
         events.open_job(self.label, self.dir)
         self._metrics_baseline = metrics.get_registry().snapshot()
         self._span_baseline = {
-            k: (s.count, s.total_s)
+            k: (s.count, s.total_s, s.self_s)
             for k, s in profiling.get().stats().items()}
         self._token = None
         self._finalized = False
@@ -221,13 +223,15 @@ class JobRun:
         ev_path = events.close_job(self.label)
         spans = {}
         for k, s in profiling.get().stats().items():
-            c0, t0 = self._span_baseline.get(k, (0, 0.0))
+            c0, t0, self0 = self._span_baseline.get(k, (0, 0.0, 0.0))
             if s.count <= c0:
                 continue
-            # count/total are true deltas; min/max are process-lifetime
-            # aggregates (the profiler keeps no per-interval extrema)
+            # count/total/self are true deltas; min/max are
+            # process-lifetime aggregates (the profiler keeps no
+            # per-interval extrema)
             spans[k] = {"count": s.count - c0,
                         "total_s": round(s.total_s - t0, 3),
+                        "self_s": round(s.self_s - self0, 3),
                         "max_s": round(s.max_s, 3),
                         "min_s": round(s.min_s, 3)}
         reg = metrics.get_registry()

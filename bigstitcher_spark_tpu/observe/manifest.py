@@ -17,6 +17,8 @@ import json
 import os
 import time
 
+from . import process as _process
+
 SCHEMA = "bst-run-manifest/1"
 MERGED_SCHEMA = "bst-merged-report/1"
 
@@ -91,6 +93,7 @@ def write_manifest(
         "params": params or {},
         "world": {"process_index": pi, "process_count": pc},
         "device": device_info(),
+        "process": _process.summary(),
         "started_at": time.strftime("%Y-%m-%dT%H:%M:%S",
                                     time.localtime(started_at)),
         "seconds": round(seconds, 3),

@@ -22,6 +22,12 @@ METRICS: dict[str, str] = {
     "bst_io_read_ops_total": "chunk-level read operations per path",
     "bst_io_write_bytes_total": "bytes written per (op, implementation path)",
     "bst_io_write_ops_total": "chunk-level write operations per path",
+    "bst_io_read_seconds_total":
+        "seconds inside chunk reads that missed the decoded LRU (fetch + "
+        "decode), summed over threads, per path — L1's busy time",
+    "bst_io_write_seconds_total":
+        "seconds inside container writes (encode + store), summed over "
+        "threads, per path",
     # remote object-store traffic (io/chunkstore.py): the subset of the
     # io totals above that crossed the network to an s3/gs root — the
     # remote_read_stall advisor evidence and the warm-leg "zero remote
@@ -99,17 +105,44 @@ METRICS: dict[str, str] = {
     "bst_barrier_seconds": "per-name barrier wait time histogram",
     # stage progress (observe/progress.py)
     "bst_stage_items_done_total": "work items completed per stage",
+    "bst_stage_item_seconds":
+        "one work item's seconds, where its completion is counted (a "
+        "block's attempt; a pair task's dispatch, or its equal share of "
+        "the batched drain that served its segment), histogram per stage",
+    # work done in the end-to-end metrics' own units, as it completes
+    "bst_stitching_pairs_total":
+        "tile pairs whose shift left the stitching drain (refined)",
+    "bst_fusion_voxels_total":
+        "output voxels whose block the fusion driver has written",
+    # JAX's own compile events (observe/compiles.py), by phase: trace =
+    # jaxpr tracing, lower = jaxpr to MLIR, backend_compile = XLA build or
+    # persistent-cache load (cache_load is the load's own part of that)
+    "bst_jax_compile_events_total": "JAX compile-pipeline events per phase",
+    "bst_jax_compile_seconds_total":
+        "seconds inside JAX's compile pipeline per phase — a stage's "
+        "first-call cost",
+    # process start (cli/main.py), seconds since the interpreter started
+    "bst_process_start_imports_seconds":
+        "seconds from process start until the bst package was imported",
+    "bst_process_start_backend_seconds":
+        "seconds from process start until the first jax.devices() returned",
     # pair-parallel scheduler (parallel/pairsched.py)
     "bst_pair_dispatch_total": "pair tasks dispatched per (stage, device)",
-    "bst_pair_busy_ms_total": "device busy milliseconds per (stage, device)",
+    "bst_pair_busy_ms_total":
+        "host time inside the device's dispatch and drain calls, "
+        "milliseconds per (stage, device) — a host clock, not device busy",
     "bst_pair_redispatch_total":
         "pair tasks re-dispatched after a device failure",
-    "bst_pair_device_util_pct": "stage device-utilization percentage",
+    "bst_pair_device_util_pct":
+        "host time inside the devices' dispatch and drain over devices x "
+        "stage wall, percent — not device busy (see --trace-device)",
     "bst_pair_proc_busy_ms_total":
-        "per-process pair-scheduler busy milliseconds (stage, process) — "
-        "the multihost split-imbalance evidence",
+        "per-process host time inside the devices' dispatch and drain, "
+        "milliseconds (stage, process) — the multihost split-imbalance "
+        "evidence",
     "bst_pair_proc_util_pct":
-        "per-process pair-scheduler device-utilization percentage",
+        "per-process host time inside the devices' dispatch and drain over "
+        "devices x stage wall, percent",
     # timeline flight recorder (observe/trace.py)
     "bst_trace_events_total": "trace events recorded into the ring buffer",
     "bst_trace_events_dropped_total":
@@ -239,16 +272,25 @@ METRICS: dict[str, str] = {
 # silent-drift argument as METRICS above: a typo'd span name would mint a
 # fresh timeline series the trace-report and the span aggregates both
 # miss. The ``span-name`` lint check (analysis/checks.py) enforces that
-# every literal passed to ``profiling.span`` / ``trace.span`` /
+# every literal passed to ``profiling.span`` / ``trace.record`` /
 # ``trace.instant`` appears here and bans dynamically constructed names;
 # dynamic identity (device ordinal, block offset, pair index, bytes)
 # belongs in the span's attribution kwargs, never in the name.
 SPANS: dict[str, str] = {
     # affine fusion driver (models/affine_fusion.py)
-    "fusion.kernel": "fused XLA computation (dispatch + on-device compute)",
+    "fusion.stage": "one fuse_volume call, whichever driver (tree root)",
+    "fusion.plan":
+        "per-block view selection and source-box plans, before any read",
+    "fusion.h2d":
+        "per-block explicit upload of the staged kernel inputs, to done",
+    "fusion.kernel":
+        "fused XLA computation: per-block, the call until the outputs are "
+        "ready; composite/sharded, the dispatch",
     "fusion.prefetch": "host-side source-box prefetch for one view patch",
     "fusion.h2d_tiles": "composite-path tile upload into HBM",
-    "fusion.d2h": "device-to-host fetch of fused output (slab or block)",
+    "fusion.d2h":
+        "device-to-host fetch of fused output (slab or block); per-block, "
+        "every fetch of the block with the output-conversion round trip",
     "fusion.write": "container write of fused output (slab or block)",
     # fused multiscale epilogue: pyramid levels computed in HBM and shipped
     # in the same drain as the full-res volume (never a second full-res
@@ -263,10 +305,27 @@ SPANS: dict[str, str] = {
     "detection.extract":
         "descriptor-extraction device dispatch of the STAGED two-pass "
         "detect+extract path (absent when the fused program runs)",
+    "stitching.stage": "one stitch_all_pairs call (tree root)",
+    "stitching.plan": "view grouping and overlapping-pair planning",
     "stitching.extract": "overlap crop extraction for one pair batch",
-    "stitching.kernel": "phase-correlation device program",
+    "stitching.kernel":
+        "one shape bucket's host packing, implicit upload and dispatch of "
+        "the phase-correlation program (host time; the device's part is "
+        "in a --trace-device trace)",
+    "stitching.pack":
+        "host-only part of stitching.kernel: pad, stack, lossless cast",
     "stitching.kernel_sync": "PCM device completion sync",
     "stitching.refine": "host-side Pearson refinement of PCM peaks",
+    "stitching.refine.pair":
+        "one pair's Pearson refinement on its pool thread",
+    "stitching.store": "driver-side collect of kept pair results",
+    # project model (io/spimdata.py) — L4
+    "spimdata.load": "project XML fetch and parse",
+    "spimdata.save": "project XML serialize and write",
+    # JAX's compile pipeline (observe/compiles.py)
+    "jax.compile":
+        "one JAX compile-pipeline event (instant at its end; stage = the "
+        "span open meanwhile, item = phase and function, bytes unused)",
     "nonrigid.kernel": "nonrigid fusion device computation",
     "nonrigid.write": "nonrigid fused block write",
     "nonrigid.prefetch": "nonrigid source patch prefetch",

@@ -119,13 +119,20 @@ class Heartbeat:
         self._eta_first_s: float | None = None
         self._counter = metrics.counter("bst_stage_items_done_total",
                                         stage=stage)
+        self._item_seconds = metrics.histogram("bst_stage_item_seconds",
+                                               stage=stage)
         self._finished = False
         _set_live(stage=stage, done=0, total=self.total,
                   ts=round(time.time(), 3))
         events.emit("stage.start", stage=stage, total=self.total)
 
-    def tick(self, n: int = 1) -> None:
+    def tick(self, n: int = 1, seconds: float | None = None) -> None:
+        """``n`` items done; ``seconds`` is what ONE of them took, where
+        the caller timed it (``bst_stage_item_seconds``)."""
         self._counter.inc(n)
+        if seconds is not None:
+            for _ in range(n):
+                self._item_seconds.observe(seconds)
         with self._lock:
             self._done += n
             if not events.enabled() and not _track_live:

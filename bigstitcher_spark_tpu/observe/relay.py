@@ -57,7 +57,7 @@ import time
 
 from . import metrics as _metrics
 from . import trace as _trace
-from .. import config
+from .. import config, profiling
 
 SCHEMA = "bst-relay/1"
 
@@ -300,7 +300,7 @@ class RelayClient:
             _DROP_CONN.inc()
             return
         data = (json.dumps(msg, default=str) + "\n").encode()
-        with _trace.span("relay.send", nbytes=len(data)):
+        with profiling.span("relay.send", nbytes=len(data)):
             # read the ref under the lock, send OUTSIDE it: a send that
             # rides its 10s timeout must not stall _close_sock and the
             # reconnect path behind it. A connection swapped mid-send
@@ -760,7 +760,7 @@ class RelayCollector:
         ``merge_traces`` into ONE Perfetto file at ``out`` — mid-run,
         nothing pauses. Ranks that fail to answer within ``timeout_s``
         are reported missing, never fatal."""
-        with _trace.span("relay.dump"):
+        with profiling.span("relay.dump"):
             have_local = _trace.enabled()
             lhost, lpi, lpc = _identity()
             with self._dump_lock:

@@ -18,9 +18,9 @@ per host thread.
 
 Cost model:
 
-- **off (default)**: ``enabled()`` is one dict read; ``span`` yields
-  immediately; nothing allocates. ``profiling.span`` call sites pay one
-  extra truthiness check.
+- **off (default)**: ``enabled()`` is one dict read; nothing allocates.
+  ``profiling.span`` (the one span primitive) pays one extra truthiness
+  check and sets no context variable.
 - **on**: one lock + tuple append per event. The ring is sized in bytes
   (``BST_TRACE_BUFFER_BYTES`` / ``_EVENT_COST_BYTES``) and OVERFLOW
   KEEPS THE NEWEST events (the tail of a run is where the frontier is);
@@ -37,11 +37,20 @@ Span NAMES are literals declared in ``observe/metric_names.py``'s
 reusing :mod:`profiling`'s names means the trace and the span aggregates
 can never disagree about what was measured. Dynamic identity (device,
 block offset, pair index, bytes) rides in the event's args instead.
+
+Every event carries an ``id`` and the ``parent`` that was open where it
+was recorded (0 = none): :func:`profiling.span` holds the open span in
+the context variable below, which ``utils.threads`` carries across a
+pool hop, so the events of one call form a tree whatever thread they ran
+on. With ``configure(device=True)`` (``--trace-device``) a JAX profiler
+session runs alongside and :func:`finalize` folds its device timeline
+into the same file (:mod:`.devicetrace`).
 """
 
 from __future__ import annotations
 
-import contextlib
+import contextvars
+import itertools
 import json
 import os
 import threading
@@ -51,12 +60,12 @@ from collections import deque
 from . import metrics as _metrics
 from .. import config
 
-SCHEMA = "bst-trace/1"
+SCHEMA = "bst-trace/2"
 MERGED_SCHEMA = "bst-merged-trace/1"
 
-# amortized python-side cost of one buffered event tuple (8-slot tuple +
-# interned strings + smallint refs); sizes the ring from the byte knob
-_EVENT_COST_BYTES = 160
+# amortized python-side cost of one buffered event tuple (10-slot tuple +
+# interned strings + int refs); sizes the ring from the byte knob
+_EVENT_COST_BYTES = 192
 _MIN_CAPACITY = 64
 
 # device-track ids in the exported trace: Perfetto tids are plain ints,
@@ -71,26 +80,57 @@ _lock = threading.Lock()
 _STATE: dict = {
     "enabled": False,
     "buf": None,           # deque of (ts, ph, name, tid, device, stage,
-    "capacity": 0,         #           item, nbytes)
+    "capacity": 0,         #           item, nbytes, id, parent)
     "recorded": 0,
     "dropped": 0,
     "path": None,          # explicit output override (beats the knob)
     "last_path": None,     # where finalize() wrote, for CLI echo
+    "device_dir": None,    # live JAX profiler session's directory
+    "device": None,        # its reduction (devicetrace.reduce), at finalize
 }
 _thread_names: dict[int, str] = {}
+
+
+class OpenSpan:
+    """One span that is open now: what its children and the events
+    recorded under it need to know. ``children`` collects the closed
+    direct children's ``(start, end)`` (perf_counter seconds) for the
+    parent's self time."""
+
+    __slots__ = ("id", "root", "name", "children")
+
+    def __init__(self, name: str, parent: "OpenSpan | None"):
+        self.id = next(_ids)
+        self.root = parent.root if parent is not None else self.id
+        self.name = name
+        self.children: list[tuple[float, float]] = []
+
+
+_ids = itertools.count(1)    # next() on it is atomic under the GIL
+# the innermost open span of this context; set only by profiling.span and
+# only while something records
+CURRENT: contextvars.ContextVar[OpenSpan | None] = contextvars.ContextVar(
+    "open_span", default=None)
 
 
 def trace_name(process_index: int, process_count: int) -> str:
     return f"trace-{process_index:05d}-of-{process_count:05d}.json"
 
 
-def configure(buffer_bytes: int | None = None, path: str | None = None) -> None:
+def configure(buffer_bytes: int | None = None, path: str | None = None,
+              device: bool = False) -> None:
     """Start recording into a fresh ring. ``buffer_bytes`` defaults to the
     ``BST_TRACE_BUFFER_BYTES`` knob; ``path`` overrides the output
-    resolution of :func:`finalize`."""
+    resolution of :func:`finalize`; ``device`` starts a JAX profiler
+    session beside the ring, which :func:`finalize` reduces into the
+    trace file's ``device N (XLA)`` tracks."""
+    from . import compiles
+
     if buffer_bytes is None:
         buffer_bytes = config.get_bytes("BST_TRACE_BUFFER_BYTES")
     cap = max(_MIN_CAPACITY, int(buffer_bytes) // _EVENT_COST_BYTES)
+    compiles.listen()
+    _stop_device_session()
     with _lock:
         _thread_names.clear()  # OS thread idents get recycled across runs
         _STATE["buf"] = deque(maxlen=cap)
@@ -99,7 +139,14 @@ def configure(buffer_bytes: int | None = None, path: str | None = None) -> None:
         _STATE["dropped"] = 0
         _STATE["path"] = path
         _STATE["last_path"] = None
+        _STATE["device"] = None
         _STATE["enabled"] = True
+    if device:
+        from . import devicetrace
+
+        d = devicetrace.start()
+        with _lock:
+            _STATE["device_dir"] = d
 
 
 def enabled() -> bool:
@@ -110,17 +157,31 @@ def last_path() -> str | None:
     return _STATE["last_path"]
 
 
+def device_session() -> bool:
+    """Whether a profiler session runs beside the ring (``--trace-device``)."""
+    return _STATE["device_dir"] is not None
+
+
 def record(ph: str, name: str, *, device: int | None = None,
            stage: str | None = None, item=None, nbytes: int | None = None,
-           ts: float | None = None) -> None:
+           ts: float | None = None, id: int | None = None,
+           parent: int | None = None) -> None:
     """Append one event (``ph``: ``"B"`` begin / ``"E"`` end / ``"i"``
     instant); no-op unless configured. ``ts`` is wall-clock seconds
     (defaulted) — wall clock, not a monotonic counter, because multihost
-    merge aligns traces across processes via shared barrier exits."""
+    merge aligns traces across processes via shared barrier exits.
+    ``id``/``parent`` are the span's (``profiling.span`` passes them for
+    its begin and end); left out, the event gets an id of its own and the
+    span open in this context for its parent."""
     if not _STATE["enabled"]:
         return
     t = time.time() if ts is None else ts
     tid = threading.get_ident()
+    if id is None:
+        id = next(_ids)
+    if parent is None:
+        cur = CURRENT.get()
+        parent = cur.id if cur is not None else 0
     with _lock:
         buf = _STATE["buf"]
         if buf is None:
@@ -130,26 +191,10 @@ def record(ph: str, name: str, *, device: int | None = None,
         if len(buf) == _STATE["capacity"]:
             _STATE["dropped"] += 1     # deque drops the OLDEST: newest win
             _EVENTS_DROPPED.inc()
-        buf.append((t, ph, name, tid, device, stage, item, nbytes))
+        buf.append((t, ph, name, tid, device, stage, item, nbytes, id,
+                    parent))
         _STATE["recorded"] += 1
         _EVENTS_TOTAL.inc()
-
-
-@contextlib.contextmanager
-def span(name: str, *, device: int | None = None, stage: str | None = None,
-         item=None, nbytes: int | None = None):
-    """Record a begin/end pair around the body (trace-only — use
-    :func:`profiling.span` where the wall-clock aggregate should exist
-    too; that one forwards here when tracing is on)."""
-    if not _STATE["enabled"]:
-        yield
-        return
-    record("B", name, device=device, stage=stage, item=item, nbytes=nbytes)
-    try:
-        yield
-    finally:
-        record("E", name, device=device, stage=stage, item=item,
-               nbytes=nbytes)
 
 
 def instant(name: str, *, device: int | None = None, stage: str | None = None,
@@ -174,8 +219,9 @@ def snapshot() -> list[dict]:
     with _lock:
         items = list(_STATE["buf"]) if _STATE["buf"] is not None else []
     out = []
-    for t, ph, name, tid, device, stage, item, nbytes in items:
-        rec = {"ts": t, "ph": ph, "name": name, "tid": tid}
+    for t, ph, name, tid, device, stage, item, nbytes, sid, parent in items:
+        rec = {"ts": t, "ph": ph, "name": name, "tid": tid, "id": sid,
+               "parent": parent}
         if device is not None:
             rec["device"] = device
         if stage is not None:
@@ -188,8 +234,21 @@ def snapshot() -> list[dict]:
     return out
 
 
+def _stop_device_session() -> dict | None:
+    """Stop the profiler session, if one runs, and return its reduction
+    on the ring's clock (None without a session or a device trace)."""
+    with _lock:
+        d, _STATE["device_dir"] = _STATE["device_dir"], None
+    if d is None:
+        return None
+    from . import devicetrace
+
+    return devicetrace.stop_and_reduce(d, snapshot())
+
+
 def reset() -> None:
     """Stop recording and drop the buffer (test isolation)."""
+    _stop_device_session()
     with _lock:
         _thread_names.clear()
         _STATE["enabled"] = False
@@ -198,17 +257,21 @@ def reset() -> None:
         _STATE["recorded"] = 0
         _STATE["dropped"] = 0
         _STATE["path"] = None
+        _STATE["device"] = None
 
 
 def export(process_index: int = 0, process_count: int = 1) -> dict:
     """The Chrome/Perfetto ``trace_event`` JSON document: ``B``/``E``/``i``
     events in microseconds, device-attributed events routed to one track
     per device ordinal, host events to one track per thread, plus the
-    ``M`` metadata naming every track."""
+    ``M`` metadata naming every track. After a ``--trace-device`` run the
+    reduced device timeline rides along (``device N (XLA)`` tracks, its
+    totals under ``bst.device`` and the clock join's ``bst.clock_*``)."""
     with _lock:
         items = list(_STATE["buf"]) if _STATE["buf"] is not None else []
         tnames = dict(_thread_names)
         recorded, dropped = _STATE["recorded"], _STATE["dropped"]
+        reduced = _STATE["device"]
 
     tid_index: dict[int, int] = {}
     for _t, _ph, _n, tid, device, *_rest in items:
@@ -221,13 +284,13 @@ def export(process_index: int = 0, process_count: int = 1) -> dict:
     }]
     used_device_tids: set[int] = set()
     events = []
-    for t, ph, name, tid, device, stage, item, nbytes in items:
+    for t, ph, name, tid, device, stage, item, nbytes, sid, parent in items:
         if device is not None:
             out_tid = _DEVICE_TID_BASE + int(device)
             used_device_tids.add(out_tid)
         else:
             out_tid = tid_index[tid]
-        args = {}
+        args = {"id": sid, "parent": parent}
         if stage is not None:
             args["stage"] = stage
         if item is not None:
@@ -253,13 +316,19 @@ def export(process_index: int = 0, process_count: int = 1) -> dict:
         meta.append({"ph": "M", "name": "thread_name", "pid": process_index,
                      "tid": idx,
                      "args": {"name": tnames.get(tid, f"thread {tid}")}})
-    return {
-        "traceEvents": meta + events,
-        "displayTimeUnit": "ms",
-        "bst": {"schema": SCHEMA, "process_index": process_index,
-                "process_count": process_count, "recorded": recorded,
-                "dropped": dropped},
-    }
+    bst = {"schema": SCHEMA, "process_index": process_index,
+           "process_count": process_count, "recorded": recorded,
+           "dropped": dropped}
+    if reduced is not None:
+        from . import devicetrace
+
+        dmeta, devents, summary = devicetrace.perfetto(
+            reduced, process_index)
+        meta += dmeta
+        events += devents
+        bst.update(summary)
+    return {"traceEvents": meta + events, "displayTimeUnit": "ms",
+            "bst": bst}
 
 
 def dump(path: str) -> str:
@@ -310,6 +379,9 @@ def finalize(dir_hint: str | None = None) -> str | None:
         pi, pc = _events.world()
         path = os.path.join(dir_hint, trace_name(pi, pc)) if dir_hint \
             else os.path.abspath("bst-trace.json")
+    reduced = _stop_device_session()
+    with _lock:
+        _STATE["device"] = reduced
     path = dump(path)
     with _lock:
         _STATE["enabled"] = False

@@ -186,10 +186,14 @@ def dog_block(
     s2 = float(sigma) * DOG_K
     k1 = gaussian_kernel_1d(s1)
     k2 = gaussian_kernel_1d(s2)
-    if _blur_strategy() == "fft":
-        diff = _dog_response_fft(x, k1, k2)
-    else:
-        diff = _blur_separable(x, [k1] * 3) - _blur_separable(x, [k2] * 3)
+    # named scopes are metadata in the HLO: they name the kernel's phases
+    # in a device trace and cost nothing at run time
+    with jax.named_scope("blur"):
+        if _blur_strategy() == "fft":
+            diff = _dog_response_fft(x, k1, k2)
+        else:
+            diff = (_blur_separable(x, [k1] * 3)
+                    - _blur_separable(x, [k2] * 3))
     # the [min,max]->[0,1] normalization (DoGImgLib2,
     # SparkInterestPointDetection.java:552-568) commutes with the DoG:
     # both blur kernels are normalized, so the constant offset cancels in
@@ -205,16 +209,17 @@ def dog_block(
 
     if origin is None:
         origin = jnp.zeros(3, jnp.int32)
-    tb = _tiebreak(dog.shape, origin)
-    mask = jnp.zeros(dog.shape, bool)
-    if find_max:
-        d = dog + tb
-        mp = _window_extremum3(d, jnp.maximum, -jnp.inf)
-        mask = mask | ((d >= mp) & (dog > threshold))
-    if find_min:
-        d = dog - tb
-        mp = _window_extremum3(d, jnp.minimum, jnp.inf)
-        mask = mask | ((d <= mp) & (dog < -threshold))
+    with jax.named_scope("extrema"):
+        tb = _tiebreak(dog.shape, origin)
+        mask = jnp.zeros(dog.shape, bool)
+        if find_max:
+            d = dog + tb
+            mp = _window_extremum3(d, jnp.maximum, -jnp.inf)
+            mask = mask | ((d >= mp) & (dog > threshold))
+        if find_min:
+            d = dog - tb
+            mp = _window_extremum3(d, jnp.minimum, jnp.inf)
+            mask = mask | ((d <= mp) & (dog < -threshold))
     return dog, mask
 
 
@@ -351,13 +356,15 @@ def dog_block_topk_impl(block, min_i, max_i, threshold, origin, sigma,
             core = m if core is None else (core & m)
         mask = mask & core
     k = int(min(k, int(np.prod(dog.shape))))
-    score = jnp.where(mask, jnp.abs(dog), -jnp.inf).ravel()
-    _, flat_idx = jax.lax.top_k(score, k)
-    valid = jnp.take(score, flat_idx) > -jnp.inf
-    sy, sz = dog.shape[1], dog.shape[2]
-    idx = jnp.stack([flat_idx // (sy * sz), (flat_idx // sz) % sy,
-                     flat_idx % sz], axis=-1).astype(jnp.int32)
-    sub, val = _localize_quadratic_device(dog, idx, valid)
+    with jax.named_scope("topk"):
+        score = jnp.where(mask, jnp.abs(dog), -jnp.inf).ravel()
+        _, flat_idx = jax.lax.top_k(score, k)
+        valid = jnp.take(score, flat_idx) > -jnp.inf
+        sy, sz = dog.shape[1], dog.shape[2]
+        idx = jnp.stack([flat_idx // (sy * sz), (flat_idx // sz) % sy,
+                         flat_idx % sz], axis=-1).astype(jnp.int32)
+    with jax.named_scope("localize"):
+        sub, val = _localize_quadratic_device(dog, idx, valid)
     count = mask.sum().astype(jnp.int32)
     return idx, sub, jnp.where(valid, val, 0.0), valid, count
 

@@ -97,8 +97,12 @@ def _sample_one_view(patch, affine, patch_offset, img_dim, border, blend_range,
     ``coeff`` (Cx,Cy,Cz,2): per-view intensity-correction grid [scale,offset]
     sampled at ``coeff_affine @ lpos`` — mvrecon Coefficients applied inside
     the fusion kernel (SparkAffineFusion.java:545-559)."""
-    p = coords @ affine[:, :3].T + affine[:, 3]  # patch coords (N,3)
-    val = _trilinear_sample(patch, p)
+    # named scopes are metadata in the HLO: they name the kernel's phases
+    # in a device trace and cost nothing at run time
+    with jax.named_scope("coords"):
+        p = coords @ affine[:, :3].T + affine[:, 3]  # patch coords (N,3)
+    with jax.named_scope("gather8"):
+        val = _trilinear_sample(patch, p)
     lpos = p + patch_offset  # level-image coords
     if coeff is not None:
         from .nonrigid import _trilinear_vec
@@ -106,10 +110,11 @@ def _sample_one_view(patch, affine, patch_offset, img_dim, border, blend_range,
         g = lpos @ coeff_affine[:, :3].T + coeff_affine[:, 3]
         so = _trilinear_vec(coeff, g)
         val = so[:, 0] * val + so[:, 1]
-    inside = jnp.all(
-        (lpos >= -inside_off) & (lpos <= img_dim - 1.0 + inside_off), axis=-1
-    ).astype(jnp.float32)
-    w_blend = _blend_weight(lpos, img_dim, border, blend_range)
+    with jax.named_scope("blend_weights"):
+        inside = jnp.all(
+            (lpos >= -inside_off) & (lpos <= img_dim - 1.0 + inside_off),
+            axis=-1).astype(jnp.float32)
+        w_blend = _blend_weight(lpos, img_dim, border, blend_range)
     return val, inside, w_blend
 
 
@@ -136,7 +141,8 @@ def fuse_block_impl(
     patches = patches.astype(jnp.float32)
     if inside_offs is None:
         inside_offs = jnp.zeros_like(borders)
-    coords = block_coords(block_shape)
+    with jax.named_scope("coords"):
+        coords = block_coords(block_shape)
     if coeffs is None:
         vals, insides, wblends = jax.vmap(
             _sample_one_view, in_axes=(0, 0, 0, 0, 0, 0, 0, None)
@@ -147,7 +153,9 @@ def fuse_block_impl(
             _sample_one_view, in_axes=(0, 0, 0, 0, 0, 0, 0, None, 0, 0)
         )(patches, affines, patch_offsets, img_dims, borders, blend_ranges,
           inside_offs, coords, coeffs, coeff_affines)
-    fused, wsum = _combine_views(vals, insides, wblends, valid, fusion_type)
+    with jax.named_scope("accumulate"):
+        fused, wsum = _combine_views(vals, insides, wblends, valid,
+                                     fusion_type)
     return (fused.reshape(block_shape), wsum.reshape(block_shape))
 
 
@@ -188,24 +196,26 @@ def _one_view_shift(patch, frac, lpos0, img_dim, border, blend_range,
     bx, by, bz = block_shape
     fx, fy, fz = frac[0], frac[1], frac[2]
     val = jnp.zeros(block_shape, jnp.float32)
-    for cx in (0, 1):
-        wxc = fx if cx else 1.0 - fx
-        for cy in (0, 1):
-            wyc = fy if cy else 1.0 - fy
-            for cz in (0, 1):
-                wzc = fz if cz else 1.0 - fz
-                sl = jax.lax.slice(
-                    patch, (cx, cy, cz), (cx + bx, cy + by, cz + bz)
-                )
-                val = val + (wxc * wyc * wzc) * sl
-    wx, ix = _axis_blend(lpos0[0], bx, img_dim[0], border[0], blend_range[0],
-                         inside_off[0])
-    wy, iy = _axis_blend(lpos0[1], by, img_dim[1], border[1], blend_range[1],
-                         inside_off[1])
-    wz, iz = _axis_blend(lpos0[2], bz, img_dim[2], border[2], blend_range[2],
-                         inside_off[2])
-    blend = wx[:, None, None] * wy[None, :, None] * wz[None, None, :]
-    inside = ix[:, None, None] * iy[None, :, None] * iz[None, None, :]
+    with jax.named_scope("shift8"):
+        for cx in (0, 1):
+            wxc = fx if cx else 1.0 - fx
+            for cy in (0, 1):
+                wyc = fy if cy else 1.0 - fy
+                for cz in (0, 1):
+                    wzc = fz if cz else 1.0 - fz
+                    sl = jax.lax.slice(
+                        patch, (cx, cy, cz), (cx + bx, cy + by, cz + bz)
+                    )
+                    val = val + (wxc * wyc * wzc) * sl
+    with jax.named_scope("blend_weights"):
+        wx, ix = _axis_blend(lpos0[0], bx, img_dim[0], border[0],
+                             blend_range[0], inside_off[0])
+        wy, iy = _axis_blend(lpos0[1], by, img_dim[1], border[1],
+                             blend_range[1], inside_off[1])
+        wz, iz = _axis_blend(lpos0[2], bz, img_dim[2], border[2],
+                             blend_range[2], inside_off[2])
+        blend = wx[:, None, None] * wy[None, :, None] * wz[None, None, :]
+        inside = ix[:, None, None] * iy[None, :, None] * iz[None, None, :]
     return val, inside, blend
 
 
@@ -331,7 +341,8 @@ def fuse_block_shift_impl(
         _one_view_shift, in_axes=(0, 0, 0, 0, 0, 0, 0, None)
     )(patches, fracs, lpos0, img_dims, borders, blend_ranges, inside_offs,
       block_shape)
-    return _combine_views(vals, insides, wblends, valid, fusion_type)
+    with jax.named_scope("accumulate"):
+        return _combine_views(vals, insides, wblends, valid, fusion_type)
 
 
 fuse_block_shift = jax.jit(

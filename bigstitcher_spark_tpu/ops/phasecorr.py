@@ -49,20 +49,21 @@ def _windowed(img: jnp.ndarray, ext: jnp.ndarray, fade_frac: float):
     fade so the crop-edge discontinuity does not dominate the PCM — without
     this, smooth microscopy data (spectral energy at low k only) buries the
     true peak under zero-padding edge correlation."""
-    n = jnp.prod(ext.astype(jnp.float32))
-    mean = jnp.sum(img) / jnp.maximum(n, 1.0)
-    w = img
-    masks = []
-    for ax in range(3):
-        x = jnp.arange(img.shape[ax], dtype=jnp.float32)
-        e = ext[ax].astype(jnp.float32)
-        m = jnp.maximum(jnp.round(e * fade_frac), 1.0)
-        d = jnp.minimum(x + 0.5, e - (x + 0.5))  # distance into the crop
-        ramp = 0.5 * (1.0 - jnp.cos(jnp.pi * jnp.clip(d / m, 0.0, 1.0)))
-        masks.append(jnp.where(x < e, ramp, 0.0))
-    win = (masks[0][:, None, None] * masks[1][None, :, None]
-           * masks[2][None, None, :])
-    return (w - mean) * win
+    with jax.named_scope("window"):
+        n = jnp.prod(ext.astype(jnp.float32))
+        mean = jnp.sum(img) / jnp.maximum(n, 1.0)
+        w = img
+        masks = []
+        for ax in range(3):
+            x = jnp.arange(img.shape[ax], dtype=jnp.float32)
+            e = ext[ax].astype(jnp.float32)
+            m = jnp.maximum(jnp.round(e * fade_frac), 1.0)
+            d = jnp.minimum(x + 0.5, e - (x + 0.5))  # distance into the crop
+            ramp = 0.5 * (1.0 - jnp.cos(jnp.pi * jnp.clip(d / m, 0.0, 1.0)))
+            masks.append(jnp.where(x < e, ramp, 0.0))
+        win = (masks[0][:, None, None] * masks[1][None, :, None]
+               * masks[2][None, None, :])
+        return (w - mean) * win
 
 
 @functools.partial(jax.jit, static_argnames=("n_peaks",))
@@ -82,23 +83,31 @@ def pcm_peaks(
     # kernel math is float32 either way
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
-    fa = jnp.fft.rfftn(_windowed(a, ext_a, fade_frac))
-    fb = jnp.fft.rfftn(_windowed(b, ext_b, fade_frac))
-    cross = fa * jnp.conj(fb)
-    mag = jnp.abs(cross)
-    # zero out negligible bins instead of normalizing their garbage phase
-    norm = jnp.where(mag > 1e-5 * jnp.max(mag),
-                     cross / jnp.maximum(mag, 1e-30), 0.0)
-    pcm = jnp.fft.irfftn(norm, s=a.shape).astype(jnp.float32)
+    # named scopes are metadata in the HLO: they name the kernel's phases
+    # in a device trace and cost nothing at run time
+    with jax.named_scope("fft"):
+        fa = jnp.fft.rfftn(_windowed(a, ext_a, fade_frac))
+        fb = jnp.fft.rfftn(_windowed(b, ext_b, fade_frac))
+    with jax.named_scope("normalise"):
+        cross = fa * jnp.conj(fb)
+        mag = jnp.abs(cross)
+        # zero out negligible bins instead of normalizing their garbage
+        # phase
+        norm = jnp.where(mag > 1e-5 * jnp.max(mag),
+                         cross / jnp.maximum(mag, 1e-30), 0.0)
+    with jax.named_scope("fft"):
+        pcm = jnp.fft.irfftn(norm, s=a.shape).astype(jnp.float32)
 
-    masked = jnp.where(_local_maxima(pcm), pcm, -jnp.inf)
-    _, flat_idx = jax.lax.top_k(masked.ravel(), n_peaks)
-    sy = a.shape[1] * a.shape[2]
-    sz = a.shape[2]
-    return jnp.stack(
-        [flat_idx // sy, (flat_idx // sz) % a.shape[1], flat_idx % a.shape[2]],
-        axis=-1,
-    ).astype(jnp.int32)
+    with jax.named_scope("peak_search"):
+        masked = jnp.where(_local_maxima(pcm), pcm, -jnp.inf)
+        _, flat_idx = jax.lax.top_k(masked.ravel(), n_peaks)
+        sy = a.shape[1] * a.shape[2]
+        sz = a.shape[2]
+        return jnp.stack(
+            [flat_idx // sy, (flat_idx // sz) % a.shape[1],
+             flat_idx % a.shape[2]],
+            axis=-1,
+        ).astype(jnp.int32)
 
 
 pcm_peaks_batch = jax.jit(

@@ -132,13 +132,14 @@ def barrier(name: str = "bst") -> None:
 
     from jax.experimental import multihost_utils
 
-    from ..observe import events, metrics, trace
+    from .. import profiling
+    from ..observe import events, metrics
 
     t0 = time.perf_counter()
     # the trace span doubles as the multihost clock-alignment anchor: all
     # processes leave sync_global_devices together, so telemetry-merge can
     # shift per-process traces onto one timeline via equal-named exits
-    with trace.span("barrier", stage=name):
+    with profiling.span("barrier", stage=name):
         multihost_utils.sync_global_devices(name)
     dt = time.perf_counter() - t0
     metrics.histogram("bst_barrier_seconds", name=name).observe(dt)

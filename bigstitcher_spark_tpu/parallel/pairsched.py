@@ -47,12 +47,14 @@ Design:
   applies to the block-parallel fusion/downsample drivers, keeping every
   result's D2H and write on the worker track that computed it.
 
-Instrumented through ``observe.metrics``: per-device dispatch/busy
-counters (``bst_pair_dispatch_total`` / ``bst_pair_busy_ms_total``,
-labeled ``stage``+``device``) and a per-stage utilization gauge
-(``bst_pair_device_util_pct`` = busy time over devices x wall) — the
-multichip dry run, ``chip_smoke.py`` and the bench ``"io"`` columns read
-these to prove the spread.
+Instrumented through ``observe.metrics``: per-device dispatch counters
+and HOST time inside each device's dispatch and drain calls
+(``bst_pair_dispatch_total`` / ``bst_pair_busy_ms_total``, labeled
+``stage``+``device``) and a per-stage gauge of that time over devices x
+wall (``bst_pair_device_util_pct``) — the multichip dry run,
+``chip_smoke.py`` and the bench ``"io"`` columns read these to prove the
+spread. They are host clocks: a drain that refines on the host counts in
+full. What the device itself did is in a ``--trace-device`` trace.
 
 ``BST_PAIR_SHARD=0`` opts out (single-device, today's pipelined path);
 one local device degrades to the same thing automatically.
@@ -66,7 +68,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable, Sequence
 
-from .. import config
+from .. import config, profiling
 from ..observe import events, metrics as _metrics, progress as _progress
 from ..observe import trace as _trace
 from ..utils import cancel as _cancel
@@ -142,8 +144,9 @@ def concurrent_pair_workers() -> int:
 
 
 class _StageMeters:
-    """Per-(stage, device) dispatch/busy counters + the stage utilization
-    gauge, shared by every worker of one run."""
+    """Per-(stage, device) dispatch counters, host time inside dispatch and
+    drain, and the stage gauge of that time over devices x wall, shared by
+    every worker of one run."""
 
     def __init__(self, stage: str, n_dev: int):
         self.stage = stage
@@ -230,13 +233,14 @@ def _run_queue(queue, di, dispatch, drain, window, results, failures,
                 return
             try:
                 t0 = time.perf_counter()
-                with _trace.span("pair.dispatch", device=di,
-                                 stage=meters.stage, item=t.index,
-                                 nbytes=t.nbytes or None):
+                with profiling.span("pair.dispatch", device=di,
+                                    stage=meters.stage, item=t.index,
+                                    nbytes=t.nbytes or None):
                     results[t.index] = (True, dispatch(t))
-                meters.add_busy(di, time.perf_counter() - t0)
+                dt = time.perf_counter() - t0
+                meters.add_busy(di, dt)
                 meters.dispatch[di].inc()
-                hb.tick()
+                hb.tick(seconds=dt)
             except Exception as e:  # noqa: BLE001 - re-dispatched by caller
                 failures.append((t, di, e))
         return
@@ -250,13 +254,16 @@ def _run_queue(queue, di, dispatch, drain, window, results, failures,
         tasks = [t for t, _ in group]
         try:
             t0 = time.perf_counter()
-            with _trace.span("pair.drain", device=di, stage=meters.stage,
-                             nbytes=sum(t.nbytes for t in tasks) or None):
+            with profiling.span("pair.drain", device=di, stage=meters.stage,
+                                nbytes=sum(t.nbytes for t in tasks) or None):
                 outs = drain(tasks, [h for _, h in group])
-            meters.add_busy(di, time.perf_counter() - t0)
+            dt = time.perf_counter() - t0
+            meters.add_busy(di, dt)
             for t, r in zip(tasks, outs):
                 results[t.index] = (True, r)
-                hb.tick()
+            # one batched drain serves the whole segment: each of its
+            # tasks is charged an equal share of it
+            hb.tick(len(tasks), seconds=dt / len(tasks))
         except Exception:  # noqa: BLE001 - isolate, then re-dispatch
             # a batched-drain error usually belongs to ONE task's host
             # post-processing: drain each task singly so its healthy
@@ -287,8 +294,9 @@ def _run_queue(queue, di, dispatch, drain, window, results, failures,
             prev, seg, seg_bytes = seg, [], 0
         try:
             t0 = time.perf_counter()
-            with _trace.span("pair.dispatch", device=di, stage=meters.stage,
-                             item=t.index, nbytes=t.nbytes or None):
+            with profiling.span("pair.dispatch", device=di,
+                                stage=meters.stage, item=t.index,
+                                nbytes=t.nbytes or None):
                 out = dispatch(t)
             meters.add_busy(di, time.perf_counter() - t0)
         except Exception as e:  # noqa: BLE001 - re-dispatched by caller
@@ -340,7 +348,7 @@ def _merge_multihost(stage: str, results: list,
                           if r is not None})
     # the gather doubles as the stage barrier: time spent here is the
     # straggler signal of an imbalanced split
-    with _trace.span("pair.allgather", stage=stage):
+    with profiling.span("pair.allgather", stage=stage):
         gathered = allgather_object(payload)
     if err is not None:
         raise err

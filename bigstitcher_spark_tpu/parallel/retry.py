@@ -17,6 +17,7 @@ import time
 import traceback
 from typing import Callable, Sequence, TypeVar
 
+from .. import profiling
 from ..observe import events, metrics, progress, trace
 from ..utils.cancel import Cancelled
 from ..utils.threads import CtxThreadPool
@@ -67,10 +68,11 @@ def run_with_retry(
 
         def attempt(it: T):
             try:
-                with trace.span("retry.attempt", stage=label,
-                                item=_item_key(it)):
+                t0 = time.perf_counter()
+                with profiling.span("retry.attempt", stage=label,
+                                    item=_item_key(it)):
                     process(it)
-                hb.tick()
+                hb.tick(seconds=time.perf_counter() - t0)
                 return None
             except Cancelled:
                 # cancellation is not a block failure: resubmitting a

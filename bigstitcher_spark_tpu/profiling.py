@@ -2,14 +2,22 @@
 
 The reference only prints per-stage ``currentTimeMillis`` deltas
 (SparkAffineFusion.java:424,470,698); we keep per-span aggregates
-(count/total/min/max) queryable in-process and printable per stage.
+(count/total/self/min/max) queryable in-process and printable per stage.
 Zero overhead when disabled.
 
-``span`` is also the begin/end source for the timeline flight recorder
-(:mod:`.observe.trace`): when tracing is on, every span forwards its
-begin/end (plus optional device/stage/item/byte attribution) to the
-ring buffer under the SAME name, so the trace and the aggregates can
-never disagree about what was measured.
+``span`` is the ONE way to open a span. It is also the begin/end source
+for the timeline flight recorder (:mod:`.observe.trace`): when tracing is
+on, every span forwards its begin/end (plus optional device/stage/item/
+byte attribution) to the ring buffer under the SAME name, so the trace
+and the aggregates can never disagree about what was measured. A span
+knows the span that was open where it started (its parent) through a
+context variable that ``utils.threads`` carries across a pool hop, so a
+call's spans form one tree whatever threads they ran on; a span's SELF
+time is its duration less the union of its direct children's intervals.
+While the ring records, each span also opens a
+``jax.profiler.TraceAnnotation`` carrying its id: a no-op without a
+profiler session, and in any device trace taken meanwhile every program
+span then sits on the profiler's own clock beside "XLA Ops".
 """
 
 from __future__ import annotations
@@ -20,6 +28,8 @@ import time
 from collections import defaultdict
 from dataclasses import dataclass
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from .observe import trace as _trace
 
 
@@ -29,6 +39,7 @@ class SpanStat:
     total_s: float = 0.0
     max_s: float = 0.0
     min_s: float = 0.0
+    self_s: float = 0.0
 
 
 class Profiler:
@@ -41,17 +52,19 @@ class Profiler:
         with self._lock:
             self._stats.clear()
 
-    def record(self, name: str, dt: float):
+    def record(self, name: str, dt: float, self_s: float | None = None):
         with self._lock:
             s = self._stats[name]
             s.min_s = dt if s.count == 0 else min(s.min_s, dt)
             s.count += 1
             s.total_s += dt
             s.max_s = max(s.max_s, dt)
+            s.self_s += dt if self_s is None else self_s
 
     def stats(self) -> dict[str, SpanStat]:
         with self._lock:
-            return {k: SpanStat(v.count, v.total_s, v.max_s, v.min_s)
+            return {k: SpanStat(v.count, v.total_s, v.max_s, v.min_s,
+                                v.self_s)
                     for k, v in self._stats.items()}
 
     def report(self) -> str:
@@ -60,13 +73,13 @@ class Profiler:
         # Sorted by total_s DESC so the hot span is the first line.
         stats = self.stats()
         lines = ["span                            count    total_s     "
-                 "mean_s      min_s      max_s"]
+                 "mean_s      min_s      max_s     self_s"]
         for k in sorted(stats, key=lambda k: (-stats[k].total_s, k)):
             s = stats[k]
             lines.append(
                 f"{k:<30} {s.count:>6} {s.total_s:>10.3f} "
                 f"{s.total_s / max(s.count, 1):>10.3f} "
-                f"{s.min_s:>10.3f} {s.max_s:>10.3f}")
+                f"{s.min_s:>10.3f} {s.max_s:>10.3f} {s.self_s:>10.3f}")
         return "\n".join(lines)
 
 
@@ -81,29 +94,56 @@ def get() -> Profiler:
     return _global
 
 
+def _self_seconds(t0: float, t1: float,
+                  children: list[tuple[float, float]]) -> float:
+    """``t1 - t0`` less the union of the children's intervals, each
+    clipped to the span (a child on another thread may outlive it)."""
+    covered, edge = 0.0, t0
+    for a, b in sorted(children):
+        a, b = max(a, edge), min(b, t1)
+        if b > a:
+            covered += b - a
+            edge = b
+    return (t1 - t0) - covered
+
+
 @contextlib.contextmanager
 def span(name: str, *, device: int | None = None, stage: str | None = None,
          item=None, nbytes: int | None = None):
     """Aggregate-profiled (and, when tracing, timeline-recorded) span.
 
     The attribution kwargs cost nothing off the hot path: disabled, the
-    whole call is two truthiness checks and an immediate yield."""
+    whole call is two truthiness checks and an immediate yield, and no
+    context variable is set."""
     tracing = _trace.enabled()
     if not _global.enabled and not tracing:
         yield
         return
+    parent = _trace.CURRENT.get()
+    me = _trace.OpenSpan(name, parent)
+    token = _trace.CURRENT.set(me)
+    parent_id = parent.id if parent is not None else 0
+    note = None
     if tracing:
         _trace.record("B", name, device=device, stage=stage, item=item,
-                      nbytes=nbytes)
+                      nbytes=nbytes, id=me.id, parent=parent_id)
+        note = _TraceAnnotation(name, id=me.id)
+        note.__enter__()
     t0 = time.perf_counter()
     try:
         yield
     finally:
+        t1 = time.perf_counter()
+        if note is not None:
+            note.__exit__(None, None, None)
+        _trace.CURRENT.reset(token)
+        if parent is not None:
+            parent.children.append((t0, t1))
         if _global.enabled:
-            _global.record(name, time.perf_counter() - t0)
+            _global.record(name, t1 - t0, _self_seconds(t0, t1, me.children))
         if tracing:
             _trace.record("E", name, device=device, stage=stage, item=item,
-                          nbytes=nbytes)
+                          nbytes=nbytes, id=me.id, parent=parent_id)
 
 
 def device_sync(x):

@@ -303,7 +303,7 @@ class TestDaemonLive:
             evs, meta = load_events(dump_path)
             build_report(evs, meta)   # must not raise
             doc = json.load(open(dump_path))
-            assert doc["bst"]["schema"] == "bst-trace/1"
+            assert doc["bst"]["schema"] == "bst-trace/2"
             names = {e.get("name") for e in doc["traceEvents"]}
             assert "serve.submit" in names
             from bigstitcher_spark_tpu.observe import trace as _trace

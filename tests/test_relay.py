@@ -32,6 +32,7 @@ import click
 import pytest
 from click.testing import CliRunner
 
+from bigstitcher_spark_tpu import profiling
 from bigstitcher_spark_tpu.cli.main import cli
 from bigstitcher_spark_tpu.observe import (
     events, history, httpexport, metrics, progress, relay, trace,
@@ -517,7 +518,7 @@ class TestClusterPlane:
         try:
             _wait_for(lambda: collector.cluster_status()["collector"]
                       ["connected"] == 2, what="both connected")
-            with trace.span("barrier", stage="self-dedup"):
+            with profiling.span("barrier", stage="self-dedup"):
                 pass
             out = str(tmp_path / "self-dedup-trace.json")
             res = collector.cluster_trace_dump(out, timeout_s=10)
@@ -565,7 +566,7 @@ class TestClusterPlane:
         try:
             _wait_for(lambda: collector.cluster_status()["collector"]
                       ["connected"] == 2, what="both connected")
-            with trace.span("barrier", stage="relay-test"):
+            with profiling.span("barrier", stage="relay-test"):
                 pass
             out = str(tmp_path / "pod-trace.json")
             res = collector.cluster_trace_dump(out, timeout_s=10)
@@ -675,6 +676,7 @@ import os, sys, time
 from bigstitcher_spark_tpu.parallel.distributed import init_distributed
 
 init_distributed()   # relay bring-up rides beside initialize
+from bigstitcher_spark_tpu import profiling
 from bigstitcher_spark_tpu.observe import metrics, progress, relay, trace
 
 assert relay.client() is not None, "worker did not become a push client"
@@ -684,7 +686,7 @@ metrics.counter("bst_io_read_bytes_total", op="e2e",
 hb = progress.Heartbeat("e2e-stage", total=1000, every_s=0.0)
 print("WORKER-READY", flush=True)
 while True:
-    with trace.span("barrier", stage="e2e"):
+    with profiling.span("barrier", stage="e2e"):
         hb.tick()
     time.sleep(0.05)
 """

@@ -55,7 +55,7 @@ class TestRecorder:
         assert not trace.enabled()
         trace.record("B", "fusion.kernel")
         trace.instant("io.read", nbytes=10)
-        with trace.span("fusion.write"):
+        with profiling.span("fusion.write"):
             pass
         s = trace.stats()
         assert s["recorded"] == 0 and s["buffered"] == 0
@@ -81,8 +81,8 @@ class TestRecorder:
         trace.configure(buffer_bytes=1 << 20)
 
         def work(i):
-            with trace.span("pair.dispatch", device=i % 2, item=i):
-                with trace.span("fusion.kernel", item=i):
+            with profiling.span("pair.dispatch", device=i % 2, item=i):
+                with profiling.span("fusion.kernel", item=i):
                     pass
             trace.instant("io.read", nbytes=i)
 
@@ -140,10 +140,10 @@ class TestRecorder:
 class TestExport:
     def test_perfetto_document_structure(self):
         trace.configure(buffer_bytes=1 << 20)
-        with trace.span("fusion.kernel", device=2, item=[0, 0, 0],
+        with profiling.span("fusion.kernel", device=2, item=[0, 0, 0],
                         nbytes=4096):
             pass
-        with trace.span("fusion.write", item=[0, 0, 0], nbytes=2048):
+        with profiling.span("fusion.write", item=[0, 0, 0], nbytes=2048):
             pass
         trace.instant("pair.redispatch", device=2, item=7)
         doc = trace.export(0, 1)
