@@ -38,6 +38,8 @@ _D2H_BYTES = _metrics.counter("bst_xfer_d2h_bytes_total")
 _H2D_SAVED = _metrics.counter("bst_xfer_h2d_bytes_saved_total")
 _D2H_SAVED = _metrics.counter("bst_xfer_d2h_bytes_saved_total")
 _VOXELS_DONE = _metrics.counter("bst_fusion_voxels_total")
+_BLOCKS_BY_KERNEL = {k: _metrics.counter("bst_fusion_blocks_total", kernel=k)
+                     for k in ("shift", "sep", "gather")}
 _TILE_HITS = _metrics.counter("bst_tile_cache_hits_total")
 _TILE_MISSES = _metrics.counter("bst_tile_cache_misses_total")
 _TILE_HIT_BYTES = _metrics.counter("bst_tile_cache_hit_bytes_total")
@@ -227,17 +229,20 @@ def _fuse_grid_block_device(sd, loader, views, block, bbox,
         return None
 
     if coefficients is None and all(p.is_translation for p in plans):
+        _BLOCKS_BY_KERNEL["shift"].inc()
         return _fuse_shift_path(
             loader, plans, block, block_global, bshape, fusion_type, blend,
             stats, inside_offset,
         )
 
     if coefficients is None and all(p.is_diagonal for p in plans):
+        _BLOCKS_BY_KERNEL["sep"].inc()
         return _fuse_sep_path(
             sd, loader, plans, block, bshape, fusion_type, blend, stats,
             inside_offset, patch_quantum,
         )
 
+    _BLOCKS_BY_KERNEL["gather"].inc()
     vb = F.bucket_views(len(plans))
     pshape = F.bucket_shape(
         np.max([p.patch_interval.shape for p in plans], axis=0), patch_quantum

@@ -114,6 +114,9 @@ METRICS: dict[str, str] = {
         "tile pairs whose shift left the stitching drain (refined)",
     "bst_fusion_voxels_total":
         "output voxels whose block the fusion driver has written",
+    "bst_fusion_blocks_total":
+        "blocks the per-block fusion driver sent to a kernel, labeled by "
+        "kernel (shift | sep | gather): which one a block's views allowed",
     # JAX's own compile events (observe/compiles.py), by phase: trace =
     # jaxpr tracing, lower = jaxpr to MLIR, backend_compile = XLA build or
     # persistent-cache load (cache_load is the load's own part of that)

@@ -6,9 +6,19 @@ for each output block, every overlapping view is inverse-affine resampled
 (tri-linear) out of a host-prefetched source patch, weighted with a cosine
 ramp at the image borders (FusionType AVG_BLEND), accumulated, and normalized.
 One fused XLA computation per (block shape, patch bucket, view bucket) — all
-shapes static, no data-dependent control flow; views are a vmapped leading
-axis and invalid/padded views are masked, so a single compile serves every
-block with the same bucket.
+shapes static; views are a leading axis and invalid/padded views are masked,
+so a single compile serves every block with the same bucket.
+
+Three kernels, picked per block by the host planner
+(models/affine_fusion.py): ``fuse_block_shift`` (translations: eight shifted
+slices), ``fuse_block_sep`` (diagonal affines: three GEMMs) and
+``fuse_block`` (general affines). The general kernel fetches no tap on its
+own: it walks the block in small output tiles, fetches each tile's window
+of source rows once (one index a tile) and selects the eight taps of every
+voxel with dense hat weights, the z taps as a float32 contraction on the
+MXU (``_tile_sample``). A scalar gather a tap — what ``_trilinear_sample``
+does, kept for the displacement fields of ops/nonrigid.py — runs at
+16 ns an index on XLA:TPU, thirty times slower at a compute block.
 
 Fusion types (reference enum use at SparkAffineFusion.java:124-125):
 AVG, AVG_BLEND, MAX_INTENSITY, FIRST_WINS (lowest view wins),
@@ -27,20 +37,12 @@ import numpy as np
 FUSION_TYPES = ("AVG", "AVG_BLEND", "MAX_INTENSITY", "FIRST_WINS", "LAST_WINS")
 
 
-def block_coords(block_shape: Sequence[int]) -> jnp.ndarray:
-    """(N,3) float32 local voxel indices of a block, N = prod(shape)."""
-    bx, by, bz = block_shape
-    gx, gy, gz = jnp.meshgrid(
-        jnp.arange(bx, dtype=jnp.float32),
-        jnp.arange(by, dtype=jnp.float32),
-        jnp.arange(bz, dtype=jnp.float32),
-        indexing="ij",
-    )
-    return jnp.stack([gx.ravel(), gy.ravel(), gz.ravel()], axis=-1)
-
-
 def _trilinear_sample(patch: jnp.ndarray, pts: jnp.ndarray) -> jnp.ndarray:
-    """Sample one (Px,Py,Pz) patch at (N,3) float coords; clamped at edges."""
+    """Sample one (Px,Py,Pz) patch at (N,3) float coords; clamped at edges.
+
+    One scalar gather a tap: right for coordinates with no structure (the
+    displaced ones of ops/nonrigid.py); ``_tile_sample`` is the fetch for
+    coordinates that come in compact tiles."""
     px, py, pz = patch.shape
     p0 = jnp.floor(pts)
     f = pts - p0
@@ -69,6 +71,108 @@ def _trilinear_sample(patch: jnp.ndarray, pts: jnp.ndarray) -> jnp.ndarray:
     return c000 + c100 + c010 + c110 + c001 + c101 + c011 + c111
 
 
+# ---------------------------------------------------------------------------
+# General affines: the tap fetch by tile.
+#
+# An affine maps a small output tile into a small source box, so the eight
+# taps of a tile's voxels share a few source rows. The block is walked in
+# x slabs of tiles; a tile's (x, y) window of whole z rows is fetched with
+# ONE index, and the taps are selected by the hat weight
+# max(0, 1 - |coordinate - voxel|), which is trilinear interpolation written
+# without indices: along z as a float32 contraction over the row (MXU,
+# precision HIGHEST: stored values reach 65535), along x and y as a weighted
+# sum over the window. The window's size is static, a guess from the shapes
+# alone (``_tile_window``); when an affine needs more (a patch clipped at the
+# image edge) a loop with a run-time count fetches further windows, so the
+# result never depends on the guess.
+# ---------------------------------------------------------------------------
+
+_TILE_VOXELS = 256   # output voxels a tile: the rows of one MXU contraction
+
+
+def _tile_grid(block_shape: Sequence[int]):
+    """Static (tile shape, tiles per axis): the block cut n times an axis,
+    so a tile keeps the block's proportions and its source box the
+    patch's."""
+    n = max(1, round((float(np.prod(block_shape)) / _TILE_VOXELS) ** (1 / 3)))
+    tile = tuple(-(-int(b) // n) for b in block_shape)
+    grid = tuple(-(-int(b) // t) for b, t in zip(block_shape, tile))
+    return tile, grid
+
+
+def _tile_window(px: int, py: int, grid) -> tuple[int, int]:
+    """Static (x, y) extent of the source window fetched a tile: the patch
+    is the block's image, so a tile's image is the patch over the tiles an
+    axis, and one voxel more on either side for the taps."""
+    n = max(grid)
+    return -(-px // n) + 2, -(-py // n) + 2
+
+
+def _slab_coords(ix, tile, grid) -> jnp.ndarray:
+    """(N,3) float32 block voxel indices of x slab ``ix`` in tile order:
+    (tile y, tile z, x, y, z in the tile)."""
+    shape = (grid[1], grid[2]) + tuple(tile)
+
+    def iota(axis):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    xyz = (ix * tile[0] + iota(2),
+           iota(0) * tile[1] + iota(3),
+           iota(1) * tile[2] + iota(4))
+    return jnp.stack([c.astype(jnp.float32).ravel() for c in xyz], axis=-1)
+
+
+def _hat(d):
+    return jnp.maximum(0.0, 1.0 - jnp.abs(d))
+
+
+def _pad_for_windows(patches: jnp.ndarray, box: tuple[int, int]):
+    """(V,Px,Py,Pz) patches with room past x and y for a window that starts
+    at the last voxel: rows past the patch weigh nought."""
+    return jnp.pad(patches, ((0, 0), (0, box[0]), (0, box[1]), (0, 0)))
+
+
+def _tile_sample(padded: jnp.ndarray, pts: jnp.ndarray,
+                 box: tuple[int, int]) -> jnp.ndarray:
+    """Trilinear samples of (V,Px,Py,Pz) patches, handed over as
+    ``_pad_for_windows`` leaves them, at (V,T,n,3) float coords that come
+    in T compact tiles of n; clamped at edges. ``box`` is the (x, y) window
+    fetched at once."""
+    cx, cy = box
+    V, px, py, pz = padded.shape
+    px, py = px - cx, py - cy
+    # a coordinate clamped to the patch gives the clamped taps' weights
+    q = jnp.clip(pts, 0.0, jnp.array([px - 1, py - 1, pz - 1], jnp.float32))
+    lo = jnp.floor(q.min(axis=2)).astype(jnp.int32)          # (V,T,3)
+    top = jnp.floor(q.max(axis=2)).astype(jnp.int32) + 1
+    span = jnp.max(top - lo + 1, axis=(0, 1))
+    nbx, nby = -(-span[0] // cx), -(-span[1] // cy)
+    view = jnp.broadcast_to(jnp.arange(V)[:, None], lo.shape[:2])
+    xs = jnp.arange(cx, dtype=jnp.int32)
+    ys = jnp.arange(cy, dtype=jnp.int32)
+    hz = _hat(q[..., 2, None] - jnp.arange(pz, dtype=jnp.float32))
+
+    def window(v, x, y):
+        return jax.lax.dynamic_slice(padded, (v, x, y, 0), (1, cx, cy, pz))
+
+    def fetch(k, acc):
+        ox = lo[..., 0] + (k // nby) * cx                     # (V,T)
+        oy = lo[..., 1] + (k % nby) * cy
+        rows = jax.vmap(jax.vmap(window))(
+            view, jnp.minimum(ox, px), jnp.minimum(oy, py))
+        rows = rows.reshape(lo.shape[:2] + (cx * cy, pz)).astype(jnp.float32)
+        wx = _hat(q[..., 0, None] - (ox[..., None, None] + xs))
+        wy = _hat(q[..., 1, None] - (oy[..., None, None] + ys))
+        wxy = (wx[..., :, None] * wy[..., None, :]).reshape(
+            q.shape[:3] + (cx * cy,))
+        along_z = jnp.einsum("vtnz,vtrz->vtnr", hz, rows,
+                             precision=jax.lax.Precision.HIGHEST)
+        return acc + jnp.sum(along_z * wxy, axis=-1)
+
+    return jax.lax.fori_loop(0, nbx * nby, fetch,
+                             jnp.zeros(q.shape[:3], jnp.float32))
+
+
 def _blend_weight(
     lpos: jnp.ndarray, img_dim: jnp.ndarray, border: jnp.ndarray,
     blend_range: jnp.ndarray,
@@ -87,9 +191,17 @@ def _blend_weight(
     return jnp.prod(w, axis=-1)
 
 
-def _sample_one_view(patch, affine, patch_offset, img_dim, border, blend_range,
-                     inside_off, coords, coeff=None, coeff_affine=None):
-    """Per-view: transform block coords, sample, weight. Returns (val, w).
+def _patch_coords(affine, coords):
+    """Block voxel indices (N,3) -> patch coords (N,3), in float32 whatever
+    the backend's matmul default."""
+    return (coords[:, 0:1] * affine[:, 0] + coords[:, 1:2] * affine[:, 1]
+            + coords[:, 2:3] * affine[:, 2] + affine[:, 3])
+
+
+def _weigh_one_view(val, p, patch_offset, img_dim, border, blend_range,
+                    inside_off, coeff=None, coeff_affine=None):
+    """Per-view: correct and weight the samples ``val`` taken at patch
+    coords ``p``. Returns (val, inside, w_blend).
 
     ``inside_off`` expands (+) or shrinks (-) the image box used for the
     inside test — the reference's ``--maskOffset`` for masks mode
@@ -97,12 +209,6 @@ def _sample_one_view(patch, affine, patch_offset, img_dim, border, blend_range,
     ``coeff`` (Cx,Cy,Cz,2): per-view intensity-correction grid [scale,offset]
     sampled at ``coeff_affine @ lpos`` — mvrecon Coefficients applied inside
     the fusion kernel (SparkAffineFusion.java:545-559)."""
-    # named scopes are metadata in the HLO: they name the kernel's phases
-    # in a device trace and cost nothing at run time
-    with jax.named_scope("coords"):
-        p = coords @ affine[:, :3].T + affine[:, 3]  # patch coords (N,3)
-    with jax.named_scope("gather8"):
-        val = _trilinear_sample(patch, p)
     lpos = p + patch_offset  # level-image coords
     if coeff is not None:
         from .nonrigid import _trilinear_vec
@@ -110,6 +216,8 @@ def _sample_one_view(patch, affine, patch_offset, img_dim, border, blend_range,
         g = lpos @ coeff_affine[:, :3].T + coeff_affine[:, 3]
         so = _trilinear_vec(coeff, g)
         val = so[:, 0] * val + so[:, 1]
+    # named scopes are metadata in the HLO: they name the kernel's phases
+    # in a device trace and cost nothing at run time
     with jax.named_scope("blend_weights"):
         inside = jnp.all(
             (lpos >= -inside_off) & (lpos <= img_dim - 1.0 + inside_off),
@@ -119,7 +227,7 @@ def _sample_one_view(patch, affine, patch_offset, img_dim, border, blend_range,
 
 
 def fuse_block_impl(
-    patches: jnp.ndarray,        # (V, Px, Py, Pz) float32
+    patches: jnp.ndarray,        # (V, Px, Py, Pz) float32 or stored integer
     affines: jnp.ndarray,        # (V, 3, 4) float32: block idx -> patch coords
     patch_offsets: jnp.ndarray,  # (V, 3) float32: patch origin in level coords
     img_dims: jnp.ndarray,       # (V, 3) float32
@@ -136,27 +244,38 @@ def fuse_block_impl(
 
     Weight-sum doubles as the coverage mask for ``--masks`` mode
     (GenerateComputeBlockMasks equivalent)."""
-    # patches may arrive in their stored integer dtype (lossless transport
-    # downcast — halves h2d bytes on wire-limited links); math is float32
-    patches = patches.astype(jnp.float32)
+    # patches stay in their stored dtype (lossless transport downcast —
+    # halves h2d bytes and the fetched rows); math is float32
     if inside_offs is None:
         inside_offs = jnp.zeros_like(borders)
-    with jax.named_scope("coords"):
-        coords = block_coords(block_shape)
-    if coeffs is None:
-        vals, insides, wblends = jax.vmap(
-            _sample_one_view, in_axes=(0, 0, 0, 0, 0, 0, 0, None)
-        )(patches, affines, patch_offsets, img_dims, borders, blend_ranges,
-          inside_offs, coords)
-    else:
-        vals, insides, wblends = jax.vmap(
-            _sample_one_view, in_axes=(0, 0, 0, 0, 0, 0, 0, None, 0, 0)
-        )(patches, affines, patch_offsets, img_dims, borders, blend_ranges,
-          inside_offs, coords, coeffs, coeff_affines)
-    with jax.named_scope("accumulate"):
-        fused, wsum = _combine_views(vals, insides, wblends, valid,
-                                     fusion_type)
-    return (fused.reshape(block_shape), wsum.reshape(block_shape))
+    V, px, py, _ = patches.shape
+    tile, grid = _tile_grid(block_shape)
+    box = _tile_window(px, py, grid)
+    padded = _pad_for_windows(patches, box)   # once, not a slab
+    per_view = (patch_offsets, img_dims, borders, blend_ranges, inside_offs)
+    if coeffs is not None:
+        per_view += (coeffs, coeff_affines)
+
+    def slab(ix):
+        with jax.named_scope("coords"):
+            coords = _slab_coords(ix, tile, grid)
+            p = jax.vmap(_patch_coords, in_axes=(0, None))(affines, coords)
+        with jax.named_scope("tile_fetch"):
+            vals = _tile_sample(
+                padded, p.reshape(V, grid[1] * grid[2], -1, 3), box)
+        vals, insides, wblends = jax.vmap(_weigh_one_view)(
+            vals.reshape(V, -1), p, *per_view)
+        with jax.named_scope("accumulate"):
+            return _combine_views(vals, insides, wblends, valid, fusion_type)
+
+    def untile(x):
+        # (tile x, tile y, tile z, x, y, z) -> the block, its padding cut
+        x = x.reshape(grid + tile).transpose(0, 3, 1, 4, 2, 5)
+        x = x.reshape(tuple(g * t for g, t in zip(grid, tile)))
+        return x[tuple(slice(0, b) for b in block_shape)]
+
+    fused, wsum = jax.lax.map(slab, jnp.arange(grid[0]))
+    return untile(fused), untile(wsum)
 
 
 fuse_block = jax.jit(
@@ -172,8 +291,9 @@ fuse_block = jax.jit(
 # translation-model solve), sampling degenerates to EIGHT STATICALLY-SHIFTED
 # SLICES of the patch with constant trilinear corner weights, and the blend
 # weight is separable per axis. That is pure elementwise arithmetic — the
-# shape XLA/TPU wants — instead of 8 random gathers per voxel. The host
-# planner picks this kernel per block (models/affine_fusion.py).
+# shape XLA/TPU wants — with no fetch by index at all, not even the general
+# kernel's one window a tile. The host planner picks this kernel per block
+# (models/affine_fusion.py).
 # ---------------------------------------------------------------------------
 
 

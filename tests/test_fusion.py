@@ -169,6 +169,287 @@ class TestKernel:
         np.testing.assert_array_equal(out16, [0, 16384, 32768, 65535])
 
 
+def _rot(axis, deg):
+    c, s = np.cos(np.radians(deg)), np.sin(np.radians(deg))
+    m = np.eye(3)
+    i, j = [(1, 2), (0, 2), (0, 1)][axis]
+    m[i, i], m[i, j], m[j, i], m[j, j] = c, -s, s, c
+    return m
+
+
+# block index -> patch coords, linear parts: what an AFFINE solve leaves
+GENERAL_LINEAR = {
+    "rot_x_30": _rot(0, 30),
+    "rot_y_45_z_calibration": np.diag([1, 1, 0.25]) @ _rot(1, 45),
+    "rot_z_100": _rot(2, 100),
+    "anisotropic_scale": np.diag([0.6, 1.3, 0.35]),
+    "shear": np.array([[1, 0.4, -0.2], [0.1, 1, 0.3], [-0.3, 0.2, 1.0]]),
+    "mirrored_rot_y_135": np.diag([-1, 1, 0.5]) @ _rot(1, 135),
+}
+
+
+def _raster_idx(block):
+    """(N,3) float32 voxel indices of a block in raster order."""
+    return np.stack(np.meshgrid(*[np.arange(b, dtype=np.float32)
+                                  for b in block], indexing="ij"),
+                    -1).reshape(-1, 3)
+
+
+def _centred_affine(lin, block, pshape, shift=(0.0, 0.0, 0.0)):
+    """(3,4) float32 affine that maps the block's centre to the patch's."""
+    lin = np.asarray(lin, np.float64)
+    t = (np.array(pshape) - 1) / 2 - lin @ ((np.array(block) - 1) / 2)
+    return np.concatenate([lin, (t + shift)[:, None]], 1).astype(np.float32)
+
+
+def _tile_fetch(patches, affines, block):
+    """The general kernel's fetch as ``fuse_block_impl`` drives it, and the
+    raster-order coordinates it sampled: (V, *block) samples, (V, N, 3)."""
+    import jax
+    import jax.numpy as jnp
+
+    tile, grid = F._tile_grid(block)
+    V, px, py, _ = patches.shape
+    box = F._tile_window(px, py, grid)
+    slabs = []
+    for ix in range(grid[0]):
+        coords = F._slab_coords(ix, tile, grid)
+        p = jax.vmap(F._patch_coords, in_axes=(0, None))(
+            jnp.asarray(affines), coords)
+        vals = F._tile_sample(F._pad_for_windows(jnp.asarray(patches), box),
+                              p.reshape(V, grid[1] * grid[2], -1, 3), box)
+        slabs.append(np.asarray(vals).reshape(
+            (V, grid[1], grid[2]) + tile).transpose(0, 3, 1, 4, 2, 5))
+    full = np.concatenate(slabs, axis=1).reshape(
+        (V,) + tuple(g * t for g, t in zip(grid, tile)))
+    full = full[(slice(None),) + tuple(slice(0, b) for b in block)]
+    pts = np.stack([np.asarray(F._patch_coords(jnp.asarray(a),
+                                               _raster_idx(block)))
+                    for a in affines])
+    return full, pts
+
+
+def _scalar_gather(patches, pts, block):
+    return np.stack([
+        np.asarray(F._trilinear_sample(np.asarray(pa, np.float32), pt))
+        for pa, pt in zip(patches, pts)]).reshape((len(patches),) + block)
+
+
+def _reference_fuse(patches, affines, offsets, img_dims, borders, ranges,
+                    valid, block, fusion_type, inside_offs=None):
+    """The parent's algorithm: raster-order coordinates, one scalar gather
+    a tap, the same weights and the same combination."""
+    import jax
+
+    if inside_offs is None:
+        inside_offs = np.zeros_like(borders)
+    pts = np.stack([np.asarray(F._patch_coords(a, _raster_idx(block)))
+                    for a in affines])
+    vals = _scalar_gather(patches, pts, block).reshape(len(patches), -1)
+    vals, insides, wblends = jax.vmap(F._weigh_one_view)(
+        vals, pts, offsets, img_dims, borders, ranges, inside_offs)
+    fused, wsum = F._combine_views(vals, insides, wblends, valid, fusion_type)
+    return (np.asarray(fused).reshape(block), np.asarray(wsum).reshape(block))
+
+
+def _general_inputs(rng, block, pshape, dtype=np.uint16, vmax=60000):
+    """Three views under general affines plus one padded (valid = 0)."""
+    names = ["rot_y_45_z_calibration", "shear", "rot_z_100"]
+    V = 4
+    patches = np.zeros((V, *pshape), dtype)
+    patches[:3] = rng.integers(0, vmax, (3, *pshape)).astype(dtype)
+    affines = np.zeros((V, 3, 4), np.float32)
+    for i, n in enumerate(names):
+        affines[i] = _centred_affine(GENERAL_LINEAR[n] * 0.8, block, pshape,
+                                     rng.uniform(-2, 2, 3))
+    offsets = np.zeros((V, 3), np.float32)
+    offsets[:3] = rng.uniform(0, 5, (3, 3))
+    img_dims = np.tile(np.array(pshape, np.float32) + 4, (V, 1))
+    borders = np.zeros((V, 3), np.float32)
+    ranges = np.full((V, 3), 6.0, np.float32)
+    valid = np.array([1, 1, 1, 0], np.float32)
+    return patches, affines, offsets, img_dims, borders, ranges, valid
+
+
+# float32 rounding of a sum of eight products of values up to 65535 with three
+# weights each, summed in another order: 2e-6 of the largest value
+F32_ATOL = 0.15
+
+
+class TestTileFetch:
+    """The general kernel's fetch (one window of source rows a tile, taps
+    selected by hat weights) against one scalar gather a tap."""
+
+    BLOCK, PSHAPE = (20, 18, 11), (30, 27, 16)   # not multiples of a tile
+
+    @pytest.mark.parametrize("dtype", [np.uint16, np.float32])
+    @pytest.mark.parametrize("name", sorted(GENERAL_LINEAR))
+    def test_matches_scalar_gather_on_general_affines(self, name, dtype):
+        rng = np.random.default_rng(sorted(GENERAL_LINEAR).index(name))
+        patches = rng.integers(0, 65535, (2, *self.PSHAPE)).astype(dtype)
+        affines = np.stack([
+            _centred_affine(GENERAL_LINEAR[name] * s, self.BLOCK,
+                            self.PSHAPE, rng.uniform(-1, 1, 3))
+            for s in (0.7, 1.0)])
+        new, pts = _tile_fetch(patches, affines, self.BLOCK)
+        old = _scalar_gather(patches, pts, self.BLOCK)
+        np.testing.assert_allclose(new, old, atol=F32_ATOL, rtol=0)
+
+    @pytest.mark.parametrize("axis", [0, 1, 2])
+    @pytest.mark.parametrize("side", ["before", "beyond"])
+    def test_clamps_at_the_patch_edge(self, axis, side):
+        """Coordinates that reach and pass the edge take the edge voxel."""
+        rng = np.random.default_rng(10 + axis)
+        patches = rng.integers(0, 65535, (1, *self.PSHAPE)).astype(np.uint16)
+        shift = np.zeros(3)
+        shift[axis] = (-0.6 if side == "before" else 0.6) * self.PSHAPE[axis]
+        affines = _centred_affine(GENERAL_LINEAR["rot_x_30"], self.BLOCK,
+                                  self.PSHAPE, shift)[None]
+        new, pts = _tile_fetch(patches, affines, self.BLOCK)
+        outside = ((pts[0, :, axis] < 0) if side == "before"
+                   else (pts[0, :, axis] > self.PSHAPE[axis] - 1))
+        assert 0.2 < outside.mean() < 0.9 and (~outside).any()
+        np.testing.assert_allclose(
+            new, _scalar_gather(patches, pts, self.BLOCK), atol=F32_ATOL, rtol=0)
+        pick = np.flatnonzero(outside)[::97]
+        np.testing.assert_allclose(
+            new.reshape(-1)[pick],
+            np_trilinear(patches[0].astype(np.float64), pts[0][pick]),
+            atol=F32_ATOL, rtol=0)
+
+    @pytest.mark.parametrize("box", [(1, 1), (2, 3), (5, 4), (40, 40)])
+    def test_any_window_size_gives_the_same_samples(self, box):
+        """The window is a guess from the shapes: a smaller one takes more
+        fetches (a run-time count), a larger one reads padding."""
+        import jax
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(3)
+        block = (16, 16, 8)
+        patches = rng.integers(0, 65535, (2, *self.PSHAPE)).astype(np.uint16)
+        affines = np.stack([_centred_affine(GENERAL_LINEAR[n], block,
+                                            self.PSHAPE)
+                            for n in ("shear", "rot_y_45_z_calibration")])
+        tile, grid = F._tile_grid(block)
+        coords = F._slab_coords(1, tile, grid)
+        p = jax.vmap(F._patch_coords, in_axes=(0, None))(
+            jnp.asarray(affines), coords)
+        new = F._tile_sample(F._pad_for_windows(jnp.asarray(patches), box),
+                             p.reshape(2, grid[1] * grid[2], -1, 3), box)
+        old = np.stack([np.asarray(F._trilinear_sample(
+            patches[v].astype(np.float32), p[v])) for v in range(2)])
+        np.testing.assert_allclose(np.asarray(new).reshape(2, -1), old,
+                                   atol=F32_ATOL, rtol=0)
+
+    @pytest.mark.parametrize("block", [(16, 16, 8), (20, 18, 11), (5, 3, 1),
+                                       (33, 9, 40)])
+    def test_slabs_cover_the_block_once(self, block):
+        tile, grid = F._tile_grid(block)
+        assert all(g * t >= b for g, t, b in zip(grid, tile, block))
+        seen = np.zeros(tuple(g * t for g, t in zip(grid, tile)), int)
+        for ix in range(grid[0]):
+            c = np.asarray(F._slab_coords(ix, tile, grid)).astype(int)
+            np.add.at(seen, tuple(c.T), 1)
+        assert np.all(seen == 1)
+
+    @pytest.mark.parametrize("fusion_type", F.FUSION_TYPES)
+    def test_every_fusion_type_matches_the_scalar_gather(self, fusion_type):
+        import jax
+
+        rng = np.random.default_rng(21)
+        args = _general_inputs(rng, self.BLOCK, self.PSHAPE)
+        fused, wsum = jax.jit(
+            F.fuse_block_impl, static_argnames=("block_shape", "fusion_type")
+        )(*args, block_shape=self.BLOCK, fusion_type=fusion_type)
+        ref_f, ref_w = _reference_fuse(*args, self.BLOCK, fusion_type)
+        assert (ref_w > 0).mean() > 0.5
+        np.testing.assert_allclose(np.asarray(wsum), ref_w, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(fused), ref_f, atol=F32_ATOL)
+
+    def test_padded_view_changes_nothing(self):
+        """A view with valid = 0 (an all-zero affine and patch, as the
+        driver pads a bucket) leaves the block as the real views give it."""
+        rng = np.random.default_rng(22)
+        args = _general_inputs(rng, self.BLOCK, self.PSHAPE)
+        with_pad = F.fuse_block(*args, block_shape=self.BLOCK,
+                                fusion_type="AVG_BLEND")
+        junk = [np.array(a) for a in args]
+        junk[0][3] = 65535                      # what a padded view holds
+        junk[1][3] = args[1][0]                 # and where it points
+        with_junk = F.fuse_block(*junk, block_shape=self.BLOCK,
+                                 fusion_type="AVG_BLEND")
+        for a, b in zip(with_pad, with_junk):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        ref_f, _ = _reference_fuse(*[a[:3] for a in args], self.BLOCK,
+                                   "AVG_BLEND")
+        np.testing.assert_allclose(np.asarray(with_pad[0]), ref_f, atol=F32_ATOL)
+
+    def test_stored_and_float_patches_agree(self):
+        rng = np.random.default_rng(23)
+        args = list(_general_inputs(rng, self.BLOCK, self.PSHAPE))
+        stored = F.fuse_block(*args, block_shape=self.BLOCK)
+        args[0] = args[0].astype(np.float32)
+        as_float = F.fuse_block(*args, block_shape=self.BLOCK)
+        for a, b in zip(stored, as_float):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("name", ["rot_y_45_z_calibration", "shear"])
+    def test_fuse_block_matches_the_numpy_resampler(self, name):
+        rng = np.random.default_rng(24)
+        block, pshape = (12, 10, 9), (22, 20, 14)
+        patch = rng.uniform(0, 100, pshape).astype(np.float32)
+        args = list(_identity_inputs(patch))
+        args[1][0] = _centred_affine(GENERAL_LINEAR[name], block, pshape)
+        fused, wsum = F.fuse_block(*args, block_shape=block,
+                                   fusion_type="AVG")
+        pts = (_raster_idx(block).astype(np.float64)
+               @ args[1][0][:, :3].T.astype(np.float64) + args[1][0][:, 3])
+        inside = np.all((pts >= 0) & (pts <= np.array(pshape) - 1), axis=1)
+        expected = np.where(inside, np_trilinear(patch, pts), 0.0)
+        assert 0.3 < inside.mean()
+        # a coordinate within float32 rounding of the image edge may fall
+        # on either side of the inside test
+        near = np.any((np.abs(pts) < 1e-4)
+                      | (np.abs(pts - (np.array(pshape) - 1)) < 1e-4), axis=1)
+        np.testing.assert_allclose(np.asarray(fused).reshape(-1)[~near],
+                                   expected[~near], atol=2e-3)
+
+    def test_module_name_the_benchmark_reads(self):
+        """``fuse_kernel_ms`` and ``fuse_kernel_roofline`` find the kernel
+        by its XLA module name: one module a block, named after the entry."""
+        rng = np.random.default_rng(25)
+        args = _general_inputs(rng, (8, 8, 4), (10, 10, 6))
+        text = F.fuse_block.lower(*args, block_shape=(8, 8, 4),
+                                  fusion_type="AVG_BLEND").as_text()
+        assert "module @jit_fuse_block_impl" in text
+        assert text.count("module @") == 1
+
+    def test_sharded_gather_core_matches_per_block(self):
+        """``parallel/mesh`` vmaps ``fuse_block_impl`` over a batch of
+        blocks: the fetch's run-time count of windows batches too."""
+        from bigstitcher_spark_tpu.parallel.mesh import (
+            make_mesh, make_sharded_fuser,
+        )
+
+        rng = np.random.default_rng(26)
+        block, pshape = (16, 12, 8), (24, 20, 12)
+        blocks = [_general_inputs(rng, block, pshape) for _ in range(2)]
+        # the second block's views need more windows than the first's
+        blocks[1][1][:3, :, :3] *= 1.6
+        mesh = make_mesh(1)
+        fn = make_sharded_fuser(mesh, block, "AVG_BLEND", kernel="gather")
+        stacked = [np.stack([b[i] for b in blocks]) for i in range(7)]
+        ioffs = np.zeros_like(stacked[4])
+        out = fn(np.float32(0), np.float32(1), *stacked, ioffs)
+        for k, b in enumerate(blocks):
+            fused, wsum = F.fuse_block(*b, block_shape=block,
+                                       fusion_type="AVG_BLEND")
+            np.testing.assert_allclose(np.asarray(out[0][k]),
+                                       np.asarray(fused), atol=1e-3)
+            np.testing.assert_allclose(np.asarray(out[1][k]),
+                                       np.asarray(wsum), atol=1e-6)
+
+
 class TestPyramidProposal:
     def test_estimate(self):
         ds = estimate_multires_pyramid((512, 512, 128))
