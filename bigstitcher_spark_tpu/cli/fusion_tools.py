@@ -23,6 +23,7 @@ from ..io.container import (
 from ..io.dataset_io import ViewLoader
 from ..io.spimdata import SpimData, ViewId
 from ..models.affine_fusion import BlendParams, fuse_volume
+from ..models.downsample_driver import write_pyramid
 from ..ops.fusion import FUSION_TYPES
 from ..io.uris import has_scheme
 from ..utils.geometry import Interval
@@ -406,35 +407,9 @@ def affine_fusion_cmd(output, storage_opt, fusion_type, block_scale, masks,
                     f"{(stats.voxels + stats.pyramid_voxels) / max(stats.seconds, 1e-9):,.0f}"
                     " vox/s incl. pyramid)")
             if len(mr) > 1 and not dry_run:
-                _write_pyramid(store, mr, is_zarr5d, (ci, ti),
-                               epilogue_levels=stats.pyramid_levels)
+                write_pyramid(store, mr, is_zarr5d, (ci, ti),
+                              epilogue_levels=stats.pyramid_levels)
     click.echo(f"done, {total_vox} voxels, took {time.time() - t_start:.1f}s")
-
-
-def _write_pyramid(store, mr_levels, is_zarr5d, ct, epilogue_levels=0):
-    """Downsample s0 into the remaining pyramid levels
-    (SparkAffineFusion.java:703-782). Each level reads chunks the previous
-    stage may have written on another host -> barrier per boundary.
-
-    ``epilogue_levels``: how many leading levels the fusion drivers already
-    materialized as a fused multiscale epilogue this run. Their container
-    markers are set (and stale ones from earlier runs revoked) before the
-    barrier, then ``downsample_pyramid_level(skip_existing=True)`` skips
-    exactly those — no full-res container re-read for levels that rode the
-    fusion drain."""
-    from ..io.container import set_epilogue_written
-    from ..models.downsample_driver import downsample_pyramid_level
-    from ..parallel.distributed import barrier, world
-
-    if world()[0] == 0:  # one writer for the shared container attributes
-        for lvl in range(1, len(mr_levels)):
-            set_epilogue_written(store, mr_levels[lvl].dataset, ct,
-                                 lvl <= epilogue_levels)
-    barrier("fusion-s0")
-    for lvl in range(1, len(mr_levels)):
-        downsample_pyramid_level(store, mr_levels[lvl - 1], mr_levels[lvl],
-                                 is_zarr5d, ct, skip_existing=True)
-        barrier(f"fusion-s{lvl}")
 
 
 @click.command()
@@ -485,11 +460,7 @@ def nonrigid_fusion_cmd(output, xml, labels, cpd, alpha, fusion_type,
                         bounding_box, bdv, xml_out, dry_run, **kwargs):
     """Distributed non-rigid fusion driven by corresponding interest points
     (SparkNonRigidFusion)."""
-    from ..io.interestpoints import InterestPointStore
-    from ..models.nonrigid_fusion import (
-        build_unique_points,
-        fuse_nonrigid_volume,
-    )
+    from ..models.nonrigid_fusion import fuse_nonrigid_project
 
     t_start = time.time()
     try:
@@ -523,60 +494,13 @@ def nonrigid_fusion_cmd(output, xml, labels, cpd, alpha, fusion_type,
         store = open_container(output)
         meta = read_container_meta(store)
     sd = SpimData.load(meta.input_xml)
-    loader = ViewLoader(sd)
-    all_views = select_views_from_kwargs(sd, kwargs)
-    ip_store = InterestPointStore.for_project(sd)
-
-    blend = BlendParams(
-        border=tuple(float(v) for v in blending_border.split(",")),
-        range=tuple(float(v) for v in blending_range.split(",")),
-    )
-    bscale = parse_csv_ints(block_scale, 3)
-    is_zarr5d = meta.fusion_format in ("OME-ZARR", "BDV/OME-ZARR")
-    channels = sorted({sd.setups[v.setup].attributes.get("channel", 0)
-                       for v in all_views})
-    tps = sorted({v.timepoint for v in all_views})
-    c_indices = ([channel_index] if channel_index is not None
-                 else list(range(len(channels))))
-    t_indices = ([timepoint_index] if timepoint_index is not None
-                 else list(range(len(tps))))
-
-    total_vox = 0
-    for ti in t_indices:
-        t = tps[ti]
-        for ci in c_indices:
-            c = channels[ci]
-            views = [
-                v for v in all_views
-                if v.timepoint == t
-                and sd.setups[v.setup].attributes.get("channel", 0) == c
-            ]
-            if not views:
-                continue
-            # deformation may use IPs of ALL views of this timepoint
-            # (corresponding views need not be restricted to the channel)
-            ip_views = [v for v in all_views if v.timepoint == t]
-            unique = build_unique_points(sd, ip_store, ip_views, list(labels))
-            mr = meta.mr_infos[ci + ti * meta.num_channels]
-            ds = store.open_dataset(mr[0].dataset.strip("/"))
-            click.echo(f"nonrigid fusing channel {c} timepoint {t}: "
-                       f"{len(views)} views -> {mr[0].dataset}")
-            if dry_run:
-                continue
-            stats = fuse_nonrigid_volume(
-                sd, loader, views, unique, ds, meta.bbox,
-                block_size=tuple(meta.block_size), block_scale=tuple(bscale),
-                cpd=cpd, alpha=alpha,
-                fusion_type=fusion_type.upper(), blend=blend,
-                anisotropy_factor=(meta.anisotropy_factor
-                                   if meta.preserve_anisotropy else float("nan")),
-                out_dtype=meta.data_type,
-                min_intensity=meta.min_intensity,
-                max_intensity=meta.max_intensity,
-                zarr_ct=(ci, ti) if is_zarr5d else None,
-            )
-            total_vox += stats.voxels
-            click.echo(f"  {stats.voxels} voxels in {stats.seconds:.2f}s")
-            if len(mr) > 1 and not dry_run:
-                _write_pyramid(store, mr, is_zarr5d, (ci, ti))
+    total_vox = fuse_nonrigid_project(
+        store, meta, sd, select_views_from_kwargs(sd, kwargs), list(labels),
+        cpd, alpha, fusion_type.upper(),
+        BlendParams(
+            border=tuple(float(v) for v in blending_border.split(",")),
+            range=tuple(float(v) for v in blending_range.split(",")),
+        ),
+        parse_csv_ints(block_scale, 3), channel_index=channel_index,
+        timepoint_index=timepoint_index, dry_run=dry_run, log=click.echo)
     click.echo(f"done, {total_vox} voxels, took {time.time() - t_start:.1f}s")

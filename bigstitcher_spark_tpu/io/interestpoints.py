@@ -133,23 +133,28 @@ class InterestPointStore:
         self.store.set_attribute(base, "correspondences", "1.0.0")
         self.store.set_attribute(base, "idMap", id_map)
 
-    def load_correspondences(self, view: ViewId, label: str) -> list[CorrespondingPoint]:
+    def load_correspondence_rows(
+        self, view: ViewId, label: str
+    ) -> tuple[np.ndarray, dict[int, tuple[ViewId, str]]]:
+        """-> ((M,3) uint64 rows of (own id, other id, pair code), {pair
+        code: (other view, other label)}); no rows and no codes if absent."""
         base = f"{view_group(view, label)}/correspondences"
+        none = np.zeros((0, 3), np.uint64), {}
         if not self.store.is_dataset(f"{base}/data"):
-            return []
+            return none
         id_map = self.store.get_attribute(base, "idMap", {}) or {}
         if not id_map:
-            return []
+            return none
         decode = {}
         for key, code in id_map.items():
             tp, setup, lab = key.split(",", 2)
             decode[int(code)] = (ViewId(int(tp), int(setup)), lab)
-        rows = self.store.open_dataset(f"{base}/data").read_full()
-        out = []
-        for ida, idb, code in rows.T:
-            ov, ol = decode[int(code)]
-            out.append(CorrespondingPoint(int(ida), ov, ol, int(idb)))
-        return out
+        return self.store.open_dataset(f"{base}/data").read_full().T, decode
+
+    def load_correspondences(self, view: ViewId, label: str) -> list[CorrespondingPoint]:
+        rows, decode = self.load_correspondence_rows(view, label)
+        return [CorrespondingPoint(int(ida), *decode[int(code)], int(idb))
+                for ida, idb, code in rows]
 
     def clear_correspondences(self, view: ViewId, label: str) -> None:
         base = f"{view_group(view, label)}/correspondences"

@@ -215,9 +215,10 @@ def _fuse_grid_block_device(sd, loader, views, block, bbox,
                             anisotropy=None, patch_quantum=32,
                             compute_block_shape=None, stats=None,
                             inside_offset=(0.0, 0.0, 0.0),
-                            coefficients=None):
+                            coefficients=None, pool=None):
     """:func:`fuse_grid_block` up to the kernel's end: the DEVICE (fused,
-    wsum) of the static compute shape, or None when no view overlaps."""
+    wsum) of the static compute shape, or None when no view overlaps.
+    ``pool``: where the block's views are read side by side."""
     bkey = tuple(map(int, block.offset))
     blend = blend or BlendParams()
     bshape = tuple(compute_block_shape or block.size)
@@ -232,14 +233,14 @@ def _fuse_grid_block_device(sd, loader, views, block, bbox,
         _BLOCKS_BY_KERNEL["shift"].inc()
         return _fuse_shift_path(
             loader, plans, block, block_global, bshape, fusion_type, blend,
-            stats, inside_offset,
+            stats, inside_offset, pool,
         )
 
     if coefficients is None and all(p.is_diagonal for p in plans):
         _BLOCKS_BY_KERNEL["sep"].inc()
         return _fuse_sep_path(
             sd, loader, plans, block, bshape, fusion_type, blend, stats,
-            inside_offset, patch_quantum,
+            inside_offset, patch_quantum, pool,
         )
 
     _BLOCKS_BY_KERNEL["gather"].inc()
@@ -249,7 +250,8 @@ def _fuse_grid_block_device(sd, loader, views, block, bbox,
     )
     (patches, affines, offsets, img_dims, borders, ranges, valid, ioffs,
      coeffs, coeff_affs) = _gather_inputs(
-        sd, loader, plans, pshape, vb, blend, inside_offset, coefficients)
+        sd, loader, plans, pshape, vb, blend, inside_offset, coefficients,
+        pool)
 
     if stats is not None:
         stats.compile_keys.add((bshape, pshape, vb, fusion_type,
@@ -443,8 +445,24 @@ def patch_dtype(loader, view_levels) -> np.dtype:
     return np.dtype(np.float32)
 
 
+def _read_boxes(loader, plans, offsets, pshape, patches, pool=None):
+    """Each plan's source box at ``offsets[i]`` into ``patches[i]``: one
+    after another, or side by side on ``pool`` (the per-block driver's,
+    whose device would otherwise sit through four reads a block)."""
+    def read(i, p):
+        with profiling.span("fusion.prefetch"):
+            patches[i] = loader.read_block(
+                p.view, p.level, tuple(offsets[i]), pshape)
+
+    if pool is None or len(plans) < 2:
+        for i, p in enumerate(plans):
+            read(i, p)
+    else:
+        list(pool.map(read, range(len(plans)), plans))
+
+
 def _gather_inputs(sd, loader, plans, pshape, vb, blend, inside_offset,
-                   coefficients):
+                   coefficients, pool=None):
     """Host-side input staging for the general gather kernel: prefetch the
     clipped source boxes and assemble the per-view parameter arrays."""
     patches = np.zeros((vb, *pshape), dtype=patch_dtype(
@@ -455,10 +473,9 @@ def _gather_inputs(sd, loader, plans, pshape, vb, blend, inside_offset,
     borders = np.zeros((vb, 3), dtype=np.float32)
     ranges = np.ones((vb, 3), dtype=np.float32)
     valid = np.zeros((vb,), dtype=np.float32)
+    _read_boxes(loader, plans, [p.patch_offset for p in plans], pshape,
+                patches, pool)
     for i, p in enumerate(plans):
-        with profiling.span("fusion.prefetch"):
-            patches[i] = loader.read_block(
-                p.view, p.level, tuple(p.patch_offset), pshape)
         affines[i] = p.affine
         offsets[i] = p.patch_offset
         img_dims[i] = p.img_dim
@@ -477,7 +494,7 @@ def _gather_inputs(sd, loader, plans, pshape, vb, blend, inside_offset,
 
 
 def _shift_inputs(loader, plans, block_global, bshape, vb, blend,
-                  inside_offset):
+                  inside_offset, pool=None):
     """Host-side input staging for the translation shifted-slice kernel."""
     pshape = tuple(s + 1 for s in bshape)
     patches = np.zeros((vb, *pshape), dtype=patch_dtype(
@@ -489,14 +506,13 @@ def _shift_inputs(loader, plans, block_global, bshape, vb, blend,
     ranges = np.ones((vb, 3), dtype=np.float32)
     valid = np.zeros((vb,), dtype=np.float32)
     bg_min = np.asarray(block_global.min, dtype=np.float64)
+    tlevels = [p.inv_total[:, :3] @ bg_min + p.inv_total[:, 3]
+               for p in plans]
+    floor_offs = [np.floor(t).astype(np.int64) for t in tlevels]
+    _read_boxes(loader, plans, floor_offs, pshape, patches, pool)
     for i, p in enumerate(plans):
-        tlevel = p.inv_total[:, :3] @ bg_min + p.inv_total[:, 3]
-        floor_off = np.floor(tlevel).astype(np.int64)
-        with profiling.span("fusion.prefetch"):
-            patches[i] = loader.read_block(
-                p.view, p.level, tuple(floor_off), pshape)
-        fracs[i] = tlevel - floor_off
-        lpos0[i] = tlevel
+        fracs[i] = tlevels[i] - floor_offs[i]
+        lpos0[i] = tlevels[i]
         img_dims[i] = p.img_dim
         factors = loader.downsampling_factors(p.view.setup)[p.level]
         borders[i] = np.asarray(blend.border) / np.asarray(factors, dtype=np.float64)
@@ -506,13 +522,14 @@ def _shift_inputs(loader, plans, block_global, bshape, vb, blend,
     return patches, fracs, lpos0, img_dims, borders, ranges, valid, ioffs
 
 
-def _sep_inputs(sd, loader, plans, pshape, vb, blend, inside_offset):
+def _sep_inputs(sd, loader, plans, pshape, vb, blend, inside_offset,
+                pool=None):
     """Host-side staging for the diagonal separable kernel: same clipped
     patch prefetch as the gather path, plus the per-view (diag, t) of the
     block-index -> patch-coordinate affine."""
     (patches, affines, offsets, img_dims, borders, ranges, valid, ioffs,
      _c, _ca) = _gather_inputs(sd, loader, plans, pshape, vb, blend,
-                               inside_offset, None)
+                               inside_offset, None, pool)
     diags = np.ascontiguousarray(
         np.stack([np.diagonal(affines[i, :, :3]) for i in range(vb)]))
     ts = np.ascontiguousarray(affines[:, :, 3])
@@ -520,7 +537,8 @@ def _sep_inputs(sd, loader, plans, pshape, vb, blend, inside_offset):
 
 
 def _fuse_sep_path(sd, loader, plans, block, bshape, fusion_type, blend,
-                   stats, inside_offset=(0.0, 0.0, 0.0), patch_quantum=32):
+                   stats, inside_offset=(0.0, 0.0, 0.0), patch_quantum=32,
+                   pool=None):
     """Diagonal-affine blocks (e.g. --preserveAnisotropy over
     translation-registered views): separable interpolation GEMMs, no
     gathers."""
@@ -528,7 +546,8 @@ def _fuse_sep_path(sd, loader, plans, block, bshape, fusion_type, blend,
     pshape = F.bucket_shape(
         np.max([p.patch_interval.shape for p in plans], axis=0), patch_quantum)
     (patches, diags, ts, offsets, img_dims, borders, ranges, valid, ioffs
-     ) = _sep_inputs(sd, loader, plans, pshape, vb, blend, inside_offset)
+     ) = _sep_inputs(sd, loader, plans, pshape, vb, blend, inside_offset,
+                     pool)
     if stats is not None:
         stats.compile_keys.add((bshape, pshape, "sep", vb, fusion_type))
     return _run_block_kernel(
@@ -538,13 +557,13 @@ def _fuse_sep_path(sd, loader, plans, block, bshape, fusion_type, blend,
 
 
 def _fuse_shift_path(loader, plans, block, block_global, bshape, fusion_type,
-                     blend, stats, inside_offset=(0.0, 0.0, 0.0)):
+                     blend, stats, inside_offset=(0.0, 0.0, 0.0), pool=None):
     """Translation-only blocks: 8-shifted-slice kernel, no gather, one compile
     per (block shape, view bucket)."""
     vb = F.bucket_views(len(plans))
     (patches, fracs, lpos0, img_dims, borders, ranges, valid, ioffs
      ) = _shift_inputs(loader, plans, block_global, bshape, vb, blend,
-                       inside_offset)
+                       inside_offset, pool)
     if stats is not None:
         stats.compile_keys.add((bshape, "shift", vb, fusion_type))
     return _run_block_kernel(
@@ -1361,7 +1380,7 @@ def fuse_volume(
             sd, loader, views, block, bbox, fusion_type, blend, aniso,
             compute_block_shape=compute_block, stats=stats,
             inside_offset=mask_offset if masks else (0.0, 0.0, 0.0),
-            coefficients=coefficients,
+            coefficients=coefficients, pool=pool,
         )
         stats.blocks += 1
         if dev_out is None:
@@ -1404,8 +1423,10 @@ def fuse_volume(
                         stage="affine-fusion")
 
     from ..parallel.retry import run_with_retry
+    from ..utils.threads import CtxThreadPool
 
-    run_with_retry(grid, process, label="fusion block")
+    with CtxThreadPool(max_workers=max(1, io_threads)) as pool:
+        run_with_retry(grid, process, label="fusion block")
     stats.seconds = time.time() - t0
     _record_fusion_stage("affine-fusion", stats, "per-block")
     return stats

@@ -268,3 +268,28 @@ def downsample_pyramid_level(
         done=len(grid), blocks=len(grid), seconds=round(dt, 3),
         rate_per_s=round(len(grid) / max(dt, 1e-9), 3),
     )
+
+
+def write_pyramid(store, mr_levels, is_zarr5d, ct, epilogue_levels=0):
+    """Downsample s0 into the remaining pyramid levels
+    (SparkAffineFusion.java:703-782). Each level reads chunks the previous
+    stage may have written on another host -> barrier per boundary.
+
+    ``epilogue_levels``: how many leading levels the fusion drivers already
+    materialized as a fused multiscale epilogue this run. Their container
+    markers are set (and stale ones from earlier runs revoked) before the
+    barrier, then ``downsample_pyramid_level(skip_existing=True)`` skips
+    exactly those — no full-res container re-read for levels that rode the
+    fusion drain."""
+    from ..io.container import set_epilogue_written
+    from ..parallel.distributed import barrier, world
+
+    if world()[0] == 0:  # one writer for the shared container attributes
+        for lvl in range(1, len(mr_levels)):
+            set_epilogue_written(store, mr_levels[lvl].dataset, ct,
+                                 lvl <= epilogue_levels)
+    barrier("fusion-s0")
+    for lvl in range(1, len(mr_levels)):
+        downsample_pyramid_level(store, mr_levels[lvl - 1], mr_levels[lvl],
+                                 is_zarr5d, ct, skip_existing=True)
+        barrier(f"fusion-s{lvl}")

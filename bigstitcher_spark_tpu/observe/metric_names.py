@@ -115,8 +115,16 @@ METRICS: dict[str, str] = {
     "bst_fusion_voxels_total":
         "output voxels whose block the fusion driver has written",
     "bst_fusion_blocks_total":
-        "blocks the per-block fusion driver sent to a kernel, labeled by "
-        "kernel (shift | sep | gather): which one a block's views allowed",
+        "blocks a fusion driver sent to a kernel, labeled by kernel: the "
+        "per-block affine driver's shift | sep | gather (which one a "
+        "block's views allowed, counted at the choice), the non-rigid "
+        "driver's nonrigid (counted as the block is written)",
+    # non-rigid fusion's host fits (models/nonrigid_fusion.py)
+    "bst_nonrigid_control_points_total":
+        "unique interest points that entered a control-grid fit, summed "
+        "over the fits (a view and compute block each)",
+    "bst_nonrigid_fit_seconds_total":
+        "seconds inside fit_control_grid, summed over the pool's threads",
     # JAX's own compile events (observe/compiles.py), by phase: trace =
     # jaxpr tracing, lower = jaxpr to MLIR, backend_compile = XLA build or
     # persistent-cache load (cache_load is the load's own part of that)
@@ -329,9 +337,25 @@ SPANS: dict[str, str] = {
     "jax.compile":
         "one JAX compile-pipeline event (instant at its end; stage = the "
         "span open meanwhile, item = phase and function, bytes unused)",
-    "nonrigid.kernel": "nonrigid fusion device computation",
+    "nonrigid.stage": "one fuse_nonrigid_volume call (tree root)",
+    "nonrigid.unique_points":
+        "interest points and correspondences loaded and joined into unique "
+        "points (beside the root: once a channel and timepoint)",
+    "nonrigid.plan":
+        "one block's control grids fitted and source boxes found, its "
+        "views side by side on the pool (item = block), a batch ahead of "
+        "the device",
+    "nonrigid.fit": "one view's control grid of one block (item = view)",
+    "nonrigid.prefetch":
+        "one view's source box of one block read and decoded (item = "
+        "view), the block's views side by side",
+    "nonrigid.h2d":
+        "explicit upload of one batch's stacked inputs until it is done",
+    "nonrigid.kernel":
+        "one batch's dispatch (its compile, the first time) and, where its "
+        "outputs are fetched, the wait until they are ready",
+    "nonrigid.d2h": "fetch of one finished batch's fused blocks",
     "nonrigid.write": "nonrigid fused block write",
-    "nonrigid.prefetch": "nonrigid source patch prefetch",
     "matching.group_pair": "descriptor matching for one view-group pair",
     "matching.pair": "descriptor matching for one view pair",
     # shared mesh work loop (parallel/mesh.py)

@@ -75,29 +75,35 @@ def _sample_one_view_nonrigid(
     from .fusion import _separable_interp_matrix
 
     L = block_shape
-    so = grid  # (Gx,Gy,Gz,12)
-    for d in range(3):
-        pos = (block_origin[d] + jnp.arange(L[d], dtype=jnp.float32)
-               - grid_origin[d]) / grid_spacing[d]
-        m = _separable_interp_matrix(pos, grid.shape[d])
-        so = jnp.tensordot(so, m, axes=[[0], [1]])
-    A = so.reshape(3, 4, *L)  # per-voxel affine coefficients
-    wx = block_origin[0] + jnp.arange(L[0], dtype=jnp.float32)[:, None, None]
-    wy = block_origin[1] + jnp.arange(L[1], dtype=jnp.float32)[None, :, None]
-    wz = block_origin[2] + jnp.arange(L[2], dtype=jnp.float32)[None, None, :]
-    deformed = [A[i, 0] * wx + A[i, 1] * wy + A[i, 2] * wz + A[i, 3]
-                for i in range(3)]
-    p = jnp.stack([
-        (view_affine[i, 0] * deformed[0] + view_affine[i, 1] * deformed[1]
-         + view_affine[i, 2] * deformed[2] + view_affine[i, 3]).ravel()
-        for i in range(3)
-    ], axis=-1)  # (N,3) patch coords
-    val = _trilinear_sample(patch, p)
-    lpos = p + patch_offset
-    inside = jnp.all(
-        (lpos >= 0.0) & (lpos <= img_dim - 1.0), axis=-1
-    ).astype(jnp.float32)
-    w_blend = _blend_weight(lpos, img_dim, border, blend_range)
+    with jax.named_scope("deform"):
+        so = grid  # (Gx,Gy,Gz,12)
+        for d in range(3):
+            pos = (block_origin[d] + jnp.arange(L[d], dtype=jnp.float32)
+                   - grid_origin[d]) / grid_spacing[d]
+            m = _separable_interp_matrix(pos, grid.shape[d])
+            so = jnp.tensordot(so, m, axes=[[0], [1]])
+        A = so.reshape(3, 4, *L)  # per-voxel affine coefficients
+        wx = block_origin[0] + jnp.arange(
+            L[0], dtype=jnp.float32)[:, None, None]
+        wy = block_origin[1] + jnp.arange(
+            L[1], dtype=jnp.float32)[None, :, None]
+        wz = block_origin[2] + jnp.arange(
+            L[2], dtype=jnp.float32)[None, None, :]
+        deformed = [A[i, 0] * wx + A[i, 1] * wy + A[i, 2] * wz + A[i, 3]
+                    for i in range(3)]
+        p = jnp.stack([
+            (view_affine[i, 0] * deformed[0] + view_affine[i, 1] * deformed[1]
+             + view_affine[i, 2] * deformed[2] + view_affine[i, 3]).ravel()
+            for i in range(3)
+        ], axis=-1)  # (N,3) patch coords
+    with jax.named_scope("sample"):
+        val = _trilinear_sample(patch, p)
+    with jax.named_scope("blend_weights"):
+        lpos = p + patch_offset
+        inside = jnp.all(
+            (lpos >= 0.0) & (lpos <= img_dim - 1.0), axis=-1
+        ).astype(jnp.float32)
+        w_blend = _blend_weight(lpos, img_dim, border, blend_range)
     return val, inside, w_blend
 
 
@@ -126,7 +132,9 @@ def nonrigid_fuse_block_impl(
         one, in_axes=(0, 0, 0, 0, 0, 0, 0, None, None, None),
     )(patches, grids, view_affines, patch_offsets, img_dims, borders,
       blend_ranges, block_origin, grid_origin, grid_spacing)
-    fused, wsum = _combine_views(vals, insides, wblends, valid, fusion_type)
+    with jax.named_scope("accumulate"):
+        fused, wsum = _combine_views(vals, insides, wblends, valid,
+                                     fusion_type)
     return fused.reshape(block_shape), wsum.reshape(block_shape)
 
 
@@ -138,6 +146,12 @@ nonrigid_fuse_block = jax.jit(
 # ---------------------------------------------------------------------------
 # host-side control-grid fitting (moving least squares, IDW weights)
 # ---------------------------------------------------------------------------
+
+# vertices fitted at a time: their (chunk, M, 4) float64 temporaries stay in
+# the cache (2 MB at M = 100) where the whole grid's 13 456 vertices made 30
+# MB and more of them, mapped and faulted in anew for every fit
+_FIT_CHUNK = 512
+
 
 def fit_control_grid(
     targets: np.ndarray,         # (M,3) averaged world positions of unique IPs
@@ -155,39 +169,46 @@ def fit_control_grid(
     SparkNonRigidFusion.java:373-402). Falls back to the global affine (or
     translation) fit when points are scarce. Returns (Gx,Gy,Gz,12) float32.
     """
-    gx, gy, gz = grid_dims
-    G = gx * gy * gz
-    m = len(targets)
-    idx = np.indices((gx, gy, gz)).reshape(3, -1).T  # (G,3)
-    verts = grid_origin + idx * spacing
+    return fit_vertex_models(targets, view_world, grid_origin, grid_dims,
+                             spacing, alpha, reg_eps
+                             ).reshape(*grid_dims, 12).astype(np.float32)
 
-    out = np.zeros((G, 3, 4))
+
+def fit_vertex_models(targets, view_world, grid_origin, grid_dims, spacing,
+                      alpha: float = 1.0, reg_eps: float = 1e-6
+                      ) -> np.ndarray:
+    """``fit_control_grid`` before the cast: (G,3,4) float64, vertices in
+    C order over the grid."""
+    m = len(targets)
+    verts = grid_origin + np.indices(grid_dims).reshape(3, -1).T * spacing
+    out = np.zeros((len(verts), 3, 4))
     out[:, :, :3] = np.eye(3)
     if m == 0:
-        return out.reshape(gx, gy, gz, 12).astype(np.float32)
+        return out
     if m < 4:
         # translation-only fallback: mean displacement
-        t = (view_world - targets).mean(axis=0)
-        out[:, :, 3] = t
-        return out.reshape(gx, gy, gz, 12).astype(np.float32)
-
-    d = np.linalg.norm(verts[:, None, :] - targets[None, :, :], axis=2)  # (G,M)
-    w = 1.0 / (d**alpha + 0.5)
+        out[:, :, 3] = (view_world - targets).mean(axis=0)
+        return out
 
     # solve in vertex-centered coordinates (both sides), which keeps the
     # normal equations well-conditioned and makes the tiny identity
     # regularizer scale-free: fit maps (p - vert) -> (q - vert)
-    pc = targets[None, :, :] - verts[:, None, :]          # (G,M,3)
-    qc = view_world[None, :, :] - verts[:, None, :]
-    ph = np.concatenate([pc, np.ones((G, m, 1))], axis=2)  # (G,M,4)
-    A = np.einsum("gm,gmi,gmj->gij", w, ph, ph)
-    B = np.einsum("gm,gmi,gmk->gik", w, ph, qc)
-    lam = reg_eps * w.sum(axis=1)[:, None, None]
     x_id = np.zeros((4, 3))
     x_id[:3, :3] = np.eye(3)
-    sol = np.linalg.solve(A + lam * np.eye(4), B + lam * x_id)  # (G,4,3)
-    lin = np.swapaxes(sol[:, :3, :], 1, 2)                # (G,3,3)
-    t = sol[:, 3, :] + verts - np.einsum("gij,gj->gi", lin, verts)
-    out[:, :, :3] = lin
-    out[:, :, 3] = t
-    return out.reshape(gx, gy, gz, 12).astype(np.float32)
+    ph = np.ones((min(_FIT_CHUNK, len(verts)), m, 4))
+    for s in range(0, len(verts), _FIT_CHUNK):
+        v = verts[s:s + _FIT_CHUNK]
+        p = ph[:len(v)]                                   # (g,M,4)
+        np.subtract(targets[None], v[:, None], out=p[:, :, :3])
+        qc = view_world[None] - v[:, None]
+        d = np.sqrt(np.einsum("gmi,gmi->gm", p[:, :, :3], p[:, :, :3]))
+        w = 1.0 / (d**alpha + 0.5)
+        wp = (w[:, :, None] * p).transpose(0, 2, 1)       # (g,4,M)
+        lam = reg_eps * w.sum(axis=1)[:, None, None]
+        sol = np.linalg.solve(wp @ p + lam * np.eye(4),
+                              wp @ qc + lam * x_id)       # (g,4,3)
+        lin = np.swapaxes(sol[:, :3, :], 1, 2)            # (g,3,3)
+        out[s:s + _FIT_CHUNK, :, :3] = lin
+        out[s:s + _FIT_CHUNK, :, 3] = sol[:, 3, :] + v \
+            - np.einsum("gij,gj->gi", lin, v)
+    return out

@@ -292,6 +292,8 @@ def stack_inputs(inputs: Sequence, j: int):
                            if not isinstance(p, jax.Array)))
         return jnp.stack([jax.device_put(jnp.asarray(p), dev0)
                           for p in parts])
+    if len(parts) == 1:
+        return np.asarray(parts[0])[None]   # a view: one block, no copy
     return np.stack(parts)
 
 
@@ -311,6 +313,7 @@ def run_sharded_batches(
     device_drain: bool = False,
     device_consume=None,
     prefetch_boxes=None,
+    fetch=None,
 ):
     """The shared multi-device work loop: every sharded stage driver (fusion,
     detection, nonrigid, downsample) is this pattern — the TPU replacement of
@@ -374,7 +377,12 @@ def run_sharded_batches(
     frontier — roughly batch k+2's boxes while batch k runs — so remote
     chunk fetches overlap device compute instead of serializing inside
     ``build``. Purely advisory: with the prefetcher off (the knobs' zero
-    defaults) nothing is enqueued and no code path changes."""
+    defaults) nothing is enqueued and no code path changes.
+
+    ``fetch(outs) -> host arrays`` takes the place of the driver's batched
+    ``jax.device_get`` under ``mesh.d2h`` (the plain path only: neither
+    ``device_drain`` nor ``device_consume``) where a driver brackets the
+    wait for its kernel and the transfer with spans of its own."""
     from .retry import run_with_retry
 
     if multihost:
@@ -533,6 +541,8 @@ def run_sharded_batches(
             if drain_pool is not None:
                 _drain_per_device(outs, batch, consume, drain_pool, label, bi,
                                   device_consume)
+            elif device_consume is None and fetch is not None:
+                outs = fetch(outs)
             elif device_consume is None:
                 # device-array nbytes are free to read pre-fetch: the span
                 # carries the batch's wire payload for the trace-report D2H
@@ -559,6 +569,11 @@ def run_sharded_batches(
             # drained or dead, the buffers leave the ledger either way —
             # a fetch error must not shrink the window for the whole run
             window.release(cost)
+        # builds that finished under this batch's kernel go to the device
+        # before its outputs are written: the first call above finds the
+        # second batch of a run still staging, and the device would sit
+        # through the first one's writes
+        dispatch_ahead(bi)
         if drain_pool is None and outs is not None:
             flat = (list(outs) if device_consume is None
                     else [d for ds_ in outs for d in ds_])

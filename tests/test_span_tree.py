@@ -268,6 +268,74 @@ class TestCountsAtTheBoundaries:
             2 * data.nbytes
 
 
+class TestPerBlockDriverReadsSideBySide:
+    """The per-block affine driver reads a block's views on its pool
+    (``io_threads``): the same bytes stored as one after another, and
+    every read's ``fusion.prefetch`` span under the stage's tree though
+    it ran on another thread."""
+
+    @pytest.mark.parametrize("out_dtype", ["float32", "uint16"])
+    def test_same_bytes_and_one_tree(self, tmp_path, out_dtype):
+        from bigstitcher_spark_tpu.io.chunkstore import (
+            ChunkStore, StorageFormat,
+        )
+        from bigstitcher_spark_tpu.io.dataset_io import ViewLoader
+        from bigstitcher_spark_tpu.io.spimdata import SpimData
+        from bigstitcher_spark_tpu.models.affine_fusion import fuse_volume
+        from bigstitcher_spark_tpu.utils.geometry import (
+            Interval, transformed_interval,
+        )
+        from bigstitcher_spark_tpu.utils.testdata import (
+            make_synthetic_project,
+        )
+
+        proj = make_synthetic_project(str(tmp_path / "p"), n_tiles=(2, 2, 1),
+                                      jitter=0.0, seed=5)
+        sd = SpimData.load(proj.xml_path)
+        views = sd.view_ids()
+        bbox = None
+        for v in views:
+            b = transformed_interval(sd.model(v),
+                                     Interval.from_shape(sd.view_size(v)))
+            bbox = b if bbox is None else bbox.union(b)
+        stored, snaps = {}, {}
+        for threads in (1, 4):
+            st = ChunkStore.create(str(tmp_path / f"t{threads}.n5"),
+                                   StorageFormat.N5)
+            ds = st.create_dataset("f", bbox.shape, (64, 64, 32), out_dtype)
+            trace.reset()
+            trace.configure(buffer_bytes=1 << 22)
+            profiling.enable(True)
+            stats = fuse_volume(
+                sd, ViewLoader(sd), views, ds, bbox, block_size=(64, 64, 32),
+                block_scale=(1, 1, 1), out_dtype=out_dtype,
+                min_intensity=0.0, max_intensity=65535.0,
+                device_resident=False, devices=1, io_threads=threads)
+            assert stats.voxels == bbox.num_elements
+            stored[threads] = ds.read_full()
+            snaps[threads] = trace.snapshot()
+        assert stored[4].any()
+        np.testing.assert_array_equal(stored[1], stored[4])
+        for threads, snap in snaps.items():
+            stage = next(e for e in snap
+                         if e["name"] == "fusion.stage" and e["ph"] == "B")
+            reads = [e for e in snap
+                     if e["name"] == "fusion.prefetch" and e["ph"] == "B"]
+            parent = {e["id"]: e["parent"] for e in snap if e["ph"] == "B"}
+
+            def root(i):
+                while parent[i]:
+                    i = parent[i]
+                return i
+
+            assert reads and all(root(e["id"]) == stage["id"] for e in reads)
+        # a block that two or more views cover is read off the main thread
+        stage = next(e for e in snaps[4]
+                     if e["name"] == "fusion.stage" and e["ph"] == "B")
+        assert any(e["tid"] != stage["tid"] for e in snaps[4]
+                   if e["name"] == "fusion.prefetch")
+
+
 class TestNames:
     def test_every_new_name_is_declared_once(self):
         from bigstitcher_spark_tpu.observe import metric_names as mn
