@@ -30,7 +30,13 @@ from ..io.spimdata import (
     registration_hash,
 )
 from ..ops.downsample import downsample_block
-from ..ops.phasecorr import pad_to, pcm_peaks_batch, refine_peaks
+from ..ops.phasecorr import (
+    as_uint16_lossless,
+    device_sums,
+    pad_to,
+    pcm_peaks_batch,
+    refine_peaks,
+)
 from ..utils.geometry import (
     Interval,
     concatenate,
@@ -44,6 +50,11 @@ from ..observe import metrics as _metrics
 _H2D_BYTES = _metrics.counter("bst_xfer_h2d_bytes_total")
 _H2D_SAVED = _metrics.counter("bst_xfer_h2d_bytes_saved_total")
 _PAIRS_DONE = _metrics.counter("bst_stitching_pairs_total")
+_REFINE_PAIRS = {
+    scorer: _metrics.counter("bst_stitching_refine_pairs_total",
+                             scorer=scorer)
+    for scorer in ("device", "host")}
+_REFINE_CANDIDATES = _metrics.counter("bst_stitching_refine_candidates_total")
 
 
 @dataclass
@@ -383,16 +394,24 @@ _FFT_WORKSPACE_MULT = 4.0
 def stitch_jobs(sd, jobs: list[_PairJob], params: StitchingParams,
                 devices: int | None = None, multihost: bool | None = None
                 ) -> list[PairwiseStitchingResult]:
-    """Run the device PCM + host refinement pipeline over prepared jobs.
+    """Run the device PCM + refinement pipeline over prepared jobs.
 
     Chunks (shape-bucketed pair batches) become pair-scheduler tasks spread
     over every local device (parallel.pairsched): placement is weighted by
     FFT volume, each device bounds its dispatched-but-undrained bytes with
     its own window (inputs x FFT workspace multiplier against the
     device-derived budget — ``params.inflight_bytes`` overrides), and each
-    device's drain is pipelined so host refinement of one bucket overlaps
+    device's drain is pipelined so the refinement of one bucket overlaps
     the device FFTs of the next. One local device degrades to exactly that
     pipelined loop on the caller's thread (the pre-sharding path).
+
+    A bucket's two stacks are uploaded once and serve both halves: the PCM,
+    and — where the crops are whole uint16 numbers, so that every Pearson
+    sum is an integer — the refinement's candidate scorer, which runs on
+    the device that holds them (ops/phasecorr.pearson_sums; the search and
+    r stay on the host, in float64). A bucket of any other crops (rendered,
+    averaged, float) is scored on the host from float64 summed-area tables.
+    The data picks; ``bst_stitching_refine_pairs_total{scorer}`` counts.
 
     In a multi-process world chunks split across processes FIRST
     (cost-aware LPT over FFT volume), each process's slice over its
@@ -433,15 +452,15 @@ def stitch_jobs(sd, jobs: list[_PairJob], params: StitchingParams,
         with profiling.span("stitching.kernel"):
             return _dispatch_bucket(chunk, shp, params)
 
-    def drain(seg_tasks, peaks_devs):
+    def drain(seg_tasks, handles):
         # one pipelined fetch for the whole segment: the round-trip
         # latency is paid per memory-bounded segment, not per shape bucket
         with profiling.span("stitching.kernel_sync"):
-            peaks_list = jax.device_get(list(peaks_devs))
+            peaks_list = jax.device_get([p for p, _ in handles])
         out = []
-        for task, peaks in zip(seg_tasks, peaks_list):
+        for task, peaks, (_, stacks) in zip(seg_tasks, peaks_list, handles):
             shp, chunk = task.tag
-            out.append(_refine_bucket(sd, chunk, shp, peaks, params))
+            out.append(_refine_bucket(sd, chunk, shp, peaks, stacks, params))
         return out
 
     per_chunk = run_pair_tasks(tasks, dispatch, drain, n_devices=devices,
@@ -452,36 +471,11 @@ def stitch_jobs(sd, jobs: list[_PairJob], params: StitchingParams,
             if chunk_results is not None for r in chunk_results]
 
 
-def _as_uint16_lossless(stack: np.ndarray) -> np.ndarray | None:
-    """uint16 copy of the stack when every value survives the round-trip
-    exactly (integral, in range — single-channel stored-level crops), else
-    None. NaN/inf/out-of-range values are rejected by a min/max pre-check
-    BEFORE the cast: casting them to uint16 is C-implementation-defined
-    and raises numpy 'invalid value encountered in cast' RuntimeWarnings
-    (ADVICE r5). Fractional in-range values cast quietly and fail the
-    equality check."""
-    if stack.dtype == np.uint16:
-        return stack
-    if stack.dtype.kind in "iu":
-        if stack.size == 0:
-            return stack.astype(np.uint16)
-        mn, mx = stack.min(), stack.max()
-        if mn < 0 or mx > np.iinfo(np.uint16).max:
-            return None
-        return stack.astype(np.uint16)
-    if stack.dtype.kind != "f":
-        return None
-    if stack.size == 0:
-        return stack.astype(np.uint16)
-    mn, mx = stack.min(), stack.max()  # min/max propagate NaN
-    if (not np.isfinite(mn) or not np.isfinite(mx)
-            or mn < 0 or mx > np.iinfo(np.uint16).max):
-        return None
-    u = stack.astype(np.uint16)
-    return u if np.array_equal(stack, u) else None
-
-
 def _dispatch_bucket(jobs: list[_PairJob], shp, params):
+    """Pack and upload one bucket's two stacks, start its PCM, and hand
+    back ``(peaks, stacks)`` still on the device: ``stacks`` is the
+    resident ``(a, b, ext_a, ext_b)`` the refinement scores on where the
+    crops are whole uint16 numbers, None where they are not."""
     with profiling.span("stitching.pack"):
         a = np.stack([pad_to(j.crop_a, shp) for j in jobs])
         b = np.stack([pad_to(j.crop_b, shp) for j in jobs])
@@ -489,24 +483,35 @@ def _dispatch_bucket(jobs: list[_PairJob], shp, params):
         # kernel sees only two dtype signatures (u16/u16 or f32/f32) per
         # shape bucket: halves the bytes on the PCIe link, and the
         # device cast back to float32 is bit-identical
-        ua = _as_uint16_lossless(a)
-        ub = _as_uint16_lossless(b) if ua is not None else None
-        if ua is not None and ub is not None:
+        ua = as_uint16_lossless(a)
+        ub = as_uint16_lossless(b) if ua is not None else None
+        exact = ub is not None
+        if exact:
             a, b = ua, ub
             _H2D_SAVED.inc(a.size * 4 - a.nbytes + b.size * 4 - b.nbytes)
         ext_a = np.stack([np.array(j.crop_a.shape, np.int32) for j in jobs])
         ext_b = np.stack([np.array(j.crop_b.shape, np.int32) for j in jobs])
     _H2D_BYTES.inc(a.nbytes + b.nbytes + ext_a.nbytes + ext_b.nbytes)
-    return pcm_peaks_batch(a, b, ext_a, ext_b, params.peaks_to_check, 0.25)
+    # one upload serves the PCM and, for whole uint16 numbers, the scorer:
+    # the stacks stay with the bucket until its pairs are refined (the
+    # task's nbytes already counts them). Committed to the device they
+    # landed on, so the scorer runs there from whatever thread calls it
+    stacks = jax.device_put((a, b, ext_a, ext_b), may_alias=True)
+    dev, = stacks[0].devices()
+    stacks = jax.device_put(stacks, dev)
+    peaks = pcm_peaks_batch(*stacks, params.peaks_to_check, 0.25)
+    return peaks, stacks if exact else None
 
 
-def _refine_bucket(sd, jobs: list[_PairJob], shp, peaks,
+def _refine_bucket(sd, jobs: list[_PairJob], shp, peaks, stacks,
                    params) -> list[PairwiseStitchingResult]:
-    # per-peak true-correlation scoring + subpixel on the overlap slices
-    # (host, float64 — see ops/phasecorr.refine_peaks); numpy reductions
-    # release the GIL, so pairs refine in parallel
+    # per-peak true-correlation scoring + subpixel on the overlap boxes
+    # (ops/phasecorr.refine_peaks): the search on the host, the sums it
+    # asks for on the bucket's device where the stacks are whole uint16
+    # numbers (``stacks``), else in float64 on the host
     shifts = np.zeros((len(jobs), 3))
     rs = np.zeros(len(jobs))
+    refined = _REFINE_PAIRS["host" if stacks is None else "device"]
 
     def _refine(k):
         j = jobs[k]
@@ -515,28 +520,43 @@ def _refine_bucket(sd, jobs: list[_PairJob], shp, peaks,
             params.min_overlap_frac
             * min(int(np.prod(j.crop_a.shape)),
                   int(np.prod(j.crop_b.shape))))
+        sums = None
+        if stacks is not None:
+            score = device_sums(*stacks, k)
+
+            def sums(cands):
+                with profiling.span("stitching.refine.score"):
+                    _REFINE_CANDIDATES.inc(len(cands))
+                    return score(cands)
+
         with profiling.span("stitching.refine.pair",
                             item=(j.group_a.views[0].setup,
                                   j.group_b.views[0].setup)):
             shifts[k], rs[k] = refine_peaks(
                 j.crop_a, j.crop_b, peaks[k], shp,
-                min_overlap=min_ov, subpixel=params.subpixel)
+                min_overlap=min_ov, subpixel=params.subpixel, sums=sums)
+        refined.inc()
         _PAIRS_DONE.inc()
 
     with profiling.span("stitching.refine"):
-        # bound concurrent scorers by their SAT footprint: each refine
-        # builds 4 float64 summed-area tables (~32 B/crop voxel), so an
-        # unbounded 8-thread pool over huge crops would hold gigabytes of
-        # transient tables at once. The 2e9 host budget is shared across
-        # the drains actually refining concurrently (the pair scheduler's
-        # active workers; 1 on the inline single-device path)
-        from ..parallel.pairsched import concurrent_pair_workers
+        # pairs refine side by side: a device scorer's pair waits on round
+        # trips, and numpy's reductions release the GIL. The host scorer's
+        # pool is bounded by its footprint besides: each refine builds 4
+        # float64 summed-area tables (~32 B/crop voxel), so an unbounded
+        # 8-thread pool over huge crops would hold gigabytes of transient
+        # tables at once. The 2e9 host budget is shared across the drains
+        # actually refining concurrently (the pair scheduler's active
+        # workers; 1 on the inline single-device path)
+        workers = min(8, len(jobs))
+        if stacks is None:
+            from ..parallel.pairsched import concurrent_pair_workers
 
-        sat_bytes = 32 * max(int(np.prod(j.crop_a.shape))
-                             + int(np.prod(j.crop_b.shape)) for j in jobs)
-        budget = max(1, int(2e9 // max(concurrent_pair_workers(), 1)
-                            // max(sat_bytes, 1)))
-        workers = min(8, len(jobs), budget)
+            sat_bytes = 32 * max(int(np.prod(j.crop_a.shape))
+                                 + int(np.prod(j.crop_b.shape))
+                                 for j in jobs)
+            workers = min(workers, max(1, int(
+                2e9 // max(concurrent_pair_workers(), 1)
+                // max(sat_bytes, 1))))
         if workers > 1:
             from ..utils.threads import CtxThreadPool
 

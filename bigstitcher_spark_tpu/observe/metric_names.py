@@ -112,6 +112,13 @@ METRICS: dict[str, str] = {
     # work done in the end-to-end metrics' own units, as it completes
     "bst_stitching_pairs_total":
         "tile pairs whose shift left the stitching drain (refined)",
+    "bst_stitching_refine_pairs_total":
+        "tile pairs refined, labeled by scorer: device (whole uint16 "
+        "crops: exact integer sums on the bucket's resident stacks) | "
+        "host (any other crops: float64 summed-area tables)",
+    "bst_stitching_refine_candidates_total":
+        "candidate shifts the device scorer summed (each a pass over the "
+        "pair's two resident stacks)",
     "bst_fusion_voxels_total":
         "output voxels whose block the fusion driver has written",
     "bst_fusion_blocks_total":
@@ -326,9 +333,12 @@ SPANS: dict[str, str] = {
     "stitching.pack":
         "host-only part of stitching.kernel: pad, stack, lossless cast",
     "stitching.kernel_sync": "PCM device completion sync",
-    "stitching.refine": "host-side Pearson refinement of PCM peaks",
+    "stitching.refine": "one bucket's Pearson refinement of PCM peaks",
     "stitching.refine.pair":
         "one pair's Pearson refinement on its pool thread",
+    "stitching.refine.score":
+        "one device scorer call with its fetch: a round's unscored "
+        "candidates of one pair",
     "stitching.store": "driver-side collect of kept pair results",
     # project model (io/spimdata.py) — L4
     "spimdata.load": "project XML fetch and parse",

@@ -110,7 +110,7 @@ def test_uint16_transport_is_bit_identical():
     """The lossless h2d downcast (integral float32 crops sent as uint16,
     cast back on device) must produce exactly the same peaks, and must
     not engage for fractional crops (channel averages)."""
-    from bigstitcher_spark_tpu.models.stitching import _as_uint16_lossless
+    from bigstitcher_spark_tpu.ops.phasecorr import as_uint16_lossless
     from bigstitcher_spark_tpu.ops.phasecorr import pcm_peaks_batch
 
     rng = np.random.RandomState(1)
@@ -119,15 +119,15 @@ def test_uint16_transport_is_bit_identical():
     pk_f = np.asarray(pcm_peaks_batch(jnp.asarray(crop), jnp.asarray(crop),
                                       jnp.asarray(ext), jnp.asarray(ext),
                                       5, 0.25))
-    t = _as_uint16_lossless(crop)
+    t = as_uint16_lossless(crop)
     assert t is not None and t.dtype == np.uint16
     pk_u = np.asarray(pcm_peaks_batch(jnp.asarray(t), jnp.asarray(t),
                                       jnp.asarray(ext), jnp.asarray(ext),
                                       5, 0.25))
     np.testing.assert_array_equal(pk_f, pk_u)
-    assert _as_uint16_lossless(crop + 0.5) is None      # fractional
-    assert _as_uint16_lossless(crop - 1e6) is None      # negative
-    assert _as_uint16_lossless(crop + 1e6) is None      # out of range
+    assert as_uint16_lossless(crop + 0.5) is None      # fractional
+    assert as_uint16_lossless(crop - 1e6) is None      # negative
+    assert as_uint16_lossless(crop + 1e6) is None      # out of range
 
 
 def test_segmented_pipeline_matches_single_segment(stitch_project):
