@@ -76,10 +76,9 @@ class Knob:
     via int(float()) clamped >= 0 (accepts "2e9"), ``bool`` via the
     explicit-falsy rule above. ``consumer`` records which layer reads it:
     ``runtime`` (this package), ``wrapper`` (the ./install shell wrappers),
-    ``bench`` (bench.py / scripts), ``tests`` (the pytest suite) —
-    non-runtime knobs are declared so docs, ``bst config`` and the
-    doc-drift test cover the whole surface, not because the package reads
-    them. ``tunable`` marks knobs the ``bst tune`` autotuner may search
+    ``tests`` (the pytest suite) — non-runtime knobs are declared so docs,
+    ``bst config`` and the doc-drift test cover the whole surface, not
+    because the package reads them. ``tunable`` marks knobs the ``bst tune`` autotuner may search
     (performance-only knobs with safe kind-aware bounds)."""
 
     name: str
@@ -260,13 +259,6 @@ _knob("BST_PAIR_MULTIHOST", "str", "auto",
       choices=("auto", "1", "0"))
 
 # -- telemetry -------------------------------------------------------------
-_knob("BST_TELEMETRY_DIR", "str", None,
-      "Telemetry output directory for bench.py runs (CLI tools take "
-      "--telemetry-dir instead).", consumer="bench")
-_knob("BST_TRACE", "bool", False,
-      "Enable the timeline flight recorder without the --trace CLI flag "
-      "(bench.py and scripted runs); the trace archives next to the run "
-      "manifest when telemetry is on.")
 _knob("BST_TRACE_BUFFER_BYTES", "bytes", 64 << 20,
       "Byte budget of the --trace flight-recorder ring buffer "
       "(observe/trace.py); overflow keeps the NEWEST events and counts "
@@ -377,18 +369,6 @@ _knob("BST_DEVICES", "int", None,
       "Virtual CPU mesh size (xla_force_host_platform_device_count) "
       "exported by the ./install shell wrappers — the local[N] analogue.",
       consumer="wrapper")
-
-# -- bench.py --------------------------------------------------------------
-_knob("BST_BENCH_DIR", "str", "/tmp/bst_bench",
-      "Fixture/working directory for bench.py.", consumer="bench")
-_knob("BST_BENCH_TILE", "int", None,
-      "Override the primary bench config's tile edge (e.g. 384 runs "
-      "(384,384,192) tiles).", consumer="bench")
-_knob("BST_BENCH_RUNS", "int", 5,
-      "Fusion benchmark repetitions per config.", consumer="bench")
-_knob("BST_BENCH_FRESH_BASELINE", "bool", True,
-      "Re-measure numpy/tensorstore baselines inside every bench run; 0 "
-      "reuses BASELINE_MEASURED.json.", consumer="bench")
 
 # -- test suite ------------------------------------------------------------
 _knob("BST_TEST_TPU", "bool", False,

@@ -6,8 +6,8 @@ accounting from the Spark metrics system for free; here every layer
 registry. The registry is ALWAYS on — a counter update is one lock
 acquisition per chunk-level operation, invisible next to the IO it
 accounts — while the event log and manifests only activate with
-``--telemetry-dir``. ``bench.py`` snapshots/deltas the same registry, so
-BENCH artifacts gain IO/transfer columns without bespoke glue.
+``--telemetry-dir``; a run manifest's ``metrics`` block is a delta of the
+same registry.
 
 Series are keyed by ``(name, sorted(labels))``; handles stay valid across
 ``reset()`` (values are zeroed in place, series are never dropped), so hot

@@ -359,8 +359,8 @@ def dump_live(path: str) -> str:
     / CLI surface of the on-demand flight-recorder snapshot."""
     if not _STATE["enabled"]:
         raise RuntimeError(
-            "flight recorder is not recording — enable it with --trace / "
-            "BST_TRACE=1 (the serve daemon records always)")
+            "flight recorder is not recording — enable it with --trace "
+            "(the serve daemon records always)")
     return dump(path)
 
 

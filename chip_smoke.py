@@ -106,16 +106,68 @@ def child_fixture(work: str, seed: int, size: dict) -> None:
         }, f)
 
 
+def reference_fuse_block(sd, loader, views, block_global, blend_range=40.0):
+    """The smoke's own reference: one output block fused the way the
+    reference's BlkAffineFusion does it, in plain host code, separate from
+    every kernel of the package. Per view: inverse-affine coordinates,
+    trilinear sample (scipy.ndimage.map_coordinates order=1), cosine-edge
+    blend weight; then the weighted average (AVG_BLEND), rounded and
+    clipped to uint16. Runs in the CPU verify child only."""
+    import numpy as np
+    from scipy.ndimage import map_coordinates
+
+    from bigstitcher_spark_tpu.utils.geometry import (
+        Interval, invert_affine, transformed_interval,
+    )
+
+    shape = block_global.shape
+    acc = np.zeros(shape, np.float32)
+    wsum = np.zeros(shape, np.float32)
+    axes = [
+        (np.arange(shape[d], dtype=np.float32) + block_global.min[d]).reshape(
+            [-1 if i == d else 1 for i in range(3)])
+        for d in range(3)
+    ]
+    for v in views:
+        inv = invert_affine(sd.model(v)).astype(np.float32)
+        img_dim = np.asarray(sd.view_size(v), np.float32)
+        src = transformed_interval(inv, block_global).expand(1)
+        img_iv = Interval.from_shape(sd.view_size(v))
+        if not src.overlaps(img_iv):
+            continue
+        clipped = src.intersect(img_iv)
+        if clipped.is_empty():
+            continue
+        patch = loader.read_block(v, 0, tuple(clipped.min), clipped.shape
+                                  ).astype(np.float32)
+        w = None
+        coords = []
+        for i in range(3):
+            li = (inv[i, 0] * axes[0] + inv[i, 1] * axes[1]
+                  + inv[i, 2] * axes[2] + inv[i, 3])  # (X,Y,Z) level coords
+            coords.append(li - np.float32(clipped.min[i]))
+            d = np.minimum(li, (img_dim[i] - 1.0) - li)
+            ramp = 0.5 * (np.cos((1.0 - d / np.float32(blend_range)) * np.pi)
+                          + 1.0)
+            wi = np.where(d < 0, np.float32(0),
+                          np.where(d < blend_range, ramp, np.float32(1)))
+            w = wi if w is None else w * wi
+        val = map_coordinates(patch, coords, order=1, mode="constant",
+                              cval=0.0, output=np.float32)
+        acc += val * w
+        wsum += w
+    fused = np.where(wsum > 0, acc / np.maximum(wsum, np.float32(1e-20)), 0.0)
+    return np.clip(np.round(fused), 0, 65535).astype("uint16")
+
+
 def child_verify(work: str) -> None:
     """The answers, by the repo's own references, on the CPU: solved
     offsets of both routes vs ground truth; fused s0 blocks (corner, centre,
-    far edge) vs the independent numpy fusion ``bench._baseline_fuse_block``
-    at ``bench._validate_fusion``'s tolerance; one s1 block vs the mean of
-    its s0 parents; the rerun container vs the first one."""
+    far edge) vs the numpy fusion ``reference_fuse_block`` above, held to a
+    mean |diff| under one grey level; one s1 block vs the mean of its s0
+    parents; the rerun container vs the first one."""
     import numpy as np
 
-    sys.path.insert(0, REPO)
-    import bench
     from bigstitcher_spark_tpu.io.container import (
         open_container, read_container_meta,
     )
@@ -160,7 +212,7 @@ def child_verify(work: str) -> None:
     }
     for name, off in corners.items():
         shape = tuple(min(b, d - o) for b, d, o in zip(blk, dims, off))
-        ref = bench._baseline_fuse_block(
+        ref = reference_fuse_block(
             sd, loader, sd.view_ids(),
             Interval.from_shape(shape, off).translate(meta.bbox.min))
         got = read(s0, off, shape)

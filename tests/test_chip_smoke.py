@@ -53,6 +53,61 @@ def test_parent_imports_neither_jax_nor_the_package():
     assert r.returncode == 0, r.stderr
 
 
+def test_the_smoke_imports_no_module_bench():
+    """Its numpy fusion is its own: nothing at the repo's root is a
+    second yardstick the smoke leans on."""
+    import ast
+
+    with open(SMOKE) as f:
+        tree = ast.parse(f.read())
+    imported = {a.name.split(".")[0] for n in ast.walk(tree)
+                if isinstance(n, ast.Import) for a in n.names}
+    imported |= {(n.module or "").split(".")[0] for n in ast.walk(tree)
+                 if isinstance(n, ast.ImportFrom)}
+    assert "bench" not in imported
+
+
+@pytest.mark.parametrize("config", ["grid1k", "multiview-affine"])
+def test_the_smokes_reference_agrees_with_the_benchmarks(config, tmp_path):
+    """Two numpy fusions that share no code: the smoke's (float32, voxels
+    through the program's loader from a project on disk) and the cells'
+    (float64, voxels from the seeded generator). Corner, centre and far
+    edge of the bounding box, as the smoke samples them, for a translation
+    grid and for views under general affines. Left out as in the cells'
+    ``correct``: voxels whose summed blend weight is under 1e-5, where the
+    float32 cosine ramp cancels to nought."""
+    import numpy as np
+
+    from benchmark.reference.fixture import Acquisition
+    from benchmark.reference.fusion import fuse_box
+    from bigstitcher_spark_tpu.io.dataset_io import ViewLoader
+    from bigstitcher_spark_tpu.io.spimdata import SpimData
+    from bigstitcher_spark_tpu.utils.geometry import Interval
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           f"{config}.json")) as f:
+        cfg = json.load(f)
+    acq = Acquisition({**cfg["fixture"], **cfg["rehearsal_fixture"]}, 7)
+    acq.write(str(tmp_path), threads=2)
+    sd = SpimData.load(str(tmp_path / "registered.xml"))
+    loader = ViewLoader(sd)
+    shape = np.array([48, 48, 24])
+    covered = 0
+    for lo in (acq.bbox_min, (acq.bbox_min + acq.bbox_max - shape) // 2,
+               acq.bbox_max + 1 - shape):
+        box = Interval.from_shape(tuple(shape), tuple(int(v) for v in lo))
+        got = chip_smoke.reference_fuse_block(sd, loader, sd.view_ids(), box)
+        want, weight = fuse_box(acq, lo, tuple(shape))
+        diff = np.abs(got.astype(np.int64) - want.astype(np.int64))
+        assert diff.mean() < 1.0           # what the smoke holds a block to
+        sound = ~((weight > 0) & (weight < 1e-5))
+        assert diff[sound].max() <= 1      # a rounding step, float32 / 64
+        assert diff[sound].mean() < 1e-3
+        covered += int((weight > 0).sum())
+        assert got.std() > 0
+    assert covered > shape.prod()          # the boxes are not empty space
+
+
 def test_rehearsal_runs_every_stage_and_check_at_toy_size(tmp_path):
     # with the compile cache on (the suite runs without) and placed from
     # outside, so the rerun-adds-nothing check counts real entries

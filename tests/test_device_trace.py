@@ -1,10 +1,11 @@
 """The device's own timeline in the program's recorder (ISSUE 25, part C),
 and the join of the two clocks.
 
-- a CPU ``jax.profiler`` session of two seconds and more: ring timestamps,
-  joined to the profiler's clock by ONE anchor as the benchmark does,
-  agree with the spans' own ``TraceAnnotation`` events within 5 ms — the
-  check on that join which was missing;
+- a CPU ``jax.profiler`` session of two seconds and more: one offset puts
+  every span's ``TraceAnnotation`` inside its ring span, in the ring's
+  order, and both joins (the benchmark's ONE anchor, the program's median
+  over every span) lie within what the spans bracket — held by order and
+  containment, not by milliseconds, so a loaded machine does not fail it;
 - the program-side reduction of the recorded chip trace
   ``tests/benchmark_tests/data/v5e_small.xplane.pb`` (read only) gives
   the module counts ``test_bm_trace_reduce.py`` expects of it;
@@ -74,31 +75,57 @@ def test_ring_and_annotations_agree_over_two_seconds(tmp_path):
     ring = trace.snapshot()
     path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
                                   "*.xplane.pb"))[0]
-    anchor_ns, notes = None, {}
+    anchor, notes = None, {}
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for ev in line.events:
+                a, b = float(ev.start_ns), float(ev.start_ns + ev.duration_ns)
                 if ev.name == "bench.anchor":
-                    anchor_ns = float(ev.start_ns)
+                    anchor = (a, b)
                 elif ev.name in ("fusion.stage", "fusion.kernel"):
-                    notes[dict(ev.stats)["id"]] = (ev.name,
-                                                   float(ev.start_ns))
-    assert anchor_ns is not None
-    offset_ns = anchor_unix_ns - anchor_ns
+                    notes[dict(ev.stats)["id"]] = (ev.name, a, b)
+    assert anchor is not None
     begins = {e["id"]: e for e in ring if e["ph"] == "B"}
+    ends = {e["id"]: e["ts"] * 1e9 for e in ring if e["ph"] == "E"}
     assert len(begins) == 2 * n and n > 50
-    assert set(notes) == set(begins)    # every span opened an annotation
-    late = [abs(e["ts"] * 1e9 - (notes[i][1] + offset_ns))
-            for i, e in begins.items()]
+    assert set(notes) == set(begins) == set(ends)   # a span, an annotation
     assert all(notes[i][0] == e["name"] for i, e in begins.items())
-    assert max(late) < 5e6, f"worst disagreement {max(late) / 1e6:.3f} ms"
-    # the program's own join uses every span as an anchor and says what
-    # is left over
+    # in order: both clocks tell the spans' story in the same sequence,
+    # and a kernel's annotation lies inside its stage's
+    assert sorted(notes, key=lambda i: notes[i][1]) \
+        == sorted(begins, key=lambda i: (begins[i]["ts"], i))
+    for i, e in begins.items():
+        if e["name"] == "fusion.kernel":
+            _name, a, b = notes[e["parent"]]
+            assert a <= notes[i][1] <= notes[i][2] <= b
+    # what they bracket: a span stamps the ring, opens its annotation,
+    # closes it and stamps the ring again, so whatever else the machine is
+    # doing, ONE offset puts every annotation inside its ring span: at
+    # least the largest (begin - annotation start), at most the smallest
+    # (end - annotation end). A stalled thread widens one span's bounds
+    # and narrows nobody's; clocks that run apart leave no such offset.
+    # RESOLUTION is what two software clocks may differ by in a reading
+    # (the ring's float seconds resolve a quarter of a microsecond)
+    RESOLUTION = 50e3
+    lo = max(e["ts"] * 1e9 - notes[i][1] for i, e in begins.items())
+    hi = min(ends[i] - notes[i][2] for i in begins)
+    assert lo <= hi + RESOLUTION, f"no one offset: {(lo - hi) / 1e3:.1f} us"
+    # the benchmark's join reads the host's clock inside ONE annotation:
+    # its offset is bracketed by that annotation's two ends
+    assert anchor_unix_ns - anchor[1] <= hi + RESOLUTION
+    assert anchor_unix_ns - anchor[0] >= lo - RESOLUTION
+    # the program's own join takes every span as an anchor (the median of
+    # begin - annotation start): under it every annotation ends inside its
+    # ring span, and at least half start inside it
     planes = devicetrace.load_planes(path)
     clock = devicetrace._clock(planes["anchors"], ring)
     assert clock["anchors"] == 2 * n
-    assert abs(clock["offset_ns"] - offset_ns) < 5e6
-    assert clock["residual_us"] < 5000 and abs(clock["drift_us"]) < 5000
+    off = clock["offset_ns"]
+    assert all(notes[i][2] + off <= ends[i] + RESOLUTION for i in begins)
+    inside = sum(notes[i][1] + off >= e["ts"] * 1e9 - RESOLUTION
+                 for i, e in begins.items())
+    assert inside >= n
+    assert {"residual_us", "drift_us"} <= set(clock)    # trace-report's
     # a CPU run has no device plane: nothing to place
     assert devicetrace.reduce_planes(planes, ring) is None
 

@@ -9,6 +9,7 @@ violations per check, a clean fixture, and suppression comments;
 the config registry and vice versa."""
 
 import os
+import re
 import shutil
 import textwrap
 from pathlib import Path
@@ -665,7 +666,7 @@ class TestEnvMutationCheck:
                 os.environ.setdefault("BST_PAIR_SHARD", "0")    # line 6
                 os.environ.pop("BST_WRITE_THREADS", None)       # line 7
                 del os.environ["BST_TILE_CACHE_BYTES"]          # line 8
-                os.environ.update({"BST_TRACE": "1"})           # line 9
+                os.environ.update({"BST_NATIVE_IO": "1"})       # line 9
             """})
         fs = [f for f in run_lint(tmp_path) if f.check == "env-mutation"]
         assert sorted(f.line for f in fs) == [5, 6, 7, 8, 9]
@@ -871,9 +872,9 @@ class TestConfigRegistry:
             assert config.get_bool("BST_PAIR_SHARD") is want, raw
 
     def test_unparseable_falls_back_to_default(self, monkeypatch):
-        monkeypatch.setenv("BST_BENCH_RUNS", "not-a-number")
-        assert config.get_int("BST_BENCH_RUNS") == 5
-        assert config.source("BST_BENCH_RUNS") == "default"
+        monkeypatch.setenv("BST_WRITE_THREADS", "not-a-number")
+        assert config.get_int("BST_WRITE_THREADS") == 8
+        assert config.source("BST_WRITE_THREADS") == "default"
 
     def test_undeclared_name_raises(self):
         with pytest.raises(KeyError):
@@ -902,13 +903,38 @@ class TestConfigRegistry:
         assert {r["name"] for r in rows} == set(config.KNOBS)
         assert all(r["doc"] for r in rows)
 
+    # where a knob of each class has to be read (a glob from the repo's
+    # root) and what a read looks like there: a declared name nothing reads
+    # is an option the documents promise and no code honours
+    READERS = {
+        "runtime": ("bigstitcher_spark_tpu/**/*.py",
+                    r"\bget(?:_[a-z]+)?\(\s*[\"']{name}[\"']"),
+        "wrapper": ("install", r"\b{name}\b"),
+        "tests": ("tests/**/*.py",
+                  r"(?:environ|getenv)[^\n]*[\"']{name}[\"']"),
+    }
+
+    @pytest.mark.parametrize("consumer", sorted(READERS))
+    def test_every_knob_has_a_reader_where_its_consumer_says(self, consumer):
+        assert {k.consumer for k in config.KNOBS.values()} == set(
+            self.READERS)
+        where, pattern = self.READERS[consumer]
+        text = "\n".join(p.read_text(encoding="utf-8")
+                         for p in REPO.glob(where)
+                         if p != default_root() / "config.py")
+        names = [n for n, k in config.KNOBS.items() if k.consumer == consumer]
+        assert names
+        unread = [n for n in names
+                  if not re.search(pattern.format(name=n), text)]
+        assert not unread, (
+            f"declared {consumer!r} knobs nothing reads: {unread} — delete "
+            f"the declaration or the class is wrong")
+
 
 class TestDocDrift:
     DOCS = ("README.md", "WORKFLOW.md", "PERF.md")
 
     def _doc_names(self):
-        import re
-
         names: set[str] = set()
         for doc in self.DOCS:
             text = (REPO / doc).read_text(encoding="utf-8")
@@ -921,6 +947,21 @@ class TestDocDrift:
             f"docs mention undeclared knobs: {sorted(undeclared)} — "
             f"declare them in bigstitcher_spark_tpu/config.py or fix "
             f"the docs")
+
+    # a repository source file as a document names it: code, documents,
+    # and the records in capitals at the root. What a run writes
+    # (bst-trace.json, manifest-*.json) is not a source file
+    CITED = (r"(?<![\w./-])((?:[\w.-]+/)*[\w.-]+\.(?:py|sh|md|cpp|toml)"
+             r"|[A-Z_]+\.jsonl?)\b(?!/)")
+
+    @pytest.mark.parametrize("doc", ["README.md", "WORKFLOW.md", "PARITY.md"])
+    def test_every_cited_source_file_exists(self, doc):
+        cited = set(re.findall(self.CITED,
+                               (REPO / doc).read_text(encoding="utf-8")))
+        assert len(cited) >= 10, cited
+        gone = sorted(c for c in cited if not (REPO / c).exists()
+                      and not (default_root() / c).exists())
+        assert not gone, f"{doc} cites files that are not there: {gone}"
 
     def test_every_knob_is_documented(self):
         undocumented = set(config.KNOBS) - self._doc_names()

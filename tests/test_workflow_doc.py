@@ -1,11 +1,14 @@
 """WORKFLOW.md must stay runnable: extract its ``bst ...`` commands and run
 them in order against the generated example project. Any drift between the
-documented pipeline and the CLI breaks this test."""
+documented pipeline and the CLI breaks this test. The scripts under
+``scripts/`` are held to the CLI by name: every ``bst <tool>`` one calls is
+a command (and, of a group, a subcommand) that exists."""
 
 import os
 import re
 import shlex
 
+import click
 import numpy as np
 import pytest
 from click.testing import CliRunner
@@ -13,6 +16,7 @@ from click.testing import CliRunner
 from bigstitcher_spark_tpu.cli.main import cli
 
 DOC = os.path.join(os.path.dirname(__file__), "..", "WORKFLOW.md")
+SCRIPTS = os.path.join(os.path.dirname(__file__), "..", "scripts")
 
 
 def doc_commands():
@@ -55,3 +59,29 @@ def test_workflow_runs(tmp_path, monkeypatch):
     sd = SpimData.load("example/resaved.xml")
     # clear-registrations --keep 1 ran last: back to one transform per view
     assert all(len(ch) == 1 for ch in sd.registrations.values())
+
+
+def script_calls(name):
+    """(tool, next word) of every ``bst <tool> ...`` or ``python -m
+    bigstitcher_spark_tpu.cli.main <tool> ...`` command line of a script;
+    comments and quoted prose do not count."""
+    with open(os.path.join(SCRIPTS, name)) as f:
+        text = f.read().replace("\\\n", " ")
+    calls = []
+    for line in text.splitlines():
+        line = re.sub(r"'[^']*'|\"[^\"]*\"", "''", line.split("#")[0])
+        calls += re.findall(
+            r"(?:(?<![\w.$/<-])bst|-m\s+bigstitcher_spark_tpu\.cli\.main)"
+            r"\s+([a-z][\w-]*)(?:\s+([\w-]+))?", line)
+    return calls
+
+
+@pytest.mark.parametrize("script", sorted(
+    n for n in os.listdir(SCRIPTS) if n.endswith(".sh") and script_calls(n)))
+def test_every_tool_a_script_calls_is_a_command(script):
+    for tool, sub in script_calls(script):
+        cmd = cli.commands.get(tool)
+        assert cmd is not None, f"{script}: `bst {tool}` is no command"
+        if isinstance(cmd, click.Group) and sub and not sub.startswith("-"):
+            assert sub in cmd.commands, \
+                f"{script}: `bst {tool} {sub}` is no command"
