@@ -55,6 +55,9 @@ _REFINE_PAIRS = {
                              scorer=scorer)
     for scorer in ("device", "host")}
 _REFINE_CANDIDATES = _metrics.counter("bst_stitching_refine_candidates_total")
+_PACK_BUCKETS = {
+    path: _metrics.counter("bst_stitching_pack_buckets_total", path=path)
+    for path in ("stored", "cast", "float")}
 
 
 @dataclass
@@ -151,7 +154,9 @@ def _aggregate(sd: SpimData, crops: dict[ViewId, np.ndarray], group: ViewGroup,
         if len(imgs) == 1:
             return imgs[0]
         if how == "AVERAGE":
-            return np.mean(imgs, axis=0)
+            # stored crops arrive in the container's dtype: float32 from
+            # here (np.mean of uint16 would accumulate in float64)
+            return np.mean(np.asarray(imgs, np.float32), axis=0)
         if how == "PICK_BRIGHTEST":
             return imgs[int(np.argmax([np.sum(i, dtype=np.float64) for i in imgs]))]
         raise ValueError(f"unknown aggregation {how}")
@@ -166,8 +171,11 @@ def _aggregate(sd: SpimData, crops: dict[ViewId, np.ndarray], group: ViewGroup,
 
 
 def _downsample_crop(crop: np.ndarray, ds: Sequence[int]) -> np.ndarray:
+    """Average the residual factors away, in float32; a crop with none left
+    is handed on as it is, in the dtype it was read in."""
     if all(int(f) == 1 for f in ds):
-        return crop.astype(np.float32)
+        return crop
+    crop = np.asarray(crop, np.float32)
     pad = [(0, (-crop.shape[d]) % int(ds[d])) for d in range(3)]
     if any(p[1] for p in pad):
         crop = np.pad(crop, pad, mode="edge")
@@ -179,7 +187,11 @@ class _PairJob:
     group_a: ViewGroup
     group_b: ViewGroup
     overlap: Interval
-    crop_a: np.ndarray       # downsampled, float32
+    # downsampled; in the stored dtype (uint16 as a rule) where nothing was
+    # computed on the way (one image a group or PICK_BRIGHTEST, a stored
+    # level), float32 where something was (AVERAGE, a residual
+    # downsample, the rendered path)
+    crop_a: np.ndarray
     crop_b: np.ndarray
     # shift post-processing: S = linear @ (p0b - p0a + residual_ds*s)
     # - (t_a - t_b) with linear/t from the LEVEL model (model o mipmap), or
@@ -252,8 +264,12 @@ def _extract_pair_job(sd, loader, ga, gb, overlap, params) -> _PairJob | None:
                                + inv[:, 3]).astype(np.int64)
                 if p0 is None:
                     p0 = p0v
-                crops[v] = loader.read_block(v, levels[v], tuple(p0v), lvl_shape
-                                             ).astype(np.float32)
+                block = loader.read_block(v, levels[v], tuple(p0v), lvl_shape)
+                if not np.can_cast(block.dtype, np.float32):
+                    # a stored type float32 cannot hold (int32, float64)
+                    # is rounded once, here, to what the PCM will see
+                    block = block.astype(np.float32)
+                crops[v] = block
             return crops, p0
 
         crops_a, p0a = crops_for(ga, models_a)
@@ -405,13 +421,20 @@ def stitch_jobs(sd, jobs: list[_PairJob], params: StitchingParams,
     the device FFTs of the next. One local device degrades to exactly that
     pipelined loop on the caller's thread (the pre-sharding path).
 
-    A bucket's two stacks are uploaded once and serve both halves: the PCM,
-    and — where the crops are whole uint16 numbers, so that every Pearson
-    sum is an integer — the refinement's candidate scorer, which runs on
-    the device that holds them (ops/phasecorr.pearson_sums; the search and
-    r stay on the host, in float64). A bucket of any other crops (rendered,
-    averaged, float) is scored on the host from float64 summed-area tables.
-    The data picks; ``bst_stitching_refine_pairs_total{scorer}`` counts.
+    A bucket's two stacks are written once and uploaded once
+    (``_pack_stacks``): crops that arrive as stored uint16 voxels are
+    copied straight into a zeroed uint16 stack; crops something was
+    computed on (averaged, residually downsampled, rendered) arrive
+    float32, are padded and stacked as such and cross as uint16 only where
+    a check finds every value whole. The data picks;
+    ``bst_stitching_pack_buckets_total{path}`` counts. The upload serves
+    both halves: the PCM, and — where the stacks are whole uint16 numbers,
+    so that every Pearson sum is an integer — the refinement's candidate
+    scorer, which runs on the device that holds them
+    (ops/phasecorr.pearson_sums; the search and r stay on the host, in
+    float64). A float32 bucket is scored on the host from float64
+    summed-area tables; ``bst_stitching_refine_pairs_total{scorer}``
+    counts.
 
     In a multi-process world chunks split across processes FIRST
     (cost-aware LPT over FFT volume), each process's slice over its
@@ -471,23 +494,51 @@ def stitch_jobs(sd, jobs: list[_PairJob], params: StitchingParams,
             if chunk_results is not None for r in chunk_results]
 
 
+def _pack_stacks(jobs: list[_PairJob], shp
+                 ) -> tuple[np.ndarray, np.ndarray, str]:
+    """One bucket's two zero-padded stacks as they cross to the device, and
+    the path that made them (``bst_stitching_pack_buckets_total{path}``).
+
+    ``stored``: every crop arrived uint16 (stored-level voxels nothing was
+    computed on), so each is copied once into the corner of its row of a
+    zeroed uint16 stack: whole uint16 numbers by their type, nothing to
+    check. Any other bucket is padded and stacked in float32 and asked
+    whether it survives the cast (ops/phasecorr.as_uint16_lossless), decided
+    once for both stacks so that the jitted kernels see only two dtype
+    signatures a shape bucket (u16/u16 or f32/f32): ``cast`` where it does
+    (half the bytes on the link, the device's cast back is bit-identical),
+    ``float`` where it does not. ``stored`` and ``cast`` give the same
+    bytes for the same values."""
+    if all(c.dtype == np.uint16 for j in jobs for c in (j.crop_a, j.crop_b)):
+        # a new buffer a bucket: the upload may alias it (may_alias=True),
+        # so one reused across passes could still be read by a transfer
+        a = np.zeros((len(jobs),) + tuple(shp), np.uint16)
+        b = np.zeros_like(a)
+        for k, j in enumerate(jobs):
+            for stack, crop in ((a, j.crop_a), (b, j.crop_b)):
+                stack[(k,) + tuple(slice(0, n) for n in crop.shape)] = crop
+        return a, b, "stored"
+    a = np.stack([pad_to(j.crop_a, shp) for j in jobs])
+    b = np.stack([pad_to(j.crop_b, shp) for j in jobs])
+    ua = as_uint16_lossless(a)
+    ub = as_uint16_lossless(b) if ua is not None else None
+    if ub is None:
+        return a, b, "float"
+    return ua, ub, "cast"
+
+
 def _dispatch_bucket(jobs: list[_PairJob], shp, params):
-    """Pack and upload one bucket's two stacks, start its PCM, and hand
-    back ``(peaks, stacks)`` still on the device: ``stacks`` is the
-    resident ``(a, b, ext_a, ext_b)`` the refinement scores on where the
-    crops are whole uint16 numbers, None where they are not."""
+    """Pack and upload one bucket's two stacks (``_pack_stacks``: stored
+    uint16 crops are copied straight, any others padded in float32 and
+    checked), start its PCM, and hand back ``(peaks, stacks)`` still on the
+    device: ``stacks`` is the resident ``(a, b, ext_a, ext_b)`` the
+    refinement scores on where the crops are whole uint16 numbers, None
+    where they are not."""
     with profiling.span("stitching.pack"):
-        a = np.stack([pad_to(j.crop_a, shp) for j in jobs])
-        b = np.stack([pad_to(j.crop_b, shp) for j in jobs])
-        # lossless h2d downcast, decided ONCE for both stacks so the jitted
-        # kernel sees only two dtype signatures (u16/u16 or f32/f32) per
-        # shape bucket: halves the bytes on the PCIe link, and the
-        # device cast back to float32 is bit-identical
-        ua = as_uint16_lossless(a)
-        ub = as_uint16_lossless(b) if ua is not None else None
-        exact = ub is not None
+        a, b, path = _pack_stacks(jobs, shp)
+        _PACK_BUCKETS[path].inc()
+        exact = path != "float"
         if exact:
-            a, b = ua, ub
             _H2D_SAVED.inc(a.size * 4 - a.nbytes + b.size * 4 - b.nbytes)
         ext_a = np.stack([np.array(j.crop_a.shape, np.int32) for j in jobs])
         ext_b = np.stack([np.array(j.crop_b.shape, np.int32) for j in jobs])

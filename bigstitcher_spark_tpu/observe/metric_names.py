@@ -119,6 +119,11 @@ METRICS: dict[str, str] = {
     "bst_stitching_refine_candidates_total":
         "candidate shifts the device scorer summed (each a pass over the "
         "pair's two resident stacks)",
+    "bst_stitching_pack_buckets_total":
+        "shape buckets packed for upload, labeled by path: stored (every "
+        "crop arrived uint16 and was copied straight into a zeroed uint16 "
+        "stack) | cast (float32 crops the lossless check let through as "
+        "uint16) | float (uploaded as float32)",
     "bst_fusion_voxels_total":
         "output voxels whose block the fusion driver has written",
     "bst_fusion_blocks_total":
@@ -331,7 +336,10 @@ SPANS: dict[str, str] = {
         "the phase-correlation program (host time; the device's part is "
         "in a --trace-device trace)",
     "stitching.pack":
-        "host-only part of stitching.kernel: pad, stack, lossless cast",
+        "host-only part of stitching.kernel: one bucket's two padded "
+        "stacks written (stored uint16 crops copied straight into a zeroed "
+        "uint16 stack; float32 crops padded, stacked and checked for the "
+        "lossless cast) and the extents gathered",
     "stitching.kernel_sync": "PCM device completion sync",
     "stitching.refine": "one bucket's Pearson refinement of PCM peaks",
     "stitching.refine.pair":

@@ -345,8 +345,8 @@ class _PearsonScorer:
 
 
 def refine_peaks(
-    crop_a: np.ndarray,       # unpadded crop of group A (any float dtype)
-    crop_b: np.ndarray,
+    crop_a: np.ndarray,       # unpadded crop of group A, as stored (uint16)
+    crop_b: np.ndarray,       # or float32; only its shape where sums is given
     peaks: np.ndarray,        # (n_peaks, 3) wrapped PCM indices
     fft_shape: tuple[int, int, int],
     min_overlap: float = 32.0,

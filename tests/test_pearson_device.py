@@ -159,7 +159,7 @@ def test_the_data_picks_the_scorer_and_the_counters_say_which(
         project, monkeypatch):
     """Stored uint16 voxels take the device scorer; the same crops as
     floats that are not whole numbers take the host's, as does a bucket
-    the lossless cast turns down — with the same bits out."""
+    that crosses as float32 — with the same bits out."""
     dev, n_dev = _stitch(project, downsampling=(1, 1, 1))
     assert n_dev["device"] == len(dev) >= 4 and n_dev["host"] == 0
     assert n_dev["candidates"] >= 8 * len(dev)
@@ -168,7 +168,11 @@ def test_the_data_picks_the_scorer_and_the_counters_say_which(
     assert n_avg["host"] == len(averaged) >= 4
     assert n_avg["device"] == 0 and n_avg["candidates"] == 0
 
-    monkeypatch.setattr(st, "as_uint16_lossless", lambda stack: None)
+    # where a bucket's stacks, and with them its scorer, are decided: the
+    # same crops in float32, as a bucket the lossless cast turns down
+    monkeypatch.setattr(st, "_pack_stacks", lambda jobs, shp: (
+        np.stack([pc.pad_to(j.crop_a, shp) for j in jobs]),
+        np.stack([pc.pad_to(j.crop_b, shp) for j in jobs]), "float"))
     host, n_host = _stitch(project, downsampling=(1, 1, 1))
     assert n_host == {"device": 0, "host": len(dev), "candidates": 0}
     for d, h in zip(dev, host):

@@ -130,6 +130,211 @@ def test_uint16_transport_is_bit_identical():
     assert as_uint16_lossless(crop + 1e6) is None      # out of range
 
 
+# ---------------------------------------------------------------------------
+# stored crops keep their type from the read to the upload (PR 33)
+# ---------------------------------------------------------------------------
+
+
+def _float32_reads(monkeypatch):
+    """The route every crop took before PR 33: float32 from the read on.
+    From there on the program computes what it computed then, so its crops
+    and results are the values the stored-dtype route has to reproduce."""
+    real = ViewLoader.read_block
+
+    def read_block(self, *args, **kwargs):
+        return real(self, *args, **kwargs).astype(np.float32)
+
+    monkeypatch.setattr(ViewLoader, "read_block", read_block)
+
+
+def _pack_counts():
+    from bigstitcher_spark_tpu.models import stitching as st
+
+    return {path: c.value for path, c in st._PACK_BUCKETS.items()}
+
+
+def _packed_since(base):
+    return {path: n - base[path] for path, n in _pack_counts().items()}
+
+
+def _bare_jobs(crops):
+    from bigstitcher_spark_tpu.models.stitching import _PairJob
+
+    return [_PairJob(None, None, None, a, b, None, None, None)
+            for a, b in crops]
+
+
+@pytest.mark.parametrize("shapes", [
+    [((16, 32, 32), (16, 32, 32))],
+    [((11, 30, 32), (16, 25, 17)), ((16, 32, 1), (9, 32, 32)),
+     ((1, 1, 1), (13, 31, 29))],
+], ids=["full", "ragged"])
+def test_a_stored_bucket_uploads_the_lossless_cast_s_bytes(shapes):
+    """Copied straight into a zeroed uint16 stack, a bucket of uint16 crops
+    is byte for byte what pad_to + stack + as_uint16_lossless made of the
+    same crops as float32."""
+    from bigstitcher_spark_tpu.models.stitching import _dispatch_bucket
+    from bigstitcher_spark_tpu.ops.phasecorr import as_uint16_lossless
+
+    rng = np.random.default_rng(7)
+    crops = [tuple(rng.integers(0, 65536, shp, dtype=np.uint16)
+                   for shp in pair) for pair in shapes]
+    shp = (16, 32, 32)
+    base = _pack_counts()
+    _peaks, stacks = _dispatch_bucket(_bare_jobs(crops), shp,
+                                      StitchingParams())
+    assert _packed_since(base) == {"stored": 1, "cast": 0, "float": 0}
+    for side in (0, 1):
+        want = as_uint16_lossless(np.stack(
+            [pad_to(pair[side].astype(np.float32), shp) for pair in crops]))
+        got = np.asarray(stacks[side])
+        assert got.dtype == want.dtype == np.uint16
+        assert got.tobytes() == want.tobytes()
+        np.testing.assert_array_equal(
+            np.asarray(stacks[2 + side]),
+            [pair[side].shape for pair in crops])
+
+
+@pytest.mark.parametrize("kind,path", [
+    ("whole", "cast"), ("fractional", "float"), ("mixed", "cast"),
+    ("negative", "float")])
+def test_a_float32_bucket_is_checked_as_before(kind, path):
+    """Crops that arrive float32 (or one of a pair does) take pad, stack
+    and the lossless check: uint16 across the link where every value is
+    whole, float32 and no resident stacks where one is not."""
+    from bigstitcher_spark_tpu.models.stitching import _dispatch_bucket
+
+    rng = np.random.default_rng(8)
+    a = rng.integers(0, 65536, (12, 32, 20), dtype=np.uint16)
+    b = rng.integers(0, 65536, (16, 27, 32)).astype(np.float32)
+    if kind == "fractional":
+        b[3, 4, 5] += 0.5
+    elif kind == "negative":
+        b[0, 0, 0] = -1.0
+    elif kind == "whole":
+        a = a.astype(np.float32)
+    shp = (16, 32, 32)
+    base = _pack_counts()
+    peaks, stacks = _dispatch_bucket(_bare_jobs([(a, b)]), shp,
+                                     StitchingParams())
+    assert _packed_since(base)[path] == 1
+    assert sum(_packed_since(base).values()) == 1
+    assert np.asarray(peaks).shape == (1, 5, 3)
+    if path == "float":
+        assert stacks is None
+    else:
+        assert np.asarray(stacks[0]).dtype == np.uint16
+        np.testing.assert_array_equal(np.asarray(stacks[1])[0],
+                                      pad_to(b, shp))
+
+
+@pytest.fixture(scope="module")
+def two_channel_project(tmp_path_factory):
+    from bigstitcher_spark_tpu.utils.testdata import make_synthetic_project
+
+    return make_synthetic_project(
+        str(tmp_path_factory.mktemp("stitch2c") / "proj"),
+        n_tiles=(2, 1, 1), tile_size=(64, 64, 32), overlap=24,
+        jitter=2.0, seed=6, n_channels=2, n_beads_per_tile=60,
+        downsampling_factors=((1, 1, 1), (2, 2, 1)),
+    )
+
+
+# (project, params) -> the crops' dtype and the pack path a bucket takes
+_EXTRACT_CASES = {
+    "one-view-s0": ("stitch_project", dict(downsampling=(1, 1, 1)),
+                    np.uint16, "stored"),
+    "one-view-stored-level": (
+        "two_channel_project",
+        dict(downsampling=(2, 2, 1), channel_combine="PICK_BRIGHTEST"),
+        np.uint16, "stored"),
+    "pick-brightest": (
+        "two_channel_project",
+        dict(downsampling=(1, 1, 1), channel_combine="PICK_BRIGHTEST"),
+        np.uint16, "stored"),
+    "average-group": ("two_channel_project", dict(downsampling=(1, 1, 1)),
+                      np.float32, "float"),
+    "residual-downsample": ("stitch_project", dict(downsampling=(2, 2, 1)),
+                            np.float32, "float"),
+    "residual-over-stored-level": (
+        "two_channel_project",
+        dict(downsampling=(4, 2, 1), channel_combine="PICK_BRIGHTEST"),
+        np.float32, "float"),
+}
+
+
+@pytest.fixture(params=sorted(_EXTRACT_CASES))
+def extract_case(request):
+    project, kw, dtype, path = _EXTRACT_CASES[request.param]
+    proj = request.getfixturevalue(project)
+    sd = SpimData.load(proj.xml_path)
+    return sd, ViewLoader(sd), StitchingParams(**kw), dtype, path
+
+
+def test_extract_hands_on_the_stored_type_unless_it_computes(
+        extract_case, monkeypatch):
+    """One image a group at a stored level stays the uint16 array the
+    loader read; an AVERAGE over channels or a residual downsample gives
+    float32, with the values the float32-from-the-read route gives."""
+    from bigstitcher_spark_tpu.models.stitching import _extract_pair_job
+
+    sd, loader, params, dtype, _path = extract_case
+    pairs = plan_pairs(sd, build_groups(sd, sd.view_ids()))
+    jobs = [_extract_pair_job(sd, loader, *p, params) for p in pairs]
+    _float32_reads(monkeypatch)
+    before = [_extract_pair_job(sd, loader, *p, params) for p in pairs]
+    assert jobs and len(jobs) == len(before)
+    for job, old in zip(jobs, before):
+        for crop, old_crop in ((job.crop_a, old.crop_a),
+                               (job.crop_b, old.crop_b)):
+            assert crop.dtype == dtype and old_crop.dtype == np.float32
+            np.testing.assert_array_equal(crop, old_crop)
+        assert job.residual_ds == old.residual_ds
+        np.testing.assert_array_equal(job.p0_delta, old.p0_delta)
+
+
+def test_stitching_gives_the_float32_route_s_bits(extract_case, monkeypatch):
+    """transform and correlation of every pair, bit for bit, whichever
+    type the crops travel in; the counter says which path packed them."""
+    sd, loader, params, _dtype, path = extract_case
+
+    def run():
+        base = _pack_counts()
+        res = stitch_all_pairs(sd, loader, sd.view_ids(), params,
+                               progress=False, devices=1)
+        return sorted(res, key=lambda r: r.pair_key), _packed_since(base)
+
+    now, packed = run()
+    assert packed[path] >= 1 and sum(packed.values()) == packed[path]
+    _float32_reads(monkeypatch)
+    before, packed_before = run()
+    # float32 crops of whole numbers pass the lossless check: same bytes
+    old_path = "cast" if path == "stored" else path
+    assert packed_before[old_path] == packed[path]
+    assert sum(packed_before.values()) == packed[path]
+    assert len(now) == len(before) >= 1
+    for n, o in zip(now, before):
+        assert n.pair_key == o.pair_key and n.correlation == o.correlation
+        np.testing.assert_array_equal(n.transform, o.transform)
+
+
+def test_a_type_float32_cannot_hold_is_rounded_at_the_read(
+        stitch_project, monkeypatch):
+    """int32 or float64 voxels become float32 once, where they are read,
+    so that the PCM and the host scorer see the same numbers."""
+    from bigstitcher_spark_tpu.models.stitching import _extract_pair_job
+
+    real = ViewLoader.read_block
+    monkeypatch.setattr(
+        ViewLoader, "read_block",
+        lambda self, *a, **k: real(self, *a, **k).astype(np.float64) + 1e-9)
+    sd = SpimData.load(stitch_project.xml_path)
+    pair = plan_pairs(sd, build_groups(sd, sd.view_ids()))[0]
+    job = _extract_pair_job(sd, ViewLoader(sd), *pair,
+                            StitchingParams(downsampling=(1, 1, 1)))
+    assert job.crop_a.dtype == job.crop_b.dtype == np.float32
+
+
 def test_segmented_pipeline_matches_single_segment(stitch_project):
     """A tiny inflight_bytes budget forces one segment per chunk (max
     round-trips); results must be identical to the default single-segment
