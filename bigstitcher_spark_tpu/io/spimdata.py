@@ -18,8 +18,10 @@ matching ``ViewRegistration.getModel()`` semantics.
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import os
+import secrets
 import xml.etree.ElementTree as ET
 from dataclasses import dataclass, field
 from typing import Iterable, Sequence
@@ -408,8 +410,21 @@ class SpimData:
             uris.write_bytes(path, buf.encode())
         else:
             path = uris.strip_file_scheme(path)
-            ET.ElementTree(root).write(path, encoding="unicode",
-                                       xml_declaration=True)
+            # whole or not at all: every rank of a multi-process stage
+            # saves the project, and a reader on another rank or thread
+            # must never open a half-written file. The name is random and
+            # opened exclusively: ranks on other hosts of a shared store
+            # can share a pid and a thread id
+            tmp = f"{path}.{secrets.token_hex(8)}.tmp"
+            f = open(tmp, "x", encoding="utf-8", errors="xmlcharrefreplace")
+            try:
+                with f:
+                    ET.ElementTree(root).write(f, encoding="unicode",
+                                               xml_declaration=True)
+                os.replace(tmp, path)
+            finally:
+                with contextlib.suppress(FileNotFoundError):
+                    os.unlink(tmp)      # left only by a failed write
         self.xml_path = path
 
     def _write_sequence(self, seq: ET.Element) -> None:

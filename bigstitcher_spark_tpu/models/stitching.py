@@ -58,6 +58,9 @@ _REFINE_CANDIDATES = _metrics.counter("bst_stitching_refine_candidates_total")
 _PACK_BUCKETS = {
     path: _metrics.counter("bst_stitching_pack_buckets_total", path=path)
     for path in ("stored", "cast", "float")}
+_GROUPS = {
+    combine: _metrics.counter("bst_stitching_groups_total", combine=combine)
+    for combine in ("single", "average", "brightest")}
 
 
 @dataclass
@@ -145,14 +148,22 @@ def plan_pairs(sd: SpimData, groups: list[ViewGroup]) -> list[tuple[ViewGroup, V
     return out
 
 
+@profiling.span("stitching.aggregate")
 def _aggregate(sd: SpimData, crops: dict[ViewId, np.ndarray], group: ViewGroup,
                params: StitchingParams) -> np.ndarray:
     """GroupedViewAggregator: combine channels (AVERAGE default) then
     illuminations (PICK_BRIGHTEST default) of one tile
-    (SparkPairwiseStitching.java:204-208)."""
+    (SparkPairwiseStitching.java:204-208).
+    ``bst_stitching_groups_total{combine}`` counts the group by what came
+    of it: ``single`` (one image, handed on), ``average`` (a mean was
+    computed on the way: float32), ``brightest`` (one of several stored
+    images picked)."""
+    combined = set()
+
     def combine(imgs: list[np.ndarray], how: str) -> np.ndarray:
         if len(imgs) == 1:
             return imgs[0]
+        combined.add(how)
         if how == "AVERAGE":
             # stored crops arrive in the container's dtype: float32 from
             # here (np.mean of uint16 would accumulate in float64)
@@ -167,7 +178,10 @@ def _aggregate(sd: SpimData, crops: dict[ViewId, np.ndarray], group: ViewGroup,
         by_illum.setdefault(illum, []).append(crops[v])
     per_illum = [combine(imgs, params.channel_combine)
                  for _, imgs in sorted(by_illum.items())]
-    return combine(per_illum, params.illum_combine)
+    out = combine(per_illum, params.illum_combine)
+    _GROUPS["average" if "AVERAGE" in combined
+            else "brightest" if combined else "single"].inc()
+    return out
 
 
 def _downsample_crop(crop: np.ndarray, ds: Sequence[int]) -> np.ndarray:

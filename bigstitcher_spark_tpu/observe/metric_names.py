@@ -112,6 +112,11 @@ METRICS: dict[str, str] = {
     # work done in the end-to-end metrics' own units, as it completes
     "bst_stitching_pairs_total":
         "tile pairs whose shift left the stitching drain (refined)",
+    "bst_stitching_groups_total":
+        "view groups aggregated for a pair's side, labeled by combine: "
+        "single (one image, handed on as read) | average (a mean over "
+        "channels or illuminations was computed: float32) | brightest "
+        "(one of several stored images picked)",
     "bst_stitching_refine_pairs_total":
         "tile pairs refined, labeled by scorer: device (whole uint16 "
         "crops: exact integer sums on the bucket's resident stacks) | "
@@ -331,6 +336,9 @@ SPANS: dict[str, str] = {
     "stitching.stage": "one stitch_all_pairs call (tree root)",
     "stitching.plan": "view grouping and overlapping-pair planning",
     "stitching.extract": "overlap crop extraction for one pair batch",
+    "stitching.aggregate":
+        "one group's channels and illuminations combined into the image "
+        "the pair is correlated on (a child of stitching.extract)",
     "stitching.kernel":
         "one shape bucket's host packing, implicit upload and dispatch of "
         "the phase-correlation program (host time; the device's part is "

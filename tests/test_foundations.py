@@ -164,6 +164,79 @@ class TestSpimData:
         assert r2.hash == pytest.approx(123.5)
         assert r2.bbox == res.bbox
 
+    def test_a_local_save_is_whole_or_not_at_all(self, synthetic_project,
+                                                 monkeypatch):
+        """Every rank of a multi-process stage saves the project while
+        others may be reading it (ROADMAP C17): the XML is written beside
+        its place and moved in. A serialisation that fails half way leaves
+        the old file whole and nothing beside it."""
+        import os
+        import xml.etree.ElementTree as ET
+
+        path = synthetic_project.xml_path
+        sd = SpimData.load(path)
+        with open(path, "rb") as f:
+            before = f.read()
+        listing = sorted(os.listdir(os.path.dirname(path)))
+        seen = []
+
+        def half_way(self, target, *args, **kwargs):
+            seen.append(target.name)
+            target.write("<SpimData")
+            target.flush()
+            assert os.path.exists(target.name)
+            raise OSError("disk full")
+
+        with monkeypatch.context() as m:
+            m.setattr(ET.ElementTree, "write", half_way)
+            with pytest.raises(OSError, match="disk full"):
+                sd.save(path)
+        # never written in place, and the half-written file is gone
+        assert seen and seen[0] != path
+        assert os.path.dirname(seen[0]) == os.path.dirname(path)
+        assert sorted(os.listdir(os.path.dirname(path))) == listing
+        with open(path, "rb") as f:
+            assert f.read() == before
+        sd.timepoints = [0, 1]
+        sd.save(path)
+        assert sorted(os.listdir(os.path.dirname(path))) == listing
+        assert SpimData.load(path).timepoints == [0, 1]
+
+    def test_two_writers_with_one_pid_and_thread_id_do_not_share_a_file(
+            self, synthetic_project, monkeypatch):
+        """Ranks on different hosts over one shared store can have the same
+        pid and the same main-thread id (containers of one image): the
+        temporary's name must not be made of those. The second writer runs
+        while the first has its temporary open."""
+        import os
+        import threading
+        import xml.etree.ElementTree as ET
+
+        path = synthetic_project.xml_path
+        first, second = SpimData.load(path), SpimData.load(path)
+        first.timepoints, second.timepoints = [0, 1], [0, 1, 2]
+        listing = sorted(os.listdir(os.path.dirname(path)))
+        monkeypatch.setattr(os, "getpid", lambda: 4242)
+        monkeypatch.setattr(threading, "get_ident", lambda: 1)
+        write = ET.ElementTree.write
+        names = []
+
+        def both(self, target, *args, **kwargs):
+            names.append(target.name)
+            assert os.path.exists(target.name)
+            if len(names) == 1:
+                second.save(path)
+                # the other rank's whole file is in place, ours still beside
+                assert SpimData.load(path).timepoints == [0, 1, 2]
+                assert os.path.exists(target.name)
+            return write(self, target, *args, **kwargs)
+
+        monkeypatch.setattr(ET.ElementTree, "write", both)
+        first.save(path)
+        assert len(names) == 2 and names[0] != names[1]
+        assert SpimData.load(path).timepoints == [0, 1]
+        assert sorted(os.listdir(os.path.dirname(path))) == listing
+
 
 class TestMipmap:
     def test_mipmap_transform(self):
