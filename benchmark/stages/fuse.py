@@ -41,6 +41,7 @@ class Stage:
         self.lo, self.hi = lo, hi
         self.xml = os.path.join(job["fixture_dir"], "registered.xml")
         self._covered = None
+        self._refs = {}
 
     # ------------------------------------------------------ the timed path
 
@@ -151,6 +152,16 @@ class Stage:
                                float(self.opt["blending_range"][0]),
                                precision)
 
+    def reference(self, lo, shp):
+        """``_reference`` of one container block in float64, kept once a
+        run: a part holds a few distinct blocks and every pass draws its
+        sample from them."""
+        key = (tuple(int(v) for v in lo), tuple(int(v) for v in shp))
+        ref = self._refs.get(key)
+        if ref is None:
+            ref = self._refs[key] = self._reference(lo, shp)
+        return ref
+
     def _compare(self, pairs) -> dict:
         """Worst block of (got, ref, summed reference weight) triples."""
         means, worst, left_out, voxels = [], 0.0, 0, 0
@@ -183,7 +194,7 @@ class Stage:
                 got = arr.read([0, 0, o[2], o[1], o[0]],
                                [1, 1, o[2] + shp[2], o[1] + shp[1],
                                 o[0] + shp[0]])[0, 0].transpose(2, 1, 0)
-                pairs.append((got, *self._reference(lo, shp)))
+                pairs.append((got, *self.reference(lo, shp)))
         return {**self._compare(pairs), "fuse_missing_chunks": float(missing)}
 
     def control(self) -> dict:
