@@ -60,17 +60,9 @@ from .common import (
               help="detection block size at detection resolution")
 def detect_interestpoints_cmd(xml, dry_run, **kw):
     """Distributed DoG interest-point detection (SparkInterestPointDetection)."""
-    from ..io.dataset_io import ViewLoader
-    from ..io.interestpoints import InterestPointStore
-    from ..models.detection import (
-        DetectionParams,
-        detect_interest_points,
-        save_detections,
-    )
+    from ..models.detection import DetectionParams, detect_project
     from .common import parse_csv_ints
 
-    sd = load_project(xml)
-    views = select_views_from_kwargs(sd, kw)
     params = DetectionParams(
         label=kw["label"], sigma=kw["sigma"], threshold=kw["threshold"],
         downsample_xy=kw["downsample_xy"], downsample_z=kw["downsample_z"],
@@ -86,17 +78,9 @@ def detect_interestpoints_cmd(xml, dry_run, **kw):
         median_radius=kw["median_radius"],
         block_size=tuple(parse_csv_ints(kw["block_size"], 3)),
     )
-    loader = ViewLoader(sd)
-    detections = detect_interest_points(sd, loader, views, params)
-    total = sum(len(d.points) for d in detections)
-    click.echo(f"detected {total} interest points over {len(detections)} views")
-    if dry_run:
-        click.echo("dryRun: not saving")
-        return
-    store = InterestPointStore.for_project(sd)
-    save_detections(sd, store, detections, params)
-    sd.save(xml)
-    click.echo(f"saved interest points '{params.label}' + XML")
+    detect_project(xml, params,
+                   select=lambda sd: select_views_from_kwargs(sd, kw),
+                   dry_run=dry_run, log=click.echo)
 
 
 @click.command()

@@ -47,6 +47,11 @@ from ..utils.geometry import (
 )
 from ..utils.grid import create_grid
 from .. import profiling
+from ..observe import metrics as _metrics
+
+_BLOCKS_WHOLE = _metrics.counter("bst_detection_blocks_whole_total")
+_BLOCKS_TRUNCATED = _metrics.counter("bst_detection_blocks_truncated_total")
+_POINTS = _metrics.counter("bst_detection_points_total")
 
 
 @dataclass
@@ -109,6 +114,7 @@ class _BlockJob:
     view_idx: int
     core: Interval                # detection-res block (core, no halo)
     result: tuple | None = None   # (subpixel pts, values) after extraction
+    index: int = 0                # place in the pass's work list (spans)
 
 
 class _ViewPlan:
@@ -277,9 +283,10 @@ def _overlap_boxes_det(
 def _estimate_min_max(loader: ViewLoader, view: ViewId) -> tuple[float, float]:
     """Image min/max from the coarsest stored level (the reference scans the
     downsampled image when min/maxIntensity are absent)."""
-    lvl = loader.num_levels(view.setup) - 1
-    img = loader.open(view, lvl).read_full()
-    return float(img.min()), float(img.max())
+    with profiling.span("detection.minmax", item=view.setup):
+        lvl = loader.num_levels(view.setup) - 1
+        img = loader.open(view, lvl).read_full()
+        return float(img.min()), float(img.max())
 
 
 def _make_dog_kernel(n_dev: int, params: DetectionParams,
@@ -391,37 +398,14 @@ def _make_dog_kernel_cached(n_dev, sigma, find_max, find_min, k, halo, rel,
     return kernel
 
 
-def detect_interest_points(
-    sd: SpimData,
-    loader: ViewLoader,
-    views: list[ViewId],
-    params: DetectionParams | None = None,
-    progress: bool = True,
-    devices: int | None = None,
-) -> list[ViewDetections]:
-    """Run DoG detection over all ``views``; returns per-view detections in
-    FULL-RES view-local pixel coordinates (correctForDownsampling applied,
-    SparkInterestPointDetection.java:611)."""
-    params = params or DetectionParams()
+def _plan_blocks(sd: SpimData, views: list[ViewId],
+                 plans: dict[ViewId, _ViewPlan], params: DetectionParams
+                 ) -> tuple[dict[ViewId, list[Interval]], list[_BlockJob]]:
+    """The pass's work list: every view's detection-resolution grid of
+    ``params.block_size`` cores, with ``--overlappingOnly`` the cores that
+    touch an overlap box (and the boxes, for the final crop)."""
     ds = params.downsampling
-    halo = dog_halo(params.sigma)
     bs = tuple(int(b) for b in params.block_size)
-
-    plans = {v: _ViewPlan(loader, v, ds) for v in views}
-    minmax = {}
-    need = [v for v in views
-            if params.min_intensity is None or params.max_intensity is None]
-    ests: dict[ViewId, tuple[float, float]] = {}
-    if need:  # estimation reads are independent -> overlap them
-        with CtxThreadPool(max_workers=min(8, len(need))) as mpool:
-            ests = dict(zip(need, mpool.map(
-                lambda v: _estimate_min_max(loader, v), need)))
-    for v in views:
-        lo, hi = ests.get(v, (0.0, 0.0))
-        minmax[v] = (
-            params.min_intensity if params.min_intensity is not None else lo,
-            params.max_intensity if params.max_intensity is not None else hi)
-
     overlap_boxes: dict[ViewId, list[Interval]] = {}
     jobs: list[_BlockJob] = []
     view_list = list(views)
@@ -443,12 +427,47 @@ def detect_interest_points(
             core = Interval.from_shape(blk.size, blk.offset).translate(region.min)
             if boxes is not None and not any(core.overlaps(b) for b in boxes):
                 continue
-            jobs.append(_BlockJob(vi, core))
+            jobs.append(_BlockJob(vi, core, index=len(jobs)))
+    return overlap_boxes, jobs
 
+
+def detect_interest_points(
+    sd: SpimData,
+    loader: ViewLoader,
+    views: list[ViewId],
+    params: DetectionParams | None = None,
+    progress: bool = True,
+    devices: int | None = None,
+) -> list[ViewDetections]:
+    """Run DoG detection over all ``views``; returns per-view detections in
+    FULL-RES view-local pixel coordinates (correctForDownsampling applied,
+    SparkInterestPointDetection.java:611)."""
+    params = params or DetectionParams()
+    ds = params.downsampling
+    halo = dog_halo(params.sigma)
+    bs = tuple(int(b) for b in params.block_size)
+
+    with profiling.span("detection.plan"):
+        plans = {v: _ViewPlan(loader, v, ds) for v in views}
+        overlap_boxes, jobs = _plan_blocks(sd, views, plans, params)
+    view_list = list(views)
     observe.log(f"detection: {len(view_list)} views, {len(jobs)} blocks "
                 f"(block {bs}, halo {halo}, ds {ds})",
                 stage="detection", echo=progress,
                 views=len(view_list), blocks=len(jobs))
+    minmax = {}
+    need = [v for v in views
+            if params.min_intensity is None or params.max_intensity is None]
+    ests: dict[ViewId, tuple[float, float]] = {}
+    if need:  # estimation reads are independent -> overlap them
+        with CtxThreadPool(max_workers=min(8, len(need))) as mpool:
+            ests = dict(zip(need, mpool.map(
+                lambda v: _estimate_min_max(loader, v), need)))
+    for v in views:
+        lo, hi = ests.get(v, (0.0, 0.0))
+        minmax[v] = (
+            params.min_intensity if params.min_intensity is not None else lo,
+            params.max_intensity if params.max_intensity is not None else hi)
 
     # bucket by block shape (edge blocks are smaller) -> one compiled kernel
     # per shape bucket; the bucket's block list is batched over the device
@@ -464,33 +483,41 @@ def detect_interest_points(
         plan = plans[v]
         off = [m - halo for m in job.core.min]
         shape = [s + 2 * halo for s in job.core.shape]
-        if params.median_radius > 0:
-            raw = plan.read_det_block(loader, off, shape)
-            raw = _median_background_divide(raw, params.median_radius,
-                                            exact=params.median_exact)
-            raw = raw.astype(np.float32)
-        else:
-            # ship the LEVEL-resolution block in its native dtype; the
-            # kernel pools by ``rel`` + normalizes on device (half the
-            # wire bytes, no separate downsample dispatch)
-            raw = plan.read_raw_block(loader, off, shape)
-            if raw.dtype.byteorder == ">":  # JAX rejects big-endian (HDF5)
-                raw = raw.astype(raw.dtype.newbyteorder("="))
+        with profiling.span("detection.read", item=job.index):
+            if params.median_radius > 0:
+                raw = plan.read_det_block(loader, off, shape)
+                raw = _median_background_divide(raw, params.median_radius,
+                                                exact=params.median_exact)
+                raw = raw.astype(np.float32)
+            else:
+                # ship the LEVEL-resolution block in its native dtype; the
+                # kernel pools by ``rel`` + normalizes on device (half the
+                # wire bytes, no separate downsample dispatch)
+                raw = plan.read_raw_block(loader, off, shape)
+                if raw.dtype.byteorder == ">":  # JAX rejects big-endian
+                    raw = raw.astype(raw.dtype.newbyteorder("="))
         lo, hi = minmax[v]
         return (raw, np.float32(lo), np.float32(hi),
                 np.float32(params.threshold),
                 np.array([m - halo for m in job.core.min], np.int32))
 
-    def consume(job: _BlockJob, idx, sub, vals, valid, count, *extra):
+    def consume(job: _BlockJob, *outs):
+        with profiling.span("detection.consume", item=job.index):
+            _consume(job, *outs)
+
+    def _consume(job: _BlockJob, idx, sub, vals, valid, count, *extra):
         shape = job.core.shape
         k = len(idx)
         if int(count) > k:
             import warnings
 
+            _BLOCKS_TRUNCATED.inc()
             warnings.warn(
                 f"detection block {job.core.min} found {int(count)} extrema, "
                 f"keeping the {k} strongest (raise max_candidates_per_block "
                 "or lower the threshold noise)", stacklevel=2)
+        else:
+            _BLOCKS_WHOLE.inc()
         # the kernel pre-masks to the core slab; re-check as a safety net
         # (halo detections belong to the neighboring block)
         keep = valid.astype(bool)
@@ -582,6 +609,7 @@ def detect_interest_points(
                           np.zeros(0, bool))
         pts, vals, riders = _filter_spots(pts, vals, overlap_boxes.get(v),
                                           params, riders)
+        _POINTS.inc(len(pts))
         # detection-res -> full-res: average downsampling by f maps level
         # voxel p to full-res f*p + (f-1)/2 (DownsampleTools.correctForDownsampling)
         T = mipmap_transform(ds)
@@ -676,3 +704,37 @@ def save_detections(
         )
         register_points_in_xml(sd, det.view, params.label,
                                params.params_string(), grp)
+
+
+@profiling.span("detection.stage")
+def detect_project(
+    xml: str,
+    params: DetectionParams | None = None,
+    select=None,
+    dry_run: bool = False,
+    devices: int | None = None,
+    progress: bool = True,
+    log=lambda message: None,
+) -> list[ViewDetections]:
+    """What ``bst detect-interestpoints`` does with a project: load it, take
+    the views ``select(sd)`` names (every view where ``select`` is None),
+    :func:`detect_interest_points` over them, then, unless ``dry_run``,
+    store the points in ``interestpoints.n5`` beside the XML and register
+    them in the saved XML. The one entry of the stage: the command calls it
+    and so does the benchmark's adapter. Returns the detections."""
+    params = params or DetectionParams()
+    sd = SpimData.load(xml)
+    views = select(sd) if select is not None else sd.view_ids()
+    detections = detect_interest_points(sd, ViewLoader(sd), views, params,
+                                        progress=progress, devices=devices)
+    log(f"detected {sum(len(d.points) for d in detections)} interest "
+        f"points over {len(detections)} views")
+    if dry_run:
+        log("dryRun: not saving")
+        return detections
+    with profiling.span("detection.save"):
+        save_detections(sd, InterestPointStore.for_project(sd), detections,
+                        params)
+        sd.save(xml)
+    log(f"saved interest points '{params.label}' + XML")
+    return detections

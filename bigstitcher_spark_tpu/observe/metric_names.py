@@ -117,6 +117,15 @@ METRICS: dict[str, str] = {
         "single (one image, handed on as read) | average (a mean over "
         "channels or illuminations was computed: float32) | brightest "
         "(one of several stored images picked)",
+    "bst_detection_blocks_whole_total":
+        "detection blocks whose core extrema all fit the device's K "
+        "candidates (counted where the block's outputs are consumed)",
+    "bst_detection_blocks_truncated_total":
+        "detection blocks with more core extrema than the K candidates "
+        "that leave the device: the K strongest kept, a warning raised",
+    "bst_detection_points_total":
+        "interest points a view keeps after the overlap and brightest-N "
+        "filters, summed over the views detected",
     "bst_stitching_refine_pairs_total":
         "tile pairs refined, labeled by scorer: device (whole uint16 "
         "crops: exact integer sums on the bucket's resident stacks) | "
@@ -329,6 +338,24 @@ SPANS: dict[str, str] = {
     "fusion.epilogue.write":
         "container write of an epilogue pyramid slab or block",
     # detection / stitching / matching / nonrigid drivers
+    "detection.stage":
+        "one detect_project call: the project loaded, detected, stored "
+        "and saved (tree root)",
+    "detection.plan":
+        "every view's stored level chosen and block grid laid out, "
+        "before any read",
+    "detection.minmax":
+        "one view's min/max estimate: its coarsest stored level read "
+        "whole and scanned (item = setup)",
+    "detection.read":
+        "one block's read with halo, mirror-padded at the image border, "
+        "on a build thread (item = block)",
+    "detection.consume":
+        "the host tail of one block: candidates masked to the core, "
+        "shifted to view coordinates and sorted (item = block)",
+    "detection.save":
+        "the points written to interestpoints.n5 and the project XML "
+        "saved with their registration",
     "detection.kernel": "DoG + localization device computation",
     "detection.extract":
         "descriptor-extraction device dispatch of the STAGED two-pass "

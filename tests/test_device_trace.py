@@ -242,13 +242,16 @@ def test_categories_of_the_new_spans():
 
 
 @pytest.mark.parametrize("metric", ["fuse_kernel_ms", "fuse_kernel_roofline",
-                                    "pcm_kernel_ms", "pcm_roofline"])
+                                    "pcm_kernel_ms", "pcm_roofline",
+                                    "detect_kernel_ms",
+                                    "detect_kernel_roofline"])
 def test_kernel_module_names_match_the_benchmarks_patterns(metric):
     """The benchmark finds a kernel's device time by its XLA module name
     (``jit_<function>``): lower each kernel a cell runs and hold the
     module's name to the patterns of the metric's file."""
     import jax.numpy as jnp
 
+    from bigstitcher_spark_tpu.ops import dog as D
     from bigstitcher_spark_tpu.ops import fusion as F
     from bigstitcher_spark_tpu.ops import phasecorr as P
 
@@ -272,7 +275,12 @@ def test_kernel_module_names_match_the_benchmarks_patterns(metric):
                 jnp.zeros((2, 8, 8, 4), jnp.uint16),
                 jnp.ones((2, 3), jnp.int32), jnp.ones((2, 3), jnp.int32),
                 5, 0.25)],
-    }["fuse" if metric.startswith("fuse") else "pcm"]
+        "detect": [
+            D.dog_block_topk_batch.lower(
+                jnp.zeros((2, 20, 20, 20), jnp.uint16), jnp.zeros((2,)),
+                jnp.ones((2,)), jnp.full((2,), 0.008),
+                jnp.zeros((2, 3), jnp.int32), sigma=1.8, k=16, halo=8)],
+    }[metric.split("_")[0]]
     for low in lowered:
         text = low.as_text()
         name = re.search(r"module @(\w+)", text).group(1)

@@ -336,11 +336,71 @@ class TestPerBlockDriverReadsSideBySide:
                    if e["name"] == "fusion.prefetch")
 
 
+class TestDetectionTree:
+    """``detect_project``, the one entry of ``bst detect-interestpoints``:
+    every span of the pass hangs under ``detection.stage`` whatever thread
+    it ran on, the counters count one a block and the points kept, and
+    the named children cover the root."""
+
+    def test_spans_under_the_stage_and_counts_a_block(self, tmp_path):
+        from bigstitcher_spark_tpu.models.detection import (
+            DetectionParams, detect_project,
+        )
+        from bigstitcher_spark_tpu.utils.testdata import (
+            make_synthetic_project,
+        )
+
+        proj = make_synthetic_project(str(tmp_path / "p"), n_tiles=(2, 1, 1),
+                                      tile_size=(64, 64, 32), overlap=16,
+                                      jitter=0.0, seed=2,
+                                      n_beads_per_tile=15)
+        reg = metrics.get_registry()
+        before = reg.snapshot()
+        trace.configure(buffer_bytes=1 << 22)
+        profiling.enable(True)
+        dets = detect_project(proj.xml_path, DetectionParams(
+            downsample_xy=1, downsample_z=1, block_size=(32, 32, 16)),
+            progress=False)
+        counted = reg.snapshot_delta(before)
+        snap = trace.snapshot()
+        begins = [e for e in snap if e["ph"] == "B"]
+        parent = {e["id"]: e["parent"] for e in begins}
+
+        def root(i):
+            while parent[i]:
+                i = parent[i]
+            return i
+
+        stage = [e for e in begins if e["name"] == "detection.stage"]
+        assert len(stage) == 1 and stage[0]["parent"] == 0
+        blocks = 2 * 2 * 2 * 2      # two views of 2 x 2 x 2 blocks
+        want = {"detection.plan": 1, "detection.minmax": 2,
+                "detection.read": blocks, "detection.consume": blocks,
+                "detection.save": 1, "spimdata.load": 1,
+                "spimdata.save": 1}
+        for name, n in want.items():
+            got = [e for e in begins if e["name"] == name]
+            assert len(got) == n, name
+            assert all(root(e["id"]) == stage[0]["id"] for e in got), name
+        assert {e["item"] for e in begins
+                if e["name"] == "detection.read"} == set(range(blocks))
+        assert any(e["tid"] != stage[0]["tid"] for e in begins
+                   if e["name"] == "detection.read")
+        assert counted["bst_detection_blocks_whole_total"] == blocks
+        assert counted["bst_detection_blocks_truncated_total"] == 0
+        assert counted["bst_detection_points_total"] == \
+            sum(len(d.points) for d in dets) > 0
+        st = profiling.get().stats()["detection.stage"]
+        assert st.self_s < 0.05 * st.total_s
+
+
 class TestNames:
     def test_every_new_name_is_declared_once(self):
         from bigstitcher_spark_tpu.observe import metric_names as mn
 
         for span in ("stitching.stage", "stitching.plan", "stitching.pack",
+                     "detection.stage", "detection.plan", "detection.minmax",
+                     "detection.read", "detection.consume", "detection.save",
                      "stitching.refine.pair", "stitching.store",
                      "spimdata.load", "spimdata.save", "fusion.stage",
                      "fusion.plan", "fusion.h2d", "jax.compile"):
@@ -348,7 +408,10 @@ class TestNames:
         for m in ("bst_io_read_seconds_total", "bst_io_write_seconds_total",
                   "bst_stage_item_seconds", "bst_stitching_pairs_total",
                   "bst_fusion_voxels_total", "bst_jax_compile_events_total",
-                  "bst_jax_compile_seconds_total"):
+                  "bst_jax_compile_seconds_total",
+                  "bst_detection_blocks_whole_total",
+                  "bst_detection_blocks_truncated_total",
+                  "bst_detection_points_total"):
             assert m in mn.METRICS
         for m in ("bst_pair_busy_ms_total", "bst_pair_device_util_pct",
                   "bst_pair_proc_busy_ms_total", "bst_pair_proc_util_pct"):
